@@ -46,7 +46,7 @@ EXTRA_BUILDERS = (
 _JIT_WRAPPERS = {"jax.jit", "jax.pmap"}
 _TRACE_WRAPPERS = {
     "jax.jit", "jax.pmap", "jax.vmap",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.pallas.pallas_call",
     f"{PKG}.parallel.sharded_agg.shard_map_compat",
 }

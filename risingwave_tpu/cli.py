@@ -188,7 +188,8 @@ def main(argv=None) -> int:
                      "that have not acked (stuck-barrier blame)")
     ctl.add_argument("--peak-flops", type=float, default=None,
                      help="profile roofline: chip peak FLOP/s "
-                     "(default [observability] chip_peak_flops)")
+                     "(default [observability] chip_peak_flops, else "
+                     "by the attached device's kind)")
     ctl.add_argument("--peak-bandwidth", type=float, default=None,
                      help="profile roofline: chip HBM bandwidth in "
                      "bytes/s (default [observability] "
@@ -597,11 +598,16 @@ def _ctl_profile_roofline(args, _json) -> int:
     surface."""
     from .common.config import ObservabilityConfig
     from .common.profiling import (
-        aot_analysis, render_roofline_table, roofline_report,
+        UnknownChipError, aot_analysis, chip_peaks,
+        render_roofline_table, roofline_report,
     )
     obs = ObservabilityConfig()
-    peak_flops = args.peak_flops or obs.chip_peak_flops
-    peak_bw = args.peak_bandwidth or obs.chip_peak_bandwidth
+    try:
+        peak_flops, peak_bw = chip_peaks(
+            args.peak_flops or obs.chip_peak_flops,
+            args.peak_bandwidth or obs.chip_peak_bandwidth)
+    except UnknownChipError as e:
+        raise SystemExit(f"ctl profile roofline: {e}")
     surfaces = _roofline_surfaces()
     pick = getattr(args, "surface", None)
     if pick is not None:

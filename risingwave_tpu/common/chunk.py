@@ -93,11 +93,9 @@ class ChunkBatch:
 
     The dispatch-amortization unit: one host→device dispatch covers K chunks
     (a ``lax.scan`` over the leading axis inside the consuming executor's
-    jitted step), instead of K round-trips. Matters enormously when the
-    device is reached over a network tunnel where each dispatch costs
-    milliseconds. Stateless executors transform the whole batch with one
-    vmapped step; executors without a batched path fall back to per-chunk
-    iteration (``at``)."""
+    jitted step), instead of K dispatches. Stateless executors transform
+    the whole batch with one vmapped step; executors without a batched
+    path fall back to per-chunk iteration (``at``)."""
 
     chunk: StreamChunk  # arrays: [K, C, ...]
 

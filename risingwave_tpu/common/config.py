@@ -307,10 +307,12 @@ class ObservabilityConfig:
     hbm_capacity_bytes: int = 16 << 30
     hbm_warn_fraction: float = 0.8
     # roofline model peaks (ctl profile roofline): chip peak FLOP/s and
-    # HBM bandwidth in bytes/s (defaults ≈ TPU v4: 275 TFLOP/s bf16,
-    # 1.2 TB/s)
-    chip_peak_flops: float = 275e12
-    chip_peak_bandwidth: float = 1.2e12
+    # HBM bandwidth in bytes/s. Unset (None) = looked up by the attached
+    # device's ``device_kind`` in common/profiling.CHIP_PEAKS (v5e:
+    # 197 TFLOP/s bf16, 819 GB/s); an unknown kind is an error there,
+    # not a default
+    chip_peak_flops: Optional[float] = None
+    chip_peak_bandwidth: Optional[float] = None
 
 
 @dataclasses.dataclass

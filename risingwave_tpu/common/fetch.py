@@ -37,8 +37,8 @@ __all__ = ["FetchFuture", "PendingFlush", "async_fetch", "fetch"]
 
 def _start_copy(tree: Any) -> None:
     """Kick off the non-blocking device→host copy on every array leaf.
-    Leaves without the async-copy surface (host numpy, scalars, older
-    jax versions) simply resolve synchronously at ``result()``."""
+    Leaves without the async-copy surface (host numpy, scalars) simply
+    resolve synchronously at ``result()``."""
     import jax
 
     def start(x):

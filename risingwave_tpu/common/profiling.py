@@ -351,9 +351,7 @@ def aot_analysis(jitted: Callable, *args, **kwargs) -> dict:
     if lower is None:
         raise TypeError(f"{jitted!r} has no .lower AOT surface")
     compiled = lower(*args, **kwargs).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # older jax returns [dict]
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis() or {}
     cost = {"flops": float(ca.get("flops", 0.0) or 0.0),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0) or 0.0)}
     mem: dict = {}
@@ -431,6 +429,43 @@ def hbm_ledger(jobs: dict, capacity_bytes: int,
 # ---------------------------------------------------------------------------
 # roofline
 # ---------------------------------------------------------------------------
+
+#: published per-chip peaks, keyed by ``jax.devices()[0].device_kind``:
+#: (peak FLOP/s bf16, HBM bytes/s, HBM bytes). A device that is not in
+#: the table is an error, never a default.
+#: "TPU v5 lite" = one TPU v5e chip — 197 TFLOP/s bf16, 819 GB/s, 16 GB
+#: (Google Cloud documentation, "TPU v5e").
+CHIP_PEAKS: dict = {
+    "TPU v5 lite": (197e12, 819e9, 16e9),
+}
+
+
+class UnknownChipError(LookupError):
+    """The attached device has no entry in ``CHIP_PEAKS`` and no explicit
+    peaks were given."""
+
+
+def chip_peaks(peak_flops: Optional[float] = None,
+               peak_bandwidth: Optional[float] = None,
+               device_kind: Optional[str] = None) -> tuple:
+    """(peak FLOP/s, peak bytes/s) for a roofline: explicit values win
+    (``[observability] chip_peak_flops`` / ``chip_peak_bandwidth``, or
+    ``ctl profile roofline --peak-flops/--peak-bandwidth``); what is not
+    given comes from ``CHIP_PEAKS`` by the attached device's kind, and an
+    unknown kind raises ``UnknownChipError``."""
+    if peak_flops and peak_bandwidth:
+        return float(peak_flops), float(peak_bandwidth)
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    known = CHIP_PEAKS.get(device_kind)
+    if known is None:
+        raise UnknownChipError(
+            f"no roofline peaks for device kind {device_kind!r} (known: "
+            f"{sorted(CHIP_PEAKS)}); give both peaks explicitly")
+    return (float(peak_flops or known[0]),
+            float(peak_bandwidth or known[1]))
+
 
 
 def roofline_report(analyses: dict, peak_flops: float,

@@ -5025,14 +5025,19 @@ class Session:
         """Roofline report over every dispatch this process has seen:
         AOT-``lower().compile()`` each recorded epoch callable (chip-free
         on the CPU stand-in) and place its arithmetic intensity against
-        the configured chip peaks ([observability] chip_peak_flops /
-        chip_peak_bandwidth). Triggers compiles, so it deliberately does
+        the chip peaks ([observability] chip_peak_flops /
+        chip_peak_bandwidth, else by the attached device's kind — an
+        unknown kind raises UnknownChipError). Triggers compiles, so it deliberately does
         NOT take the session API lock — the profiler registry it reads
         has its own lock, and ticks/scrapes must not stall behind XLA."""
-        from ..common.profiling import GLOBAL_PROFILER, roofline_report
+        from ..common.profiling import (
+            GLOBAL_PROFILER, chip_peaks, roofline_report,
+        )
+        peak_flops, peak_bw = chip_peaks(
+            self.observability.chip_peak_flops,
+            self.observability.chip_peak_bandwidth)
         return roofline_report(GLOBAL_PROFILER.analyze(),
-                               self.observability.chip_peak_flops,
-                               self.observability.chip_peak_bandwidth)
+                               peak_flops, peak_bw)
 
     @_locked
     def close(self) -> None:

@@ -21,7 +21,7 @@ is the integration point that fixes that:
 The cluster clock is *epoch-based* (injected by the Session): a worker's
 heartbeat timestamp is the last epoch whose barrier the job collected, and
 the TTL is measured in epochs — deterministic under tests and independent
-of wall-clock stalls (compiles, tunnels).
+of wall-clock stalls (compiles).
 """
 
 from __future__ import annotations

@@ -33,6 +33,14 @@ class AggState:
     overflow: jax.Array                # bool scalar, sticky
     last_used: jax.Array               # int32[cap]: step of last touch (LRU)
 
+    def rebaselined(self) -> "AggState":
+        """``prev_lanes`` := a COPY of ``lanes`` (recovery: the loaded
+        snapshot is the baseline downstream already saw). A copy, not an
+        alias: the epoch steps donate the whole state, and one buffer
+        cannot be donated twice."""
+        return self.replace(
+            prev_lanes=tuple(jnp.copy(l) for l in self.lanes))
+
 
 class AggCore:
     """Static config + pure methods for one grouped-agg operator."""
@@ -54,14 +62,20 @@ class AggCore:
 
     def init_state(self) -> AggState:
         cap = self.capacity
-        init_lanes = [jnp.zeros(cap, jnp.int64)]
-        for c in self.agg_calls:
-            for v, dt in zip(c.init_lanes(), c.state_dtypes()):
-                init_lanes.append(jnp.full(cap, v, dt))
+
+        def init_lanes() -> tuple:
+            lanes = [jnp.zeros(cap, jnp.int64)]
+            for c in self.agg_calls:
+                for v, dt in zip(c.init_lanes(), c.state_dtypes()):
+                    lanes.append(jnp.full(cap, v, dt))
+            return tuple(lanes)
+
+        # lanes and prev_lanes are separate buffers: the epoch steps
+        # donate the whole state, and one buffer cannot be donated twice
         return AggState(
             table=ht_new(self.key_types, cap),
-            lanes=tuple(init_lanes),
-            prev_lanes=tuple(init_lanes),
+            lanes=init_lanes(),
+            prev_lanes=init_lanes(),
             dirty=jnp.zeros(cap, jnp.bool_),
             ckpt_dirty=jnp.zeros(cap, jnp.bool_),
             overflow=jnp.zeros((), jnp.bool_),

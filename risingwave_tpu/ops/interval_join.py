@@ -25,9 +25,9 @@ O(N) scatters; the epoch flush is O(n_buckets · W) ONCE per barrier —
 the O(N²) all-pairs compare is gone. The flush match grid is an
 MXU/VPU-friendly [n_buckets, W] tile computation: ``interval_match``
 lowers to a Pallas TPU kernel (the ops/pallas_rank.py pattern — tiles
-generated in VMEM, jnp fallback elsewhere, RWTPU_PALLAS override,
-bit-identical results; int64 values ride as hi/lo int32 halves because
-Mosaic has no native s64 compare).
+generated in VMEM, jnp twin off the TPU, bit-identical results; int64
+values ride as hi/lo int32 halves because Mosaic has no native s64
+compare).
 
 Emission parity with the executor pipeline (HashAgg max → HashJoin) is
 exact, including the churn the executor produces: its agg flush emits
@@ -49,6 +49,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from ..common.chunk import (
@@ -344,7 +345,6 @@ class IntervalJoinCore:
     def export_host(self, state: IntervalJoinState) -> dict:
         """Device state → named numpy arrays (the checkpoint payload). One
         transfer; the arrays round-trip bit-exactly through import_host."""
-        import numpy as np
         host = jax.device_get(state)
         out = {f: getattr(host, f) for f in (
             "win_id", "fill", "touched", "cur_max", "cur_cnt",
@@ -396,23 +396,32 @@ def _split64(a: jax.Array):
 def _match_kernel(vlo_ref, vhi_ref, occ_ref, olo_ref, ohi_ref, olive_ref,
                   nlo_ref, nhi_ref, nlive_ref, del_ref, ins_ref):
     """One [TB, W] tile: the equality grids are generated in VMEM from the
-    [TB] per-bucket vectors and never exist at [nb, W] intermediate
-    granularity beyond the output masks themselves."""
+    [TB, 1] per-bucket columns and never exist at [nb, W] intermediate
+    granularity beyond the output masks themselves. Everything is 2-D
+    int32: Mosaic has no layout for the rank change of a 1-D block."""
     vlo = vlo_ref[:]
     vhi = vhi_ref[:]
     occ = occ_ref[:] != 0
-    eq_old = ((vlo == olo_ref[:][:, None]) & (vhi == ohi_ref[:][:, None])
-              & (olive_ref[:] != 0)[:, None])
-    eq_new = ((vlo == nlo_ref[:][:, None]) & (vhi == nhi_ref[:][:, None])
-              & (nlive_ref[:] != 0)[:, None])
-    del_ref[:] = (occ & eq_old).astype(jnp.int32)
-    ins_ref[:] = (occ & eq_new).astype(jnp.int32)
+
+    def lanes(ref):
+        return jnp.broadcast_to(ref[:], vlo.shape)
+
+    eq_old = ((vlo == lanes(olo_ref)) & (vhi == lanes(ohi_ref))
+              & (lanes(olive_ref) != 0))
+    eq_new = ((vlo == lanes(nlo_ref)) & (vhi == lanes(nhi_ref))
+              & (lanes(nlive_ref) != 0))
+    # typed constants: under x64 a bare 1 is an int64, which Mosaic has
+    # no layout for
+    one, zero = jnp.int32(1), jnp.int32(0)
+    del_ref[:] = jnp.where(occ & eq_old, one, zero)
+    ins_ref[:] = jnp.where(occ & eq_new, one, zero)
 
 
 def interval_match_pallas_call(vals, occ, old_max, old_live,
                                new_max, new_live, interpret: bool = False):
-    """The raw pallas_call — no backend guard (compile CI proxy entry,
-    like ops/pallas_rank.rank_totals_pallas_call)."""
+    """The raw pallas_call — no backend choice (compiled for a described
+    v5e by tests/test_pallas_compile.py, like
+    ops/pallas_rank.rank_totals_pallas_call)."""
     from jax.experimental import pallas as pl
 
     nb, w = vals.shape
@@ -421,8 +430,13 @@ def interval_match_pallas_call(vals, occ, old_max, old_live,
     olo, ohi = _split64(old_max)
     nlo, nhi = _split64(new_max)
     grid = (nb // tb,)
-    vec = pl.BlockSpec((tb,), lambda i: (i,))
-    mat = pl.BlockSpec((tb, w), lambda i: (i, 0))
+    z = np.int32(0)     # block indices are int32 (a bare 0 is int64 here)
+    vec = pl.BlockSpec((tb, 1), lambda i: (i, z))
+    mat = pl.BlockSpec((tb, w), lambda i: (i, z))
+
+    def column(a):
+        return a.astype(jnp.int32).reshape(nb, 1)
+
     return pl.pallas_call(
         _match_kernel,
         grid=grid,
@@ -431,17 +445,19 @@ def interval_match_pallas_call(vals, occ, old_max, old_live,
         out_shape=[jax.ShapeDtypeStruct((nb, w), jnp.int32),
                    jax.ShapeDtypeStruct((nb, w), jnp.int32)],
         interpret=interpret,
-    )(vlo, vhi, occ.astype(jnp.int32), olo, ohi,
-      old_live.astype(jnp.int32), nlo, nhi, new_live.astype(jnp.int32))
+    )(vlo, vhi, occ.astype(jnp.int32), column(olo), column(ohi),
+      column(old_live), column(nlo), column(nhi), column(new_live))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def interval_match_pallas(vals, occ, old_max, old_live, new_max, new_live,
                           interpret: bool = False):
+    """The kernel with its bool epilogue. ``interpret=False`` compiles
+    for the attached TPU and fails anywhere else; the backend choice is
+    ``interval_match``'s alone."""
     nb, w = vals.shape
-    tb = min(TILE_B, nb)
-    if (nb % tb
-            or (not interpret and jax.default_backend() != "tpu")):
+    if nb % min(TILE_B, nb):
+        # a choice by shape: ragged bucket counts have no tile grid
         return interval_match_jnp(vals, occ, old_max, old_live,
                                   new_max, new_live)
     d, ins = interval_match_pallas_call(vals, occ, old_max, old_live,
@@ -452,15 +468,13 @@ def interval_match_pallas(vals, occ, old_max, old_live, new_max, new_live,
 
 def interval_match(vals, occ, old_max, old_live, new_max, new_live):
     """Flush match grids — Pallas kernel on TPU, jnp elsewhere; both
-    bit-identical (tests/test_interval_join.py asserts parity).
-    RWTPU_PALLAS=0 forces jnp; =1 forces Pallas (interpret off-TPU) —
-    ONE gate shared with the rank kernel so the two can never disagree
-    about when Pallas is active."""
-    from .pallas_rank import _use_pallas
-    if _use_pallas():
-        interpret = jax.default_backend() != "tpu"
+    bit-identical (tests/test_interval_join.py asserts parity). ONE
+    selector shared with the rank kernel (ops/pallas_rank
+    .pallas_selected) so the two can never disagree about when Pallas
+    is active."""
+    from .pallas_rank import pallas_selected
+    if pallas_selected():
         return interval_match_pallas(vals, occ, old_max, old_live,
-                                     new_max, new_live,
-                                     interpret=interpret)
+                                     new_max, new_live)
     return interval_match_jnp(vals, occ, old_max, old_live,
                               new_max, new_live)

@@ -266,8 +266,7 @@ class JoinCore:
         # r[i,w] = |{j<i: key_j == key_i, (j,w) matches}|, t = same over all j.
         # On TPU the fused Pallas kernel generates the [N,N] equality
         # tiles in VMEM and feeds the MXU directly (ops/pallas_rank.py);
-        # elsewhere the jnp matmul formulation runs. RWTPU_PALLAS=0/1
-        # overrides the choice.
+        # elsewhere the jnp matmul formulation runs.
         from .pallas_rank import rank_totals
         ident = jnp.where(b_found, b_slot, -1)
         r, t = rank_totals(ident, matches)

@@ -21,19 +21,21 @@ Grid: (N/TI, N/TJ); j is the reduction dimension — TPU grid cells run
 sequentially, so the output tile accumulates across the j sweep
 (initialized at j == 0). Both outputs ride the same equality tile.
 
-``rank_totals`` picks the implementation: the Pallas kernel on TPU (or
-when RWTPU_PALLAS=1 forces it, e.g. interpret mode in tests), the jnp
-matmul formulation elsewhere. Both produce bit-identical int32 results —
-``tests/test_pallas_kernels.py`` asserts parity.
+``rank_totals`` picks the implementation: the Pallas kernel on a TPU
+backend, the jnp matmul formulation elsewhere (``pallas_selected``, the
+one selector). Both produce bit-identical int32 results —
+``tests/test_pallas_kernels.py`` asserts parity in interpret mode,
+``tests/test_pallas_compile.py`` compiles the kernel for a described
+v5e, ``chip_smoke.py`` compares the two on the chip.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 TILE_I = 256
 TILE_J = 256
@@ -51,7 +53,7 @@ def rank_totals_jnp(ident: jax.Array, matches: jax.Array):
     return r, t
 
 
-def _kernel(ident_i_ref, ident_j_ref, m_ref, r_ref, t_ref):
+def _kernel(ident_col_ref, ident_row_ref, m_ref, r_ref, t_ref):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
@@ -61,92 +63,92 @@ def _kernel(ident_i_ref, ident_j_ref, m_ref, r_ref, t_ref):
         r_ref[:] = jnp.zeros_like(r_ref)
         t_ref[:] = jnp.zeros_like(t_ref)
 
-    ti = ident_i_ref.shape[0]
-    tj = ident_j_ref.shape[0]
+    ti = ident_col_ref.shape[0]
+    tj = ident_row_ref.shape[1]
     i0 = pl.program_id(0) * ti
     j0 = j * tj
-    ident_i = ident_i_ref[:]
-    ident_j = ident_j_ref[:]
+    # [TI, 1] column and [1, TJ] row blocks (reshaped by XLA outside the
+    # call): Mosaic broadcasts 2-D int32 along lanes/sublanes, but has no
+    # layout for the rank change of a 1-D block, nor for a 1-D mask
+    ident_i = jnp.broadcast_to(ident_col_ref[:], (ti, tj))
+    ident_j = jnp.broadcast_to(ident_row_ref[:], (ti, tj))
     # the [TI, TJ] equality tile, generated in VMEM — never materialized
     # at [N, N]
-    eq = (ident_i[:, None] == ident_j[None, :]) & (ident_i >= 0)[:, None]
+    eq = (ident_i == ident_j) & (ident_i >= 0)
     row_i = i0 + jax.lax.broadcasted_iota(jnp.int32, (ti, tj), 0)
     col_j = j0 + jax.lax.broadcasted_iota(jnp.int32, (ti, tj), 1)
     lower = eq & (col_j < row_i)
-    mf = m_ref[:].astype(jnp.float32)
-    r_ref[:] += jnp.dot(
-        lower.astype(jnp.float32), mf,
-        preferred_element_type=jnp.float32)
-    t_ref[:] += jnp.dot(
-        eq.astype(jnp.float32), mf,
-        preferred_element_type=jnp.float32)
+    mf = m_ref[:]
+    # typed float32 constants (under x64 a bare 1.0 is a float64, which
+    # Mosaic has no layout for); the select runs at the 32-bit layout of
+    # the int32 compare's mask and only its result narrows to bfloat16
+    one, zero = jnp.float32(1), jnp.float32(0)
+    r_ref[:] += jnp.dot(jnp.where(lower, one, zero).astype(mf.dtype), mf,
+                        preferred_element_type=jnp.float32)
+    t_ref[:] += jnp.dot(jnp.where(eq, one, zero).astype(mf.dtype), mf,
+                        preferred_element_type=jnp.float32)
 
 
 def rank_totals_pallas_call(ident: jax.Array, matches: jax.Array,
                             interpret: bool = False):
-    """The raw pallas_call — no backend guard. Callers guarantee the tile
-    divisibility; the compile CI proxy (tests/test_pallas_compile.py)
-    lowers THIS for TPU from any host to catch kernel breakage without a
-    chip."""
+    """The raw pallas_call — no backend choice. Callers guarantee the tile
+    divisibility; tests/test_pallas_compile.py compiles THIS for a
+    described v5e from any host."""
     from jax.experimental import pallas as pl
 
     n, w = matches.shape
     ti = min(TILE_I, n)
     tj = min(TILE_J, n)
     grid = (n // ti, n // tj)
+    z = np.int32(0)     # block indices are int32 (a bare 0 is int64 here)
+    # 0/1 operands are exact in bfloat16 and the MXU accumulates in
+    # float32, so counts stay exact up to 2^24 rows
     return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ti,), lambda i, j: (i,)),
-            pl.BlockSpec((tj,), lambda i, j: (j,)),
-            pl.BlockSpec((tj, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((ti, 1), lambda i, j: (i, z)),
+            pl.BlockSpec((1, tj), lambda i, j: (z, j)),
+            pl.BlockSpec((tj, w), lambda i, j: (j, z)),
         ],
         out_specs=[
-            pl.BlockSpec((ti, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((ti, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((ti, w), lambda i, j: (i, z)),
+            pl.BlockSpec((ti, w), lambda i, j: (i, z)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, w), jnp.float32),
             jax.ShapeDtypeStruct((n, w), jnp.float32),
         ],
         interpret=interpret,
-    )(ident, ident, matches)
+    )(ident.reshape(n, 1), ident.reshape(1, n),
+      matches.astype(jnp.bfloat16))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def rank_totals_pallas(ident: jax.Array, matches: jax.Array,
                        interpret: bool = False):
+    """The kernel with its int32 epilogue. ``interpret=False`` compiles
+    for the attached TPU and fails anywhere else; the backend choice is
+    ``rank_totals``'s alone."""
     n, w = matches.shape
-    ti = min(TILE_I, n)
-    tj = min(TILE_J, n)
-    if (n % ti or n % tj
-            or (not interpret and jax.default_backend() != "tpu")):
-        # ragged capacities, or a backend with no Pallas lowering, fall
-        # back to the jnp formulation (identical results)
+    if n % min(TILE_I, n) or n % min(TILE_J, n):
+        # a choice by shape: ragged capacities have no tile grid
         return rank_totals_jnp(ident, matches)
     r, t = rank_totals_pallas_call(ident, matches, interpret=interpret)
     return (jnp.round(r).astype(jnp.int32),
             jnp.round(t).astype(jnp.int32))
 
 
-def _use_pallas() -> bool:
-    mode = os.environ.get("RWTPU_PALLAS", "auto").lower()
-    if mode in ("1", "on", "true"):
-        return True
-    if mode in ("0", "off", "false"):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:   # noqa: BLE001 — backend probe must never break eval
-        return False
+def pallas_selected() -> bool:
+    """The ONE place that chooses between the Mosaic kernels and their
+    jnp twins (shared with ops/interval_join.interval_match so the two
+    can never disagree): compiled Pallas on a TPU backend, the jnp
+    formulation on every other. Nothing retries, nothing interprets."""
+    return jax.default_backend() == "tpu"
 
 
 def rank_totals(ident: jax.Array, matches: jax.Array):
-    """r[i,w], t[i,w] as int32 — kernel on TPU, jnp elsewhere.
-    RWTPU_PALLAS=0 forces the jnp path (escape hatch if a backend
-    rejects the kernel); =1 forces Pallas (interpret on CPU)."""
-    if _use_pallas():
-        interpret = jax.default_backend() != "tpu"
-        return rank_totals_pallas(ident, matches, interpret=interpret)
+    """r[i,w], t[i,w] as int32 — the kernel on TPU, jnp elsewhere."""
+    if pallas_selected():
+        return rank_totals_pallas(ident, matches)
     return rank_totals_jnp(ident, matches)

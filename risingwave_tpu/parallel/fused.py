@@ -524,7 +524,7 @@ def load_agg_rows(core, rows: Sequence) -> object:
     ``lanes``: the recovered snapshot is the baseline downstream already
     saw."""
     state = load_rows_into_state(core, core.init_state(), rows)
-    return state.replace(prev_lanes=state.lanes)
+    return state.rebaselined()
 
 
 def load_shard_states(core, rows: Sequence, n_shards: int) -> list:
@@ -761,8 +761,7 @@ def reshard_q3_payloads(core, payloads: Sequence, new_n: int) -> list:
         agg_state = load_rows_into_state(core.agg, st.agg,
                                          agg_by_shard[s])
         st = st.replace(
-            agg=agg_state.replace(
-                prev_lanes=agg_state.lanes,
+            agg=agg_state.rebaselined().replace(
                 overflow=jnp.asarray(agg_overflow, jnp.bool_)),
             emitted_key=jnp.asarray(emitted["emitted_key"]),
             emitted_rev=jnp.asarray(emitted["emitted_rev"]),

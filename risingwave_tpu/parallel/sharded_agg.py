@@ -37,17 +37,11 @@ SHARD_AXIS = "shard"
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX versions: the top-level API (with
-    ``check_vma``) when present, else ``jax.experimental.shard_map``
-    (whose equivalent knob is ``check_rep``). Replication checking is
-    off either way — the hash shuffles communicate via explicit
-    ``all_to_all``/``psum``, which the checker cannot always follow."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off — the hash
+    shuffles communicate via explicit ``all_to_all``/``psum``, which the
+    checker cannot always follow."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int) -> Mesh:

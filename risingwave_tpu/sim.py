@@ -40,6 +40,7 @@ import os
 import random
 from typing import Dict, List, Optional
 
+from .common.compile_cache import export_compile_cache_env
 from .frontend.session import Session
 from .rpc.faults import CHAOS_ENV, ChaosRule, ChaosSchedule, install, plane
 
@@ -421,8 +422,7 @@ def run_netsplit(name: str, seed: int = 7, data_dir: Optional[str] = None,
     # and a deadline under that cost reads as a dead worker and spins
     # recovery forever (found by this very harness) — the shared
     # compilation cache below keeps RESPAWNED workers fast
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(data_dir, "jax_cache"))
+    export_compile_cache_env()
     os.environ.setdefault(
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     # keepalive probing stays OFF here: detection rides the epoch
@@ -536,8 +536,7 @@ def run_traffic_spike(seed: int = 7, data_dir: Optional[str] = None,
     from .frontend.build import BuildConfig
 
     data_dir = data_dir or tempfile.mkdtemp(prefix="rwtpu_spike_")
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(data_dir, "jax_cache"))
+    export_compile_cache_env()
     os.environ.setdefault(
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     acfg = AutoscalerConfig(
@@ -813,8 +812,7 @@ def crash_point_sweep_spanning(base_dir: str, seed: int = 3,
                     "worker": 1}})
         # shared compile cache + generous deadline: a respawned worker's
         # first epoch pays XLA compilation (see run_netsplit)
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                              os.path.join(base_dir, "jax_cache"))
+        export_compile_cache_env()
         os.environ.setdefault(
             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
         fc = FaultConfig(worker_epoch_timeout_s=15.0,

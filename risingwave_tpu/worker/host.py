@@ -991,6 +991,11 @@ async def amain(data_dir: str, worker_id: int, port: int) -> None:
     install_from_env(trace_path=os.path.join(data_dir,
                                              "chaos_trace.jsonl"))
     arm_from_env(worker_id=worker_id)
+    # claim the backend BEFORE announcing readiness: a worker that cannot
+    # get its platform (a chip another process holds) dies here with
+    # JAX's own error, and the spawning session sees the exit at once
+    import jax
+    jax.devices()
     host = WorkerHost(data_dir, worker_id)
     done = asyncio.Event()
 

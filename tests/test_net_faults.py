@@ -247,9 +247,17 @@ class TestKeepaliveEviction:
         from risingwave_tpu.rpc.exchange import PeerClientPool
 
         async def run():
+            release = asyncio.Event()
+
             async def silent_server(reader, writer):
                 await reader.read(64)        # swallow hello + pings
-                await asyncio.sleep(30)
+                try:
+                    await release.wait()     # silent until the test ends
+                finally:
+                    # on Python 3.12 server.wait_closed() waits for every
+                    # connection to drop: a handler that never closes its
+                    # writer hangs the test forever
+                    writer.close()
 
             server = await asyncio.start_server(
                 silent_server, "127.0.0.1", 0)
@@ -270,8 +278,9 @@ class TestKeepaliveEviction:
             assert pool.evictions == 1
             await client.aclose()
             await fresh.aclose()
+            release.set()
             server.close()
-            await server.wait_closed()
+            await asyncio.wait_for(server.wait_closed(), timeout=10)
         asyncio.run(run())
 
 

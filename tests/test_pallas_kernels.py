@@ -38,9 +38,9 @@ def test_rank_totals_semantics_small():
 
 
 def test_ragged_capacity_falls_back():
-    ident = jnp.asarray(np.arange(100, dtype=np.int32))
-    matches = jnp.ones((100, 4), bool)
-    r, t = rank_totals_pallas(ident, matches)   # 100 % 256 != 0 → jnp
+    ident = jnp.asarray(np.arange(300, dtype=np.int32))
+    matches = jnp.ones((300, 4), bool)
+    r, t = rank_totals_pallas(ident, matches)   # 300 % 256 != 0 → jnp
     r2, t2 = rank_totals_jnp(ident, matches)
     np.testing.assert_array_equal(np.asarray(r), np.asarray(r2))
     np.testing.assert_array_equal(np.asarray(t), np.asarray(t2))

@@ -262,6 +262,23 @@ def test_roofline_report_intensity_and_bounds():
     assert "mem_bound" in table and "% of peak" in table
 
 
+def test_chip_peaks_keyed_by_device_kind():
+    """Peaks come from one table keyed by device_kind (v5e = "TPU v5
+    lite": 197 TFLOP/s bf16, 819 GB/s); explicit values win; an unknown
+    kind — the CPU these tests run on included — is an error, not a
+    default."""
+    from risingwave_tpu.common.profiling import UnknownChipError, chip_peaks
+
+    assert chip_peaks(device_kind="TPU v5 lite") == (197e12, 819e9)
+    assert chip_peaks(1e14, 1e12, device_kind="anything") == (1e14, 1e12)
+    assert chip_peaks(1e14, None, device_kind="TPU v5 lite") \
+        == (1e14, 819e9)
+    with pytest.raises(UnknownChipError, match="cpu"):
+        chip_peaks()                    # the attached device: a CPU here
+    with pytest.raises(UnknownChipError):
+        chip_peaks(1e14, None, device_kind="TPU v99")
+
+
 # ---------------------------------------------------------------------------
 # bench trend
 # ---------------------------------------------------------------------------
@@ -308,11 +325,23 @@ def test_bench_trend_partial_records_and_nested_fields(tmp_path):
             trend["fields"]["serving.qps"]["points"]] == [50.0, 10.0]
 
 
-def test_bench_trend_over_checked_in_rounds():
-    """The acceptance artifact: the real BENCH_r01–r05 history folds
-    into a trend (r03–r05 lost the chip round, so the headline 'value'
-    field regresses vs r02's healthy 96k rows/s)."""
-    history = load_bench_history(REPO)
+def _write_lost_chip_history(dirpath):
+    """The shape of the five driver records this repo once carried (the
+    files are gone; ROADMAP.md states what they showed): one healthy
+    chip number in round 2, then rounds whose chip phase failed and
+    recorded ``value: 0.0``."""
+    _write_round(dirpath, 1, {"value": 0.0}, rc=2)
+    _write_round(dirpath, 2, {"value": 96644.6})
+    for n in (3, 4, 5):
+        _write_round(dirpath, n, {"value": 0.0, "tpu_error": "init"})
+
+
+def test_bench_trend_over_checked_in_rounds(tmp_path):
+    """A history in which later rounds lost the chip folds into a trend
+    whose headline 'value' field regresses vs the one healthy round."""
+    d = str(tmp_path)
+    _write_lost_chip_history(d)
+    history = load_bench_history(d)
     assert len(history) >= 5
     trend = bench_trend(history)
     assert "value" in trend["fields"]
@@ -320,10 +349,12 @@ def test_bench_trend_over_checked_in_rounds():
 
 
 @pytest.mark.slow
-def test_ctl_bench_trend_cli():
+def test_ctl_bench_trend_cli(tmp_path):
+    d = str(tmp_path)
+    _write_lost_chip_history(d)
     res = subprocess.run(
         [sys.executable, "-m", "risingwave_tpu", "ctl", "bench", "trend",
-         "--bench-dir", REPO, "--json"],
+         "--bench-dir", d, "--json"],
         capture_output=True, text=True, timeout=120,
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO)
     assert res.returncode == 0, res.stderr

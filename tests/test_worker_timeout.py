@@ -64,3 +64,27 @@ def test_request_timeout_raises_instead_of_hanging(tmp_path):
         assert w.dead
     finally:
         s.close()
+
+
+def test_worker_that_cannot_get_its_platform_fails_fast(tmp_path,
+                                                        monkeypatch):
+    """One JAX process per chip: a worker is spawned on the session's own
+    JAX platform, named explicitly, and claims its backend BEFORE
+    WORKER_READY. A worker that cannot get it (on a one-chip host the
+    session holds the chip) must end the spawn in seconds with
+    WorkerDied — the worker's own error on stderr — not wait out
+    SPAWN_TIMEOUT_S, and never come up on another platform."""
+    import time
+
+    import jax
+
+    from risingwave_tpu.frontend.remote import RemoteWorker, WorkerDied
+
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "definitely_not_a_backend")
+    w = RemoteWorker(str(tmp_path), 0, loop=None)
+    t0 = time.monotonic()
+    with pytest.raises(WorkerDied, match="exited during startup"):
+        w.spawn()
+    assert time.monotonic() - t0 < RemoteWorker.SPAWN_TIMEOUT_S / 2
+    assert w.proc.poll() not in (None, 0)
