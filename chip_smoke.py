@@ -34,9 +34,14 @@ Phases (no arguments, one chip):
 * cache   — where the compile cache is, seconds spent compiling, hits
 
 ``--chips 4`` runs the mesh phase and what it is compared with, and no
-other phase: the q5 and q7 MVs through ``[streaming] mesh_shape = 4``
-(sharded fused epoch with ``coschedule`` on; sharded executors for the
-join) against the same MVs on one chip of the same host.
+other phase: the same q5 and q8 MVs at the same size through
+``[streaming] mesh_shape = 4`` (sharded fused epoch with ``coschedule``
+on; sharded executors and the rank kernel under shard_map for the join)
+against the same MVs on one chip of the same host and the numpy
+recomputation. (NEXmark q7 is not the join MV: its join keeps every bid
+keyed by price and the arena is rectangular — 2 M log-uniform prices put
+~3,000 bids on the hottest key, so a shard needs 4096 lanes x >= 2^15
+keys = 2^27 cells against a growth ceiling of 2^24; see PERF.md.)
 """
 
 from __future__ import annotations
@@ -128,16 +133,14 @@ def sizes(tiny: bool) -> dict:
         return dict(chunk=256, chunks_per_tick=2, ticks=12, more_ticks=2,
                     agg_slots=1 << 13, join_keys=1 << 9, join_width=64,
                     rank_shapes=((512, 128), (256, 16)),
-                    match_shape=(1 << 9, 128),
-                    mesh_chunk=128, mesh_chunks_per_tick=2, mesh_ticks=6)
+                    match_shape=(1 << 9, 128))
     # a deployment, not a unit test: 32 barriers x 16 chunks x 4096 rows
     # = 2,097,152 bid events, 3 checkpoints, 2^20-slot agg tables and a
     # 2^15-key x 128-lane join arena per side
     return dict(chunk=4096, chunks_per_tick=16, ticks=32, more_ticks=3,
                 agg_slots=1 << 20, join_keys=1 << 15, join_width=128,
                 rank_shapes=((4096, 128), (1024, 16)),
-                match_shape=(1 << 15, 128),
-                mesh_chunk=1024, mesh_chunks_per_tick=4, mesh_ticks=8)
+                match_shape=(1 << 15, 128))
 
 
 CHECKPOINT_FREQUENCY = 10          # the reference cadence (common/config.py)
@@ -159,19 +162,6 @@ Q5_SQL = """CREATE MATERIALIZED VIEW q5 AS
     FROM TUMBLE(bid, date_time, INTERVAL '10' SECOND)
     GROUP BY window_start, auction"""
 Q5_SELECT = "SELECT window_start, auction, num FROM q5"
-
-# NEXmark q7 and q8 as written in tests/test_nexmark_queries.py
-Q7_SQL = """CREATE MATERIALIZED VIEW q7 AS
-    SELECT B.auction, B.price, B.bidder, B.date_time
-    FROM bid B
-    JOIN (
-        SELECT MAX(price) AS maxprice, window_end as date_time
-        FROM TUMBLE(bid, date_time, INTERVAL '10' SECOND)
-        GROUP BY window_end
-    ) B1 ON B.price = B1.maxprice
-    WHERE B.date_time BETWEEN B1.date_time - INTERVAL '10' SECOND
-          AND B1.date_time"""
-Q7_SELECT = "SELECT auction, price, bidder, date_time FROM q7"
 
 # NEXmark q8 (tests/test_nexmark_queries.py) as a LEFT OUTER join: an
 # INNER join never uses the rank/total of ops/join_state.py — XLA removes
@@ -222,21 +212,23 @@ def source_ddl(chunk: int, tables=("bid", "auction", "person")) -> str:
 # ---------------------------------------------------------------------------
 
 
-def host_bid_stream(seed: int, chunk: int, n_chunks: int, cols=(0, 5)):
-    """Columns of the first ``n_chunks`` bid chunks the executor-path
-    source leaf produces (the session's reader is this generator with the
-    session seed), as numpy arrays."""
+def host_bid_stream(seed: int, chunk: int, n_chunks: int):
+    """(auction, date_time) of the first ``n_chunks`` bid chunks the
+    executor-path source leaf produces (the session's reader is this
+    generator with the session seed), as numpy arrays."""
     import numpy as np
     from risingwave_tpu.connector.nexmark import (
         NexmarkConfig, NexmarkGenerator,
     )
     gen = NexmarkGenerator(NexmarkConfig(chunk_capacity=chunk), seed=seed)
-    out = [[] for _ in cols]
+    auctions, times = [], []
     for _ in range(n_chunks):
         ch = gen.next_bid_chunk()
-        for o, c in zip(out, cols):
-            o.append(np.asarray(ch.columns[c].data))
-    return [np.concatenate(o) if o else np.zeros(0, np.int64) for o in out]
+        auctions.append(np.asarray(ch.columns[0].data))
+        times.append(np.asarray(ch.columns[5].data))
+    if not auctions:
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    return np.concatenate(auctions), np.concatenate(times)
 
 
 def device_bid_stream(seed: int, chunk: int, k: int, ticks: int):
@@ -279,23 +271,6 @@ def ref_q5(auction, date_time):
     keys = np.stack([ws, auction], axis=1)
     uniq, counts = np.unique(keys, axis=0, return_counts=True)
     return np.concatenate([uniq, counts[:, None]], axis=1).astype(np.int64)
-
-
-def ref_q7(seed: int, chunk: int, n_chunks: int) -> list:
-    import numpy as np
-    auction, price, bidder, ts = host_bid_stream(
-        seed, chunk, n_chunks, cols=(0, 2, 1, 5))
-    we = (ts // WINDOW_US) * WINDOW_US + WINDOW_US
-    win_max: dict = {}
-    for w in np.unique(we):
-        win_max[int(w)] = int(price[we == w].max())
-    rows = []
-    for w, mx in win_max.items():
-        hit = (price == mx) & (ts >= w - WINDOW_US) & (ts <= w)
-        for i in np.nonzero(hit)[0]:
-            rows.append((int(auction[i]), int(price[i]), int(bidder[i]),
-                         int(ts[i])))
-    return sorted(rows)
 
 
 def side_stream_rows(seed: int, chunk: int, n_chunks: int) -> tuple:
@@ -464,14 +439,14 @@ def phase_kernels(sz: dict, seed: int, on_tpu: bool) -> None:
 
 
 def open_session(data_dir: str, sz: dict, seed: int, coschedule: bool,
-                 mesh: int = 0, chunk: int = 0, chunks_per_tick: int = 0):
+                 mesh: int = 0):
     """The served path: a Session over a layered config, as `rw.toml`
     would give it (common/config.load_config)."""
     from risingwave_tpu.common.config import load_config
     from risingwave_tpu.frontend import Session
     overrides = {
         "streaming.checkpoint_frequency": CHECKPOINT_FREQUENCY,
-        "streaming.chunk_capacity": chunk or sz["chunk"],
+        "streaming.chunk_capacity": sz["chunk"],
         "streaming.agg_table_capacity": sz["agg_slots"],
         "streaming.join_key_capacity": sz["join_keys"],
         "streaming.join_bucket_width": sz["join_width"],
@@ -481,7 +456,7 @@ def open_session(data_dir: str, sz: dict, seed: int, coschedule: bool,
     if mesh:
         overrides["streaming.mesh_shape"] = mesh
     return Session(rw_config=load_config(None, **overrides), seed=seed,
-                   chunks_per_tick=chunks_per_tick or sz["chunks_per_tick"])
+                   chunks_per_tick=sz["chunks_per_tick"])
 
 
 def timed_ticks(s, n: int) -> dict:
@@ -553,11 +528,14 @@ def pipeline_nodes(job):
                 stack.append(child)
 
 
-def join_mv_runs_rank_kernel(s, chunk: int) -> bool:
-    """Is the Pallas rank kernel in the program q8's join step lowers to
-    on THIS backend? (The selector's choice, read off the executor's own
-    JoinCore — the auction side's pass is the one that needs rank/total
-    for the LEFT OUTER transitions.)"""
+def join_step_rank_kernel(s, chunk: int) -> dict:
+    """Did the Pallas rank kernel survive into the COMPILED program of
+    q8's join step on this backend? XLA removes a dead call after
+    lowering (an INNER join's), so only the compiled text can tell. The
+    step is the executor's own jitted one (the auction side's pass is the
+    one that needs rank/total for the LEFT OUTER transitions); called
+    after the ticks, its compile is a persistent-cache hit when these are
+    the shapes the ticks ran."""
     import jax
     from risingwave_tpu.common.chunk import make_chunk
     from risingwave_tpu.stream.hash_join import HashJoinExecutor
@@ -569,10 +547,10 @@ def join_mv_runs_rank_kernel(s, chunk: int) -> bool:
     shape = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
         (join.state, make_chunk(join.right.schema, [], capacity=chunk)))
-    text = jax.jit(
-        lambda st, ch: join.core.apply_chunk(st, ch, side="right")
-    ).lower(*shape).as_text()
-    return "tpu_custom_call" in text
+    misses = COMPILE["cache_misses"]
+    text = join._apply["right"].lower(*shape, None).compile().as_text()
+    return {"in_compiled_program": "tpu_custom_call" in text,
+            "compile_was_cache_hit": COMPILE["cache_misses"] == misses}
 
 
 def phase_sql_and_recover(sz: dict, seed: int, root: str,
@@ -594,11 +572,11 @@ def phase_sql_and_recover(sz: dict, seed: int, root: str,
         a.run_sql(Q8_SQL)
         check(not a.metrics()["coschedule"]["jobs"],
               "executor session co-scheduled an MV")
-        rank_in_join = join_mv_runs_rank_kernel(a, chunk)
-        check(rank_in_join == on_tpu,
-              f"q8's join step lowers with rank kernel={rank_in_join} "
-              f"on platform tpu={on_tpu}")
         tick_a = timed_ticks(a, ticks)
+        rank = join_step_rank_kernel(a, chunk)
+        check(rank["in_compiled_program"] == on_tpu,
+              f"q8's compiled join step holds the rank kernel: "
+              f"{rank['in_compiled_program']}, on platform tpu={on_tpu}")
         res = replay.check_executor_session(a, ticks, "sql")
         # B: the same q5 MV with [streaming] coschedule = true — the
         # fused epoch with donated state. It draws its events from the
@@ -624,8 +602,7 @@ def phase_sql_and_recover(sz: dict, seed: int, root: str,
             join_bucket_width=sz["join_width"],
             person_auction_rows_per_chunk=list(side_rows(chunk)),
             checkpoint_row_codec=codec,
-            q8_join_step_rank_path=("pallas-compiled" if rank_in_join
-                                    else "jnp (not a TPU)"),
+            q8_join_step_rank_kernel=rank,
             executor_ticks=tick_a, coscheduled_ticks=tick_b, **res)
 
     with Phase("recover") as ph:
@@ -680,16 +657,32 @@ def phase_cache(cache_dir: str) -> None:
             wall_s=round(time.perf_counter() - T0, 3))
 
 
+def executor_state_leaves(job) -> list:
+    """Every device array of the state a job's executors hold (on a mesh,
+    the sharded engines of parallel/: ShardedHashAgg, ShardedHashJoin)."""
+    import jax
+    return [x for node in pipeline_nodes(job)
+            for x in jax.tree_util.tree_leaves(getattr(node, "state", None))
+            if hasattr(x, "sharding")]
+
+
 def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
-    """--chips 4: the q5 and q7 MVs on a 4-device mesh against the same
-    MVs on one chip of the same host."""
+    """--chips 4: the q5 and q8 MVs of the one-chip phases, at the same
+    size, on an n-device mesh against the same MVs on one chip of the
+    same host."""
     import jax
     import numpy as np
 
-    chunk, k = sz["chunk"], sz["chunks_per_tick"]
-    jchunk, jk, jticks = (sz["mesh_chunk"], sz["mesh_chunks_per_tick"],
-                          sz["mesh_ticks"])
-    ticks = sz["ticks"]
+    chunk, k, ticks = sz["chunk"], sz["chunks_per_tick"], sz["ticks"]
+
+    def spread_over(devices: int, label: str, leaves: list,
+                    out: dict) -> None:
+        spread = sorted({len(x.sharding.device_set) for x in leaves})
+        check(spread == [devices], f"{label} state leaves live on "
+              f"{spread} devices, not {devices}")
+        out[f"{label}_state_leaves"] = len(leaves)
+        out[f"{label}_state_bytes_per_device"] = sum(
+            x.addressable_shards[0].data.nbytes for x in leaves)
 
     def run_mvs(mesh: int, tag: str) -> dict:
         out = {}
@@ -699,49 +692,32 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
                          coschedule=True, mesh=mesh)
         s.run_sql(source_ddl(chunk, tables=("bid",)))
         s.run_sql(Q5_SQL)
-        t0 = time.perf_counter()
-        for _ in range(ticks):
-            s.tick()
-        out["q5_seconds"] = round(time.perf_counter() - t0, 3)
+        out["q5_ticks"] = timed_ticks(s, ticks)
         out["q5"] = s.run_sql(Q5_SELECT)
         if mesh:
             check(not s.metrics()["coschedule"]["jobs"]
                   and len(s._shardfused_engines) == 1,
                   "mesh session did not take the sharded fused epoch")
             group = s._shardfused_engines["q5"][3]
-            leaves = jax.tree_util.tree_leaves(group.stacked)
-            spread = sorted({len(x.sharding.device_set) for x in leaves})
-            check(spread == [mesh],
-                  f"q5 state leaves live on {spread} devices, not {mesh}")
-            out["q5_state_leaves"] = len(leaves)
-            out["q5_state_bytes_per_device"] = sum(
-                x.addressable_shards[0].data.nbytes for x in leaves)
+            spread_over(mesh, "q5",
+                        jax.tree_util.tree_leaves(group.stacked), out)
         else:
             check(s.metrics()["coschedule"]["jobs"] == 1,
                   "one-chip session did not take the fused epoch")
         s.close()
-        # q7: the join MV through the executors — mesh-sharded executors
-        # (parallel/) when mesh_shape is set
-        s = open_session(os.path.join(root, f"q7_{tag}"), sz, seed,
-                         coschedule=True, mesh=mesh, chunk=jchunk,
-                         chunks_per_tick=jk)
-        s.run_sql(source_ddl(jchunk, tables=("bid",)))
-        s.run_sql(Q7_SQL)
-        t0 = time.perf_counter()
-        for _ in range(jticks):
-            s.tick()
-        out["q7_seconds"] = round(time.perf_counter() - t0, 3)
-        out["q7"] = sorted(s.run_sql(Q7_SELECT))
-        if mesh:
-            spreads = {
-                len(x.sharding.device_set)
-                for node in pipeline_nodes(s.jobs["q7"])
-                for x in jax.tree_util.tree_leaves(
-                    getattr(node, "state", None))
-                if hasattr(x, "sharding")}
-            check(mesh in spreads,
-                  f"q7 executor state lives on {sorted(spreads)} devices")
-            out["q7_state_device_sets"] = sorted(spreads)
+        # q8 LEFT OUTER: the join MV through the executors — the
+        # mesh-sharded executors of parallel/ (two sharded aggs feeding
+        # the sharded join, rank kernel under shard_map) when mesh_shape
+        # is set
+        s = open_session(os.path.join(root, f"q8_{tag}"), sz, seed,
+                         coschedule=True, mesh=mesh)
+        s.run_sql(source_ddl(chunk, tables=("auction", "person")))
+        s.run_sql(Q8_SQL)
+        out["q8_ticks"] = timed_ticks(s, ticks)
+        out["q8_checkpoints"] = s.epoch // CHECKPOINT_FREQUENCY
+        out["q8"] = sort_q8(s.run_sql(Q8_SELECT))
+        spread_over(mesh or 1, "q8", executor_state_leaves(s.jobs["q8"]),
+                    out)
         s.close()
         return out
 
@@ -752,16 +728,18 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
         check(got5.shape == exp5.shape and bool(np.array_equal(got5, exp5)),
               f"q5 on {n} chips differs from q5 on one chip "
               f"({got5.shape[0]} vs {exp5.shape[0]} groups)")
-        check(many["q7"] == one["q7"] and len(one["q7"]) > 0,
-              f"q7 on {n} chips differs from q7 on one chip "
-              f"({len(many['q7'])} vs {len(one['q7'])} rows)")
+        check(many["q8"] == one["q8"],
+              f"q8 on {n} chips differs from q8 on one chip "
+              f"({len(many['q8'])} vs {len(one['q8'])} rows)")
         # and both against the plain host recomputation
         auction, ts = device_bid_stream(seed, chunk, k, ticks)
         q5 = check_q5("mesh q5", many["q5"], auction, ts)
-        exp7 = ref_q7(seed, jchunk, jticks * jk)
-        check(many["q7"] == exp7,
-              f"q7 differs from the host recomputation "
-              f"({len(many['q7'])} vs {len(exp7)} rows)")
+        exp8 = ref_q8(*side_stream_rows(seed, chunk, ticks * k))
+        check(many["q8"] == exp8,
+              f"q8 differs from the host recomputation "
+              f"({len(many['q8'])} vs {len(exp8)} rows)")
+        matched = sum(1 for r in exp8 if r[3] is not None)
+        check(matched > 0, "mesh q8: no matched row proves nothing")
         mem = []
         for d in jax.devices():
             stats = d.memory_stats() or {}
@@ -769,12 +747,16 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
                         "bytes_in_use": stats.get("bytes_in_use"),
                         "peak_bytes_in_use": stats.get(
                             "peak_bytes_in_use")})
+        rows = ("q5", "q8")
         ph.info.update(
-            chips=n, q5=q5, q5_bid_events=ticks * k * chunk,
-            q7_rows=len(exp7), q7_bid_events=jticks * jk * jchunk,
+            chips=n, barriers=ticks, chunk_rows=chunk, chunks_per_barrier=k,
+            checkpoint_frequency=CHECKPOINT_FREQUENCY,
+            q5=q5, q5_bid_events=ticks * k * chunk,
+            q8_join_rows=len(exp8), q8_matched_rows=matched,
+            person_auction_rows_per_chunk=list(side_rows(chunk)),
             equal_rows_mesh_vs_one_chip=True,
-            one_chip={x: one[x] for x in one if x not in ("q5", "q7")},
-            mesh={x: many[x] for x in many if x not in ("q5", "q7")},
+            one_chip={x: one[x] for x in one if x not in rows},
+            mesh={x: many[x] for x in many if x not in rows},
             memory_stats=mem)
 
 

@@ -119,10 +119,18 @@ class RemoteWorker:
                 continue
             chunk = os.read(fd, 4096)
             if not chunk:
+                # stdout closed before WORKER_READY: the worker is on its
+                # way out. Reap it inside the spawn deadline; one that
+                # closed stdout yet lingers is killed, not waited on
+                try:
+                    rc = self.proc.wait(
+                        timeout=max(0.05, deadline - time.monotonic()))
+                except subprocess.TimeoutExpired:
+                    self.proc.kill()
+                    rc = self.proc.wait()
                 raise WorkerDied(
                     f"worker {self.worker_id} exited during startup "
-                    f"(rc={self.proc.wait()}; its own error is on "
-                    "stderr above)")
+                    f"(rc={rc}; its own error is on stderr above)")
             buf += chunk
             for line in buf.decode(errors="replace").splitlines():
                 if line.startswith("WORKER_READY"):

@@ -79,13 +79,12 @@ def _kernel(ident_col_ref, ident_row_ref, m_ref, r_ref, t_ref):
     col_j = j0 + jax.lax.broadcasted_iota(jnp.int32, (ti, tj), 1)
     lower = eq & (col_j < row_i)
     mf = m_ref[:]
-    # typed float32 constants (under x64 a bare 1.0 is a float64, which
-    # Mosaic has no layout for); the select runs at the 32-bit layout of
-    # the int32 compare's mask and only its result narrows to bfloat16
+    # typed float32 constants: under x64 a bare 1.0 is a float64, which
+    # Mosaic has no layout for
     one, zero = jnp.float32(1), jnp.float32(0)
-    r_ref[:] += jnp.dot(jnp.where(lower, one, zero).astype(mf.dtype), mf,
+    r_ref[:] += jnp.dot(jnp.where(lower, one, zero), mf,
                         preferred_element_type=jnp.float32)
-    t_ref[:] += jnp.dot(jnp.where(eq, one, zero).astype(mf.dtype), mf,
+    t_ref[:] += jnp.dot(jnp.where(eq, one, zero), mf,
                         preferred_element_type=jnp.float32)
 
 
@@ -101,8 +100,6 @@ def rank_totals_pallas_call(ident: jax.Array, matches: jax.Array,
     tj = min(TILE_J, n)
     grid = (n // ti, n // tj)
     z = np.int32(0)     # block indices are int32 (a bare 0 is int64 here)
-    # 0/1 operands are exact in bfloat16 and the MXU accumulates in
-    # float32, so counts stay exact up to 2^24 rows
     return pl.pallas_call(
         _kernel,
         grid=grid,
@@ -121,7 +118,7 @@ def rank_totals_pallas_call(ident: jax.Array, matches: jax.Array,
         ],
         interpret=interpret,
     )(ident.reshape(n, 1), ident.reshape(1, n),
-      matches.astype(jnp.bfloat16))
+      matches.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

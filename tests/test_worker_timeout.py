@@ -88,3 +88,26 @@ def test_worker_that_cannot_get_its_platform_fails_fast(tmp_path,
         w.spawn()
     assert time.monotonic() - t0 < RemoteWorker.SPAWN_TIMEOUT_S / 2
     assert w.proc.poll() not in (None, 0)
+
+
+def test_worker_that_closes_stdout_but_lingers_is_killed(tmp_path,
+                                                         monkeypatch):
+    """The spawn deadline also bounds the reap: a worker that closes
+    stdout before WORKER_READY and then does not exit is killed when the
+    deadline passes, instead of blocking ``spawn()`` in ``wait()``."""
+    import sys
+    import time
+
+    from risingwave_tpu.frontend.remote import RemoteWorker, WorkerDied
+
+    fake = tmp_path / "lingering_worker.sh"
+    fake.write_text("#!/bin/sh\nexec 1>&-\nsleep 60\n")
+    fake.chmod(0o755)
+    monkeypatch.setattr(sys, "executable", str(fake))
+    monkeypatch.setattr(RemoteWorker, "SPAWN_TIMEOUT_S", 2.0)
+    w = RemoteWorker(str(tmp_path), 0, loop=None)
+    t0 = time.monotonic()
+    with pytest.raises(WorkerDied, match="exited during startup"):
+        w.spawn()
+    assert time.monotonic() - t0 < 10
+    assert w.proc.poll() is not None          # reaped, nothing left running
