@@ -140,7 +140,7 @@ def main(argv=None) -> int:
                                       "metrics", "trace", "backup",
                                       "restore", "backup-info",
                                       "hummock", "vacuum", "cluster",
-                                      "profile", "bench", "udf", "meta"])
+                                      "profile", "udf", "meta"])
     ctl.add_argument("sub", nargs="?", default=None,
                      help="subcommand for `ctl cluster` "
                      "(fragments — dump the persisted fragment→worker "
@@ -150,9 +150,7 @@ def main(argv=None) -> int:
                      "plane's policy state and executed migrations), "
                      "`ctl profile` (roofline — AOT cost/memory "
                      "analysis of every registered fused surface "
-                     "against the chip roofline, chip-free) and `ctl bench` "
-                     "(trend — per-field trend with regression flags "
-                     "over the checked-in BENCH_r*.json records), and "
+                     "against the chip roofline, chip-free), "
                      "`ctl udf` (serve — run a standalone out-of-process "
                      "UDF server in the foreground; sessions attach via "
                      "[udf] addr = \"host:port\" — docs/robustness.md), "
@@ -174,13 +172,13 @@ def main(argv=None) -> int:
                      "`ctl cluster rescale` (docs/scaling.md)")
     ctl.add_argument("--data-dir", default=None,
                      help="durable data dir (required for every ctl "
-                     "command except `profile`, `bench` and `udf`, "
+                     "command except `profile` and `udf`, "
                      "which read no cluster state)")
     ctl.add_argument("--port", type=int, default=0,
                      help="udf serve: listen port (0 = ephemeral, "
                      "printed as UDF_READY <port>)")
     ctl.add_argument("--json", action="store_true",
-                     help="profile/bench/trace barrier: emit the full "
+                     help="profile/trace barrier: emit the full "
                      "JSON report instead of the table")
     ctl.add_argument("--inflight", action="store_true",
                      help="trace barrier: walk the LIVE in-flight "
@@ -198,12 +196,6 @@ def main(argv=None) -> int:
                      help="profile roofline: analyze ONE registered "
                      "fused surface (e.g. source_session, "
                      "sharded:group_agg) instead of the whole ladder")
-    ctl.add_argument("--tolerance", type=float, default=0.2,
-                     help="bench trend: relative move off the best "
-                     "prior value that flags a regression")
-    ctl.add_argument("--bench-dir", default=".",
-                     help="bench trend: directory holding "
-                     "BENCH_r*.json / BENCH_partial.json")
     ctl.add_argument("--backup-dir",
                      help="backup location for backup/restore/backup-info")
     ctl.add_argument("--workers", type=int, default=0,
@@ -262,11 +254,6 @@ def _ctl(args) -> int:
             raise SystemExit("usage: ctl profile roofline "
                              "[--peak-flops F --peak-bandwidth B --json]")
         return _ctl_profile_roofline(args, _json)
-    if args.what == "bench":
-        if args.sub != "trend":
-            raise SystemExit("usage: ctl bench trend "
-                             "[--bench-dir DIR --tolerance T --json]")
-        return _ctl_bench_trend(args, _json)
     if args.what == "udf":
         if args.sub != "serve":
             raise SystemExit("usage: ctl udf serve [--port N]")
@@ -632,28 +619,6 @@ def _ctl_profile_roofline(args, _json) -> int:
         print(_json.dumps(report, indent=2))
     else:
         print(render_roofline_table(report))
-    return 0
-
-
-def _ctl_bench_trend(args, _json) -> int:
-    """`ctl bench trend`: fold every checked-in BENCH_r*.json round and
-    BENCH_partial.json phase record into a per-field trend, flagging
-    fields whose latest value regressed past ``--tolerance`` off the
-    best prior value — ROADMAP item 5's "regressions in ANY plane show
-    up as a trend"."""
-    from .common.profiling import (
-        bench_trend, load_bench_history, render_trend_table,
-    )
-    history = load_bench_history(args.bench_dir)
-    if not history:
-        raise SystemExit(
-            f"no BENCH_r*.json / BENCH_partial.json under "
-            f"{args.bench_dir!r}")
-    trend = bench_trend(history, tolerance=args.tolerance)
-    if args.json:
-        print(_json.dumps(trend, indent=2))
-    else:
-        print(render_trend_table(trend))
     return 0
 
 

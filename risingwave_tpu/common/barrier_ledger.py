@@ -19,17 +19,21 @@ Two pieces:
   ``barrier_stages`` key with the same seq/ack outbox discipline as the
   span outbox), so stage events ride frames the session already sends —
   zero added dispatches, zero extra RPCs, nothing on the critical tick
-  path beyond a perf_counter delta and a list append.
+  path beyond a clock delta and a list append. Events are written by
+  ``common/tracing.span(..., stage=...)``: a stage IS a span's duration.
 
-* ``BarrierLedger`` — the session-owned history ring. The conductor
-  records its own stages (inject / pending / collect / commit) directly
-  with perf_counter deltas; storage, sink and worker stages fold in from
-  the stage-event logs (the session's own, synchronously at barrier
-  completion; the workers', via stats federation — late events find
-  their record in the ring and attach there).
+* ``BarrierLedger`` — the session-owned history ring. Every stage folds
+  in from the stage-event logs (the session's own, synchronously at
+  barrier completion — so a stage recorded BEFORE the record opens, the
+  source feed or the fused epoch's dispatch, still reaches it; the
+  workers', via stats federation — late events find their record in the
+  ring and attach there). Only ``pending`` is recorded directly: it is
+  the gap between two spans, not a span. A record also carries
+  ``tick_ms``, the whole of the ``Session.tick()`` call that injected the
+  barrier, and ``compiles``, the XLA compilations that ran inside it.
 
-Stage vocabulary (stable: Prometheus labels, rw_catalog columns and
-bench trend fields all key on it):
+Stage vocabulary (stable: Prometheus labels and rw_catalog columns key
+on it):
 
     inject            conductor: queue pushes + remote barrier frames
     pending           conductor: injected, waiting its turn to complete
@@ -40,13 +44,21 @@ bench trend fields all key on it):
     storage_commit    any process: segment append (epoch encode+publish)
     sink_deliver      sink executor: external delivery inside on_barrier
     worker_collect    worker conductor: its jobs' barrier collection
+    source_feed       conductor: every source generates + queues its chunks
+    epoch_dispatch    conductor: fused epochs + flush probes enqueued
+    epoch_wait        conductor: waiting on the device for the flush fetch
+    flush_decode      conductor: flush gathers pushed to the MV queues
+    state_delta       any process: an agg's state-table delta (checkpoint)
+
+The last five lie OUTSIDE ``total_ms`` on the co-scheduled path (the
+fused epoch runs before the barrier is injected) and inside ``collect``
+on the executor path (``state_delta`` only); ``tick_ms`` spans them all.
 """
 
 from __future__ import annotations
 
 import collections
 import threading
-import time
 from typing import Optional
 
 #: conductor-side stages whose sum reconciles with the epoch's total
@@ -56,7 +68,13 @@ CONDUCTOR_STAGES = ("pending", "collect", "commit")
 #: every stage the ledger may see, in waterfall order
 ALL_STAGES = ("inject", "pending", "collect", "commit",
               "storage_prepare", "storage_settle", "storage_commit",
-              "sink_deliver", "worker_collect")
+              "sink_deliver", "worker_collect",
+              "source_feed", "epoch_dispatch", "epoch_wait", "flush_decode",
+              "state_delta")
+
+
+#: per-record counters an event may bump (``StageEventLog.count``)
+COUNTERS = ("compiles",)
 
 
 class StageEventLog:
@@ -76,6 +94,13 @@ class StageEventLog:
         with self._lock:
             self._events.append(
                 {"epoch": int(epoch), "stage": stage, "ms": float(ms)})
+
+    def count(self, epoch: int, counter: str, n: int = 1) -> None:
+        """One occurrence of something countable inside an epoch (a
+        compile): folds into the record's counter of that name."""
+        with self._lock:
+            self._events.append(
+                {"epoch": int(epoch), "counter": counter, "n": int(n)})
 
     def drain(self) -> list:
         """Take-and-clear — the session consumes its own log this way at
@@ -119,24 +144,6 @@ def record_stage(epoch: Optional[int], stage: str, ms: float) -> None:
     GLOBAL_STAGES.record(epoch, stage, ms)
 
 
-class timed_stage:
-    """``with timed_stage(epoch, "storage_commit"):`` — perf_counter
-    around the body, recorded into the process-global log."""
-
-    def __init__(self, epoch: Optional[int], stage: str):
-        self.epoch = epoch
-        self.stage = stage
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        record_stage(self.epoch, self.stage,
-                     (time.perf_counter() - self._t0) * 1e3)
-        return False
-
-
 class BarrierLedger:
     """Session-owned bounded history ring of per-barrier waterfall
     records, plus per-stage p50/p99 aggregates.
@@ -144,7 +151,10 @@ class BarrierLedger:
     A record::
 
         {"epoch": int, "checkpoint": bool, "injected_at": wall_ts,
+         "injected_ns": the same instant on the spans' clock,
          "total_ms": float, "result": "ok" | "failed",
+         "tick_ms": float,                   # the injecting tick() call
+         "compiles": int,                    # XLA compilations inside it
          "stages": {stage: ms},              # summed across processes
          "workers": {wid: {stage: ms}}}      # per-process detail
 
@@ -163,11 +173,14 @@ class BarrierLedger:
 
     # -- assembly --------------------------------------------------------------
 
-    def begin(self, epoch: int, checkpoint: bool, wall_ts: float) -> None:
+    def begin(self, epoch: int, checkpoint: bool, wall_ts: float,
+              mono_ns: Optional[int] = None) -> None:
         with self._lock:
             self._open[epoch] = {
                 "epoch": int(epoch), "checkpoint": bool(checkpoint),
-                "injected_at": wall_ts, "total_ms": None, "result": None,
+                "injected_at": wall_ts, "injected_ns": mono_ns,
+                "total_ms": None, "result": None,
+                "tick_ms": None, "compiles": 0,
                 "stages": {}, "workers": {},
             }
 
@@ -190,12 +203,30 @@ class BarrierLedger:
             per = rec["workers"].setdefault(int(worker), {})
             per[stage] = per.get(stage, 0.0) + float(ms)
 
+    def count(self, epoch: int, counter: str, n: int = 1) -> None:
+        """Bump one of the record's ``COUNTERS``."""
+        with self._lock:
+            rec = self._find(epoch)
+            if rec is not None and counter in COUNTERS:
+                rec[counter] += int(n)
+
+    def set_tick_ms(self, epoch: int, ms: float) -> None:
+        """The whole ``tick()`` call that injected ``epoch`` (it ends
+        after the record is sealed, so it attaches late)."""
+        with self._lock:
+            rec = self._find(epoch)
+            if rec is not None:
+                rec["tick_ms"] = round(float(ms), 3)
+
     def ingest_events(self, events, worker: int = -1) -> None:
         """Fold a batch of stage-event dicts (a drained StageEventLog —
         the session's own, or one federated off a worker's stats
         reply)."""
         for ev in events or ():
             try:
+                if "counter" in ev:
+                    self.count(int(ev["epoch"]), ev["counter"], ev["n"])
+                    continue
                 self.stage(int(ev["epoch"]), str(ev["stage"]),
                            float(ev["ms"]), worker=worker)
             except (KeyError, TypeError, ValueError):

@@ -9,6 +9,12 @@ a pid or the time never hits across runs.
 Deliberately free of JAX (and of every other import of this package) at
 module level: ``bench.py``'s parent process, which must never import
 JAX, loads this file by path.
+
+``install_compile_listener`` also makes every compilation visible where
+the rest of a barrier is: one ``xla.compile`` span in the trace ring,
+stamped with the epoch the conductor is ticking, and ``compiles`` + 1 on
+that epoch's barrier-ledger record. A compile inside a steady-state
+barrier is a stall someone has to explain.
 """
 
 from __future__ import annotations
@@ -42,3 +48,49 @@ def enable_compile_cache() -> str:
     if not os.environ.get(_ENV):
         jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
     return compile_cache_dir()
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_listening = False
+
+
+def install_compile_listener() -> None:
+    """Register, once per process, the ``jax.monitoring`` listeners that
+    turn each backend compilation into an ``xla.compile`` span (args:
+    ``seconds``, ``cache`` = hit / miss / off, ``fun_name``). JAX hands
+    the duration over when the compile is done, so the span is recorded
+    after the fact, ending now."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring
+
+    from . import tracing
+    from .barrier_ledger import GLOBAL_STAGES
+
+    cache = "off"        # what the persistent cache said of this compile
+
+    def on_event(event: str, **_kw) -> None:
+        nonlocal cache
+        if event in _CACHE_EVENTS:
+            cache = _CACHE_EVENTS[event]
+
+    def on_duration(event: str, secs: float, **kw) -> None:
+        nonlocal cache
+        if event != _COMPILE_EVENT:
+            return
+        epoch = tracing.conductor_epoch()
+        dur_ns = int(secs * 1e9)
+        tracing.record_span(
+            "xla.compile", tracing.now_ns() - dur_ns, dur_ns, epoch=epoch,
+            cat=tracing.CAT_DISPATCH, tid="compile", seconds=secs,
+            cache=cache, fun_name=str(kw.get("fun_name", "")))
+        cache = "off"
+        if epoch is not None:
+            GLOBAL_STAGES.count(epoch, "compiles")
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)

@@ -82,7 +82,7 @@ class StreamingConfig:
     # slow_epoch_threshold_ms (kept so existing configs keep working;
     # an explicitly-set [observability] value wins — see
     # ObservabilityConfig below)
-    trace_ring_capacity: int = 4096
+    trace_ring_capacity: int = 16384
     slow_epoch_threshold_ms: float = 0.0
 
 
@@ -290,7 +290,8 @@ class ObservabilityConfig:
     # that used to live only on [streaming] (which still works as a
     # legacy alias). Unset (None) inherits the alias; ANY value set
     # here wins, including one equal to the alias default (effective
-    # defaults: 4096 spans, 0.0 = detector off)
+    # defaults: 16384 spans — 400 barriers at up to 40 spans each, see
+    # docs/observability.md "Sizing the ring" — and 0.0 = detector off)
     trace_ring_capacity: Optional[int] = None
     slow_epoch_threshold_ms: Optional[float] = None
     # barrier observatory (common/barrier_ledger.py): how many sealed
@@ -353,7 +354,6 @@ class MetaConfig:
 class ServerConfig:
     host: str = "127.0.0.1"
     port: int = 4566
-    telemetry_enabled: bool = False         # reference: telemetry/
 
 
 @dataclasses.dataclass

@@ -36,23 +36,14 @@ same qualnames ``common/dispatch_count.py`` and the
   of each analyzed kernel against configurable chip peak flops and
   HBM bandwidth — the artifact ROADMAP item 1's "measured roofline
   analysis" demands (``ctl profile roofline``).
-* ``load_bench_history`` / ``bench_trend``: fold the checked-in
-  BENCH_r*.json + BENCH_partial.json records into a per-field trend
-  with regression flags (``ctl bench trend``) — ROADMAP item 5's
-  "regressions in ANY plane show up as a trend".
 """
 
 from __future__ import annotations
 
-import glob as _glob
-import json
-import os
-import re
 import threading
-import time
 from typing import Any, Callable, Optional
 
-from .tracing import CAT_DISPATCH, GLOBAL_TRACE, Span
+from .tracing import CAT_DISPATCH, now_ns, span
 
 
 class DispatchRecord:
@@ -128,8 +119,8 @@ class DispatchProfiler:
     """Process-global dispatch telemetry registry.
 
     Enabled by default: the hot path per dispatch is one enabled check,
-    two ``perf_counter`` reads, an executable-cache-size probe and a
-    handful of attribute bumps — microseconds against a dispatch that
+    one ``tracing.span``, an executable-cache-size probe and a handful of
+    attribute bumps — microseconds against a dispatch that
     crosses into XLA. ``[observability] profiling = false`` turns the
     wrapper into a single-attribute-check passthrough."""
 
@@ -178,15 +169,17 @@ class DispatchProfiler:
             if name not in profiler._lowerable:
                 profiler._remember_aval(name, jitted, args, kwargs)
             before = cache_size() if cache_size is not None else None
-            ts = time.time()
-            t0 = time.perf_counter()
-            out = jitted(*args, **kwargs)
-            dt = time.perf_counter() - t0
+            # a short dispatch stays out of the ring (span_min_ms); its
+            # annotation is in a profiler's trace either way
+            with span(name, epoch=profiler.epoch, cat=CAT_DISPATCH,
+                      tid="dispatch", min_ms=profiler.span_min_ms) as sp:
+                out = jitted(*args, **kwargs)
+            dt = sp.dur_ns / 1e9
             rec.calls += 1
             # enqueue timestamp for completion latency (resolved when a
             # fetch future over this dispatch's outputs lands)
             if len(rec.inflight) < DispatchRecord.INFLIGHT_CAP:
-                rec.inflight.append(t0)
+                rec.inflight.append(sp.start_ns)
             rec.total_s += dt
             rec.last_s = dt
             if dt > rec.max_s:
@@ -197,10 +190,6 @@ class DispatchProfiler:
             elif before is None and rec.calls == 1:
                 rec.compiles += 1       # no cache probe: first call compiles
                 rec.compile_s += dt
-            if dt * 1e3 >= profiler.span_min_ms:
-                GLOBAL_TRACE.record(Span(
-                    name, CAT_DISPATCH, ts, dt, epoch=profiler.epoch,
-                    tid="dispatch"))
             return out
 
         wrapper.__qualname__ = name
@@ -229,7 +218,7 @@ class DispatchProfiler:
         if rec is None or not rec.inflight:
             return
         depth = len(rec.inflight)
-        dt = time.perf_counter() - rec.inflight.pop(0)
+        dt = (now_ns() - rec.inflight.pop(0)) / 1e9
         rec.complete_calls += 1
         rec.complete_s += dt
         rec.complete_last_s = dt
@@ -525,131 +514,4 @@ def render_roofline_table(report: dict) -> str:
         f"{report['peak_bandwidth_bytes_per_s'] / 1e9:.0f} GB/s, "
         f"critical intensity {report['critical_intensity']:.1f} "
         "flops/byte)")
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
-# bench trend
-# ---------------------------------------------------------------------------
-
-#: substrings marking a field where LOWER is better (latency-like);
-#: everything else numeric is treated as higher-is-better (rates)
-_LOWER_BETTER = ("p50", "p90", "p99", "latency", "pause", "_ms",
-                 "duration", "seconds")
-
-
-def _lower_is_better(field: str) -> bool:
-    f = field.lower()
-    return any(m in f for m in _LOWER_BETTER)
-
-
-def _numeric_fields(rec: dict, prefix: str = "") -> dict:
-    out: dict = {}
-    for k, v in rec.items():
-        if isinstance(v, bool) or k in ("n", "rc"):
-            continue
-        if isinstance(v, (int, float)):
-            out[prefix + k] = float(v)
-        elif isinstance(v, dict):
-            out.update(_numeric_fields(v, prefix + k + "."))
-    return out
-
-
-def load_bench_history(root: str = ".") -> list:
-    """Checked-in bench records, oldest first: every BENCH_r*.json
-    round (its ``parsed`` payload) plus every completed phase line in
-    BENCH_partial.json. Each entry: {"label", "ok", "fields"}."""
-    history: list = []
-    for path in sorted(_glob.glob(os.path.join(root, "BENCH_r*.json"))):
-        m = re.search(r"BENCH_r(\d+)\.json$", path)
-        try:
-            with open(path) as f:
-                rec = json.load(f)
-        except (OSError, ValueError):
-            continue
-        parsed = rec.get("parsed") or {}
-        history.append({
-            "label": f"r{m.group(1)}" if m else os.path.basename(path),
-            "ok": rec.get("rc") == 0,
-            "fields": _numeric_fields(parsed) if isinstance(parsed, dict)
-            else {},
-        })
-    partial = os.path.join(root, "BENCH_partial.json")
-    if os.path.exists(partial):
-        with open(partial) as f:
-            for i, line in enumerate(f):
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                payload = rec.get("record") or {}
-                history.append({
-                    "label": f"partial:{rec.get('phase', i)}",
-                    "ok": payload.get("rc", 0) in (0, None),
-                    "fields": _numeric_fields(payload)
-                    if isinstance(payload, dict) else {},
-                })
-    return history
-
-
-def bench_trend(history: list, tolerance: float = 0.2) -> dict:
-    """Per-field trend over the bench history with regression flags: the
-    LAST reported value of a field is compared against the BEST earlier
-    value; a >``tolerance`` relative move in the bad direction (down for
-    rates, up for latencies) flags the field. Rounds that failed
-    (``ok`` false) still contribute whatever fields they salvaged."""
-    series: dict = {}
-    for entry in history:
-        for field, value in entry["fields"].items():
-            series.setdefault(field, []).append((entry["label"], value))
-    fields: dict = {}
-    regressions: list = []
-    for field, points in sorted(series.items()):
-        values = [v for _, v in points]
-        latest_label, latest = points[-1]
-        lower_better = _lower_is_better(field)
-        entry = {
-            "points": [{"label": l, "value": v} for l, v in points],
-            "latest": latest,
-            "best": min(values) if lower_better else max(values),
-            "lower_is_better": lower_better,
-            "regressed": False,
-        }
-        if len(points) > 1:
-            prior = values[:-1]
-            best_prior = min(prior) if lower_better else max(prior)
-            if lower_better:
-                regressed = best_prior > 0 and \
-                    latest > best_prior * (1 + tolerance)
-            else:
-                regressed = best_prior > 0 and \
-                    latest < best_prior * (1 - tolerance)
-            if regressed:
-                entry["regressed"] = True
-                entry["vs_best"] = round(latest / best_prior, 4)
-                regressions.append(field)
-        fields[field] = entry
-    return {"rounds": [e["label"] for e in history],
-            "tolerance": tolerance,
-            "fields": fields,
-            "regressions": regressions}
-
-
-def render_trend_table(trend: dict) -> str:
-    rows = [("field", "points", "best", "latest", "flag")]
-    for field, e in trend["fields"].items():
-        flag = "REGRESSED" if e["regressed"] else ""
-        rows.append((field, str(len(e["points"])),
-                     f"{e['best']:.6g}", f"{e['latest']:.6g}", flag))
-    widths = [max(len(r[i]) for r in rows) for i in range(len(rows[0]))]
-    lines = ["  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
-             for r in rows]
-    if trend["regressions"]:
-        lines.append(f"regressions (> {trend['tolerance']:.0%} off best): "
-                     + ", ".join(trend["regressions"]))
-    else:
-        lines.append("no regressions flagged")
     return "\n".join(lines)

@@ -290,6 +290,10 @@ class Session:
         from ..common.barrier_ledger import BarrierLedger
         self._barrier_ledger = BarrierLedger(
             self.observability.barrier_history_capacity)
+        # every XLA compilation becomes an `xla.compile` span of the
+        # epoch it stalls (once per process)
+        from ..common.compile_cache import install_compile_listener
+        install_compile_listener()
         self._worker_stage_ack: dict[int, int] = {}  # last stage_seq seen
         # device profiling plane (common/profiling.py): per-dispatch
         # telemetry + HBM ledger; pure host bookkeeping, on by default
@@ -593,7 +597,7 @@ class Session:
         self._pending_mutation: Optional[Mutation] = None
         from ..stream.metrics import LatencyRecorder
         self.barrier_latency = LatencyRecorder()
-        self._inject_time: dict[int, tuple] = {}   # epoch -> (perf, wall)
+        self._inject_time: dict[int, int] = {}     # epoch -> tracing.now_ns()
         # the session owns its event loop: jobs are long-lived tasks that
         # must survive across synchronous API calls, independent of any
         # ambient loop other code may create/close
@@ -1838,25 +1842,36 @@ class Session:
         flush stays pending into the next tick; checkpoint barriers
         (and generate-off ticks) resolve it synchronously, so committed
         state is bit-exact vs the synchronous path."""
+        from ..common.tracing import CAT_EPOCH, span
         k = self.chunks_per_tick
         groups = list(self._cosched.groups.values())
-        # 1. resolve last tick's deferred flushes (pipeline_depth >= 2)
-        for group in groups:
-            if group.pending is not None:
-                self._push_cosched_outs(group.finish_flush())
+
+        def conductor(name: str, stage: Optional[str]):
+            return span(name, epoch=epoch, stage=stage, cat=CAT_EPOCH,
+                        tid="conductor")
+
+        # 1. resolve last tick's deferred flushes (pipeline_depth >= 2);
+        #    the wait and the decode inside carry the stages
+        if any(group.pending is not None for group in groups):
+            with conductor("cosched.resolve_deferred", None):
+                for group in groups:
+                    if group.pending is not None:
+                        self._push_cosched_outs(group.finish_flush())
         # 2. enqueue every group's epoch (cross-engine overlap)
         ran = generate and k > 0
         if ran:
-            for group in groups:
-                group.run_epoch(k)
-                for j, name in enumerate(group.names):
-                    cursor = self._cosched_engines[name][2]
-                    cursor.events = group.starts[j]
-                    cursor.epochs = group.batch_nos[j]
+            with conductor("cosched.dispatch", "epoch_dispatch"):
+                for group in groups:
+                    group.run_epoch(k)
+                    for j, name in enumerate(group.names):
+                        cursor = self._cosched_engines[name][2]
+                        cursor.events = group.starts[j]
+                        cursor.epochs = group.batch_nos[j]
         # 3. enqueue every group's probe + start its packed fetch BEFORE
         #    decoding any of them
-        for group in groups:
-            group.begin_flush()
+        with conductor("cosched.flush_begin", "epoch_dispatch"):
+            for group in groups:
+                group.begin_flush()
         if self.pipeline_depth >= 2 and ran and not checkpoint:
             # 4a. defer resolution to the next tick / drain point: epoch
             # N+1 will dispatch before this packed fetch resolves
@@ -1869,10 +1884,12 @@ class Session:
                 ckpt_states = []
                 for name in group.names:
                     agg = self._cosched_engines[name][0]
-                    agg.state = group.state_of(name)
+                    with conductor("cosched.restack", "state_delta"):
+                        agg.state = group.state_of(name)
                     agg._checkpoint_to_state_table(epoch)
                     ckpt_states.append(agg.state)
-                group.set_states(ckpt_states)
+                with conductor("cosched.restack", "state_delta"):
+                    group.set_states(ckpt_states)
 
     # ------------------------------------------ tick-compiled fused MV jobs --
 
@@ -3774,10 +3791,27 @@ class Session:
         epoch = self._injected + 1
         # tag this tick's dispatch spans (common/profiling.py) so a slow
         # epoch's span-tree capture includes the dispatches that caused it
+        from ..common import tracing
         from ..common.profiling import GLOBAL_PROFILER
         GLOBAL_PROFILER.epoch = epoch
         if checkpoint is None:
             checkpoint = epoch % self.checkpoint_frequency == 0
+        # the root of the epoch's span tree covers the WHOLE call; its
+        # duration is the ledger record's tick_ms (attached late: the
+        # record is sealed inside)
+        tracing.set_conductor_epoch(epoch)
+        root = tracing.span("session.tick", epoch=epoch, cat=tracing.CAT_EPOCH,
+                            tid="conductor", checkpoint=checkpoint)
+        try:
+            with root:
+                return self._tick_body(epoch, checkpoint, generate, mutation)
+        finally:
+            tracing.set_conductor_epoch(None)
+            self._barrier_ledger.set_tick_ms(epoch, root.dur_ns / 1e6)
+
+    def _tick_body(self, epoch: int, checkpoint: bool, generate: bool,
+                   mutation: Optional[Mutation]) -> int:
+        from ..common import tracing
         # keep the worker registry in sync with the live job set (workers
         # register with last_heartbeat = the current epoch clock). With a
         # remote meta, re-anchor the epoch clock FIRST: a restarted meta
@@ -3793,15 +3827,22 @@ class Session:
             self._pending_mutation = None
         barrier = Barrier.new(epoch, checkpoint=checkpoint, mutation=mutation)
         if generate and not self.paused:
-            for feed in self.feeds:
-                if feed.job in self._dead_jobs:
-                    # a dead job consumes nothing: advancing its reader
-                    # would move offsets past rows it never processed
-                    continue
-                for _ in range(self.chunks_per_tick):
-                    chunk = feed.generator()
-                    if chunk is not None:
-                        feed.queue.push(chunk)
+            with tracing.span("source.feed", epoch=epoch,
+                              stage="source_feed", cat=tracing.CAT_EPOCH,
+                              tid="conductor") as feed_span:
+                fed = fed_rows = 0
+                for feed in self.feeds:
+                    if feed.job in self._dead_jobs:
+                        # a dead job consumes nothing: advancing its reader
+                        # would move offsets past rows it never processed
+                        continue
+                    for _ in range(self.chunks_per_tick):
+                        chunk = feed.generator()
+                        if chunk is not None:
+                            feed.queue.push(chunk)
+                            fed += 1
+                            fed_rows += chunk.capacity
+                feed_span.set(chunks=fed, capacity_rows=fed_rows)
         if self._cosched.jobs:
             # co-scheduled groups: one fused dispatch per group covers
             # every member MV's epoch; flush chunks land on the job
@@ -3819,15 +3860,15 @@ class Session:
             # across ALL chips (ops/fused_sharded.py)
             self._shardfused_tick(epoch, checkpoint,
                                   generate and not self.paused)
-        from ..common.tracing import CAT_EPOCH, trace_span
         import time as _time
         # barrier observatory: open this epoch's waterfall record and
-        # time the inject stage (host-side perf_counter only — zero
-        # added dispatches, nothing on the device path)
-        self._barrier_ledger.begin(epoch, checkpoint, _time.time())
-        _inj0 = _time.perf_counter()
-        with trace_span("barrier.inject", CAT_EPOCH, epoch=epoch,
-                        tid="conductor", checkpoint=checkpoint):
+        # time the inject stage (host-side clock only — zero added
+        # dispatches, nothing on the device path)
+        self._barrier_ledger.begin(epoch, checkpoint, _time.time(),
+                                   tracing.now_ns())
+        with tracing.span("barrier.inject", epoch=epoch, stage="inject",
+                          cat=tracing.CAT_EPOCH, tid="conductor",
+                          checkpoint=checkpoint):
             self.dml.drain_into_epoch()
             for feed in self.feeds:
                 if feed.reader is not None:
@@ -3859,10 +3900,8 @@ class Session:
                 self._await(_inject_remote())
         self._injected = epoch
         self._inflight.append((epoch, checkpoint))
-        self._barrier_ledger.stage(
-            epoch, "inject", (_time.perf_counter() - _inj0) * 1e3)
-        # (perf_counter for latency precision, wall clock for span export)
-        self._inject_time[epoch] = (_time.perf_counter(), _time.time())
+        # the barrier's latency clock starts here, after inject
+        self._inject_time[epoch] = tracing.now_ns()
         # pipelined barriers would let an upstream run AHEAD of an active
         # backfill's snapshot reads (the scan would see a later epoch's
         # staged rows and the same update would also arrive as a delta —
@@ -3992,33 +4031,29 @@ class Session:
             self._exit_mutation()
 
     def _complete_oldest_impl(self) -> None:
+        from ..common import tracing
         from ..common.barrier_ledger import GLOBAL_STAGES
-        from ..common.tracing import CAT_EPOCH, GLOBAL_TRACE, Span, trace_span
-        import time as _time
+        from ..common.tracing import CAT_EPOCH, GLOBAL_TRACE
         e, ckpt = self._inflight.pop(0)
         ledger = self._barrier_ledger
-        t_entry = _time.perf_counter()
+        t_entry = tracing.now_ns()
         _pend = self._inject_time.get(e)
         if _pend is not None:
             # pending: injected, parked in _inflight behind older epochs
             # (pipelining) — with depth 1 this is ~0 and the waterfall
             # stage sum reconciles with the barrier latency recorder
-            ledger.stage(e, "pending", (t_entry - _pend[0]) * 1e3)
+            ledger.stage(e, "pending", (t_entry - _pend) / 1e6)
         dead_before = len(self._dead_jobs)
         result = "ok"
         try:
-            with trace_span("barrier.collect", CAT_EPOCH, epoch=e,
-                            tid="conductor"):
+            with tracing.span("barrier.collect", epoch=e, stage="collect",
+                              cat=CAT_EPOCH, tid="conductor"):
                 self._await(self._collect_barrier(e))
         except BaseException:
-            ledger.stage(e, "collect",
-                         (_time.perf_counter() - t_entry) * 1e3)
             ledger.ingest_events(GLOBAL_STAGES.drain())
-            ledger.finish(e, (_time.perf_counter() - t_entry) * 1e3,
-                          "failed")
+            ledger.finish(e, (tracing.now_ns() - t_entry) / 1e6, "failed")
             self._inject_time.pop(e, None)
             raise
-        ledger.stage(e, "collect", (_time.perf_counter() - t_entry) * 1e3)
         if len(self._dead_jobs) > dead_before:
             result = "failed"        # collect declared a job dead
         if ckpt and self._dead_jobs:
@@ -4030,12 +4065,9 @@ class Session:
             for n in self._dead_jobs:
                 self.store.discard_pending_tables(self._job_state_ids(n))
         if ckpt:
-            t_commit = _time.perf_counter()
-            with trace_span("checkpoint.commit", CAT_EPOCH, epoch=e,
-                            tid="conductor"):
+            with tracing.span("checkpoint.commit", epoch=e, stage="commit",
+                              cat=CAT_EPOCH, tid="conductor"):
                 self._commit_checkpoint(e)
-            ledger.stage(e, "commit",
-                         (_time.perf_counter() - t_commit) * 1e3)
         # session-process storage/sink stage events (recorded at the 2PC
         # sites in storage/checkpoint.py and stream/sink.py) fold into
         # their records here, off the device path. Worker-side events
@@ -4044,15 +4076,17 @@ class Session:
         ledger.ingest_events(GLOBAL_STAGES.drain())
         t0 = self._inject_time.pop(e, None)
         if t0 is not None:
-            perf0, wall0 = t0
-            lat = _time.perf_counter() - perf0
+            lat_ns = tracing.now_ns() - t0
+            lat = lat_ns / 1e9
             self.barrier_latency.record(lat)
             record = ledger.finish(e, lat * 1e3, result)
-            # the whole-epoch span (inject → collect/commit): parent of
-            # this epoch's executor spans in the trace export
-            GLOBAL_TRACE.record(Span(
-                f"epoch {e}", CAT_EPOCH, wall0, lat, epoch=e,
-                tid="conductor", args={"checkpoint": ckpt}))
+            # the barrier's latency interval (inject done → completed),
+            # on a track of its own: with pipelined barriers it outlives
+            # the tick that injected it, so it is no span's child
+            tracing.record_span(f"epoch {e}", t0, lat_ns, epoch=e,
+                                cat=CAT_EPOCH, tid="epoch",
+                                parent=tracing.ROOT,
+                                checkpoint=ckpt)
             lat_ms = lat * 1e3
             if (self.slow_epoch_threshold_ms
                     and lat_ms >= self.slow_epoch_threshold_ms):
@@ -4075,8 +4109,7 @@ class Session:
                               for s in GLOBAL_TRACE.snapshot(epoch=e)],
                 })
         else:
-            ledger.finish(e, (_time.perf_counter() - t_entry) * 1e3,
-                          result)
+            ledger.finish(e, (tracing.now_ns() - t_entry) / 1e6, result)
         self.epoch = e
         # control-plane publication (reference: barrier_complete responses +
         # hummock version notifications, SURVEY.md §3.2 tail)
@@ -4189,7 +4222,7 @@ class Session:
         t.start()
 
     def _drive_compactor(self, task) -> None:
-        from ..common.tracing import CAT_STORAGE, trace_span
+        from ..common.tracing import CAT_STORAGE, span
         from ..worker.compactor import CompactorDied
         mgr = self.store.manager  # type: ignore[attr-defined]
         for c in self.compactors:
@@ -4199,9 +4232,9 @@ class Session:
                 except Exception:  # noqa: BLE001 - try the next worker
                     continue
             try:
-                with trace_span("compaction.dispatch", CAT_STORAGE,
-                                tid="conductor", task_id=task.task_id,
-                                compactor=c.worker_id):
+                with span("compaction.dispatch", epoch=None,
+                          cat=CAT_STORAGE, tid="conductor",
+                          task_id=task.task_id, compactor=c.worker_id):
                     outputs = c.compact(task)
                 mgr.report_compact_task(task.task_id, outputs)
                 mgr.vacuum()
@@ -4941,7 +4974,7 @@ class Session:
         federation works through data-plane partitions. Empty list ⇔
         nothing in flight or everything already acked."""
         import re as _re
-        import time as _time
+        from ..common.tracing import now_ns
         findings: list = []
         if not self._inflight:
             return findings
@@ -4953,8 +4986,7 @@ class Session:
             r"->f(?P<df>\d+)\.(?P<da>\d+)$")
         for epoch, ckpt in self._inflight:
             t0 = self._inject_time.get(epoch)
-            age_ms = ((_time.perf_counter() - t0[0]) * 1e3
-                      if t0 is not None else None)
+            age_ms = ((now_ns() - t0) / 1e6 if t0 is not None else None)
 
             def _add(kind, reason, job=None, worker=None, fragment=None,
                      actor=None, link=None, edge=None,

@@ -94,19 +94,16 @@ def _rw_relations(catalog):
 # -- session-backed telemetry relations ---------------------------------------
 #
 # Builders take (catalog, session); session=None (DESCRIBE, recovery
-# replay) plans the schema with zero rows. Stage column order mirrors
-# barrier_ledger.ALL_STAGES so the waterfall reads left→right.
-
-_STAGE_COLUMNS = ("inject", "pending", "collect", "commit",
-                  "storage_prepare", "storage_settle", "storage_commit",
-                  "sink_deliver", "worker_collect")
-
+# replay) plans the schema with zero rows. Stage columns ARE
+# barrier_ledger.ALL_STAGES, in its order: the waterfall reads left→right.
 
 def _rw_barrier_history(catalog, session):
+    from ..common.barrier_ledger import ALL_STAGES
     schema = Schema.of(
         ("epoch", INT64), ("checkpoint", BOOL), ("result", VARCHAR),
         ("injected_at", FLOAT64), ("total_ms", FLOAT64),
-        *((f"{s}_ms", FLOAT64) for s in _STAGE_COLUMNS),
+        ("tick_ms", FLOAT64), ("compiles", INT64),
+        *((f"{s}_ms", FLOAT64) for s in ALL_STAGES),
         ("workers", VARCHAR))
     if session is None:
         return schema, []
@@ -116,7 +113,8 @@ def _rw_barrier_history(catalog, session):
         rows.append((
             rec["epoch"], bool(rec["checkpoint"]), rec.get("result"),
             rec.get("injected_at"), rec.get("total_ms"),
-            *(stages.get(s) for s in _STAGE_COLUMNS),
+            rec.get("tick_ms"), rec.get("compiles", 0),
+            *(stages.get(s) for s in ALL_STAGES),
             json.dumps(rec.get("workers", {}), sort_keys=True)))
     return schema, rows
 

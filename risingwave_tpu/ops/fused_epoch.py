@@ -69,9 +69,13 @@ def agg_epoch_body(chunk_fn: Callable, exprs: Sequence[Expr], core,
 
     def epoch(state, start, key, k: int):
         def body(st, i):
-            ch = chunk_fn(start + i * rows_per_chunk,
-                          jax.random.fold_in(key, i))
-            projected = ch.with_columns(tuple(e.eval(ch) for e in exprs))
+            # named scopes: metadata only, the names a device trace shows
+            with jax.named_scope("source_gen"):
+                ch = chunk_fn(start + i * rows_per_chunk,
+                              jax.random.fold_in(key, i))
+            with jax.named_scope("project"):
+                projected = ch.with_columns(
+                    tuple(e.eval(ch) for e in exprs))
             return core.apply_chunk(st, projected), None
 
         state, _ = jax.lax.scan(body, state,

@@ -90,9 +90,29 @@ class AggCore:
         LRU eviction ordering (None = no tracking; the sharded path and
         budget-less executors skip it)."""
         key_cols = [chunk.columns[i] for i in self.group_keys]
+        # named scopes are metadata only: "table_probe" (ops/hash_table.py)
+        # and "lane_apply" show in the op names of a device trace
         table, slots, _is_new, ovf = ht_lookup_or_insert(
             state.table, key_cols, chunk.vis
         )
+        with jax.named_scope("lane_apply"):
+            lanes, mark = self._apply_lanes(state, chunk, slots, str_ranks)
+        dirty = state.dirty.at[mark].set(True, mode="drop")
+        ckpt_dirty = state.ckpt_dirty.at[mark].set(True, mode="drop")
+        last_used = state.last_used
+        if step is not None:
+            last_used = last_used.at[mark].set(
+                jnp.asarray(step, jnp.int32), mode="drop")
+        return state.replace(
+            table=table, lanes=tuple(lanes), dirty=dirty,
+            ckpt_dirty=ckpt_dirty, overflow=state.overflow | ovf,
+            last_used=last_used,
+        )
+
+    def _apply_lanes(self, state: AggState, chunk: StreamChunk, slots,
+                     str_ranks):
+        """Fold the chunk's rows into the lanes of their slots. Returns
+        ``(lanes, mark)``; ``mark`` is the slot per visible row."""
         signs = chunk.signs()
         lanes = list(state.lanes)
         lanes[0] = scatter_reduce(lanes[0], slots, signs, "add")
@@ -109,18 +129,7 @@ class AggCore:
                 lane = call.pack_lane(lanes[ofs + j], str_ranks)
                 lanes[ofs + j] = call.unpack_lane(
                     scatter_reduce(lane, slots, contrib, op))
-        mark = jnp.where(chunk.vis, slots, self.capacity)
-        dirty = state.dirty.at[mark].set(True, mode="drop")
-        ckpt_dirty = state.ckpt_dirty.at[mark].set(True, mode="drop")
-        last_used = state.last_used
-        if step is not None:
-            last_used = last_used.at[mark].set(
-                jnp.asarray(step, jnp.int32), mode="drop")
-        return state.replace(
-            table=table, lanes=tuple(lanes), dirty=dirty,
-            ckpt_dirty=ckpt_dirty, overflow=state.overflow | ovf,
-            last_used=last_used,
-        )
+        return lanes, jnp.where(chunk.vis, slots, self.capacity)
 
     def outputs(self, lanes) -> list[tuple[jax.Array, jax.Array]]:
         live = lanes[0] > 0
@@ -131,12 +140,14 @@ class AggCore:
             outs.append((data.astype(call.output_type.dtype), mask))
         return outs
 
+    @jax.named_scope("flush_probe")
     def flush_rank(self, state: AggState) -> jax.Array:
         """Inclusive prefix count of dirty groups — computed ONCE per barrier
         and shared by every flush window (it is the only O(capacity) piece of
         the flush)."""
         return jnp.cumsum(state.dirty.astype(jnp.int32))
 
+    @jax.named_scope("flush_gather")
     def gather_flush_chunk(self, state: AggState, rank: jax.Array,
                            lo: jax.Array) -> StreamChunk:
         """One output chunk for dirty groups with rank in [lo, lo+G).
@@ -176,6 +187,7 @@ class AggCore:
                                interleave(pm, cm)))
         return StreamChunk(ops, vis, tuple(cols))
 
+    @jax.named_scope("flush_finish")
     def finish_flush(self, state: AggState) -> AggState:
         prev = tuple(
             jnp.where(state.dirty, cur, prev)

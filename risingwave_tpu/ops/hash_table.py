@@ -74,6 +74,7 @@ def _keys_equal_at(table: DeviceHashTable, cand: jax.Array,
     return eq
 
 
+@jax.named_scope("table_probe")
 def ht_lookup_or_insert(
     table: DeviceHashTable, key_cols: Sequence[Column], valid: jax.Array
 ):

@@ -1389,9 +1389,7 @@ def run_udf_soak(duration_s: float = 45.0, seed: int = 5,
     seq layer, no recovery expected) + periodic UDF-server SIGKILLs +
     concurrent serving readers (one of them crossing the UDF boundary),
     all live for ``duration_s``, then a bit-exact audit against a
-    no-chaos control. Returns a SCHEMA-STABLE numeric record shaped for
-    ``BENCH_partial.json`` (`ctl bench trend` folds it as phase
-    ``udf_soak``)."""
+    no-chaos control. Returns a SCHEMA-STABLE numeric record."""
     import tempfile
     import threading
     import time as _time

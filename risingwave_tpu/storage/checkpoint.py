@@ -489,12 +489,11 @@ class DurableStateStore(MemoryStateStore):
         if epoch <= self.committed_epoch or epoch in self._prepared_epochs:
             return
         self.join_commits()          # manifest ops stay strictly ordered
-        from ..common.barrier_ledger import timed_stage
-        from ..common.tracing import CAT_STORAGE, trace_span
+        from ..common.tracing import CAT_STORAGE, span
         deltas = self._pending_deltas(epoch)
-        with trace_span("DurableStateStore.prepare", CAT_STORAGE,
-                        epoch=epoch, tid="storage", tables=len(deltas)), \
-                timed_stage(epoch, "storage_prepare"):
+        with span("DurableStateStore.prepare", epoch=epoch,
+                  stage="storage_prepare", cat=CAT_STORAGE, tid="storage",
+                  tables=len(deltas)):
             self.log.prepare_epoch(epoch, deltas)
         self._prepared_epochs.add(epoch)
 
@@ -519,15 +518,15 @@ class DurableStateStore(MemoryStateStore):
             return
         deltas = self._pending_deltas(epoch)
         MemoryStateStore.commit(self, epoch)
-        from ..common.tracing import CAT_STORAGE, trace_span
+        from ..common.tracing import CAT_STORAGE, span
 
         def _encode_and_publish() -> None:
-            from ..common.barrier_ledger import timed_stage
+            # on its own thread: the conductor's commit span is named
             try:
-                with trace_span("DurableStateStore.commit_async",
-                                CAT_STORAGE, epoch=epoch, tid="storage",
-                                tables=len(deltas)), \
-                        timed_stage(epoch, "storage_commit"):
+                with span("DurableStateStore.commit_async", epoch=epoch,
+                          stage="storage_commit", parent="checkpoint.commit",
+                          cat=CAT_STORAGE, tid="storage",
+                          tables=len(deltas)):
                     self.log.append_epoch(epoch, deltas)
             except BaseException as e:  # noqa: BLE001 - surfaced at join
                 self._commit_error = e
@@ -553,25 +552,22 @@ class DurableStateStore(MemoryStateStore):
         if epoch <= self.committed_epoch:
             return
         self.join_commits()
-        from ..common.barrier_ledger import timed_stage
-        from ..common.tracing import CAT_STORAGE, trace_span
+        from ..common.tracing import CAT_STORAGE, span
         prepared = {e for e in self._prepared_epochs if e <= epoch}
         if prepared:
             # phase 2: promote the durably staged segment(s); epochs
             # prepared BEYOND this commit (pipelined checkpoints) keep
             # their staged segments for their own commit frames
-            with trace_span("DurableStateStore.settle", CAT_STORAGE,
-                            epoch=epoch, tid="storage",
-                            prepared=len(prepared)), \
-                    timed_stage(epoch, "storage_settle"):
+            with span("DurableStateStore.settle", epoch=epoch,
+                      stage="storage_settle", cat=CAT_STORAGE,
+                      tid="storage", prepared=len(prepared)):
                 self.log.settle_prepared(epoch, discard_beyond=False)
             self._prepared_epochs -= prepared
         else:
             deltas = self._pending_deltas(epoch)
-            with trace_span("DurableStateStore.commit", CAT_STORAGE,
-                            epoch=epoch, tid="storage",
-                            tables=len(deltas)), \
-                    timed_stage(epoch, "storage_commit"):
+            with span("DurableStateStore.commit", epoch=epoch,
+                      stage="storage_commit", cat=CAT_STORAGE,
+                      tid="storage", tables=len(deltas)):
                 self.log.append_epoch(epoch, deltas)
         super().commit(epoch)
 

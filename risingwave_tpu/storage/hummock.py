@@ -136,15 +136,16 @@ def run_compact_task(store: ObjectStore, task: CompactTask,
     dedicated compactor worker. Crash-safe at every point: outputs are
     orphans until the meta-side version swap references them."""
     from ..common.failpoint import fail_point
-    from ..common.tracing import CAT_STORAGE, trace_span
+    from ..common.tracing import CAT_STORAGE, span
     fail_point("compactor.task.start")
     dropped = set(task.dropped_tables)
     runs = [load_sst(store, name) for name in task.inputs]
     outputs: List[str] = []
     builder: Optional[SstBuilder] = None
     size = 0
-    with trace_span("compactor.task", CAT_STORAGE, tid="compactor",
-                    task_id=task.task_id, inputs=len(task.inputs)):
+    with span("compactor.task", epoch=None, cat=CAT_STORAGE,
+              tid="compactor", task_id=task.task_id,
+              inputs=len(task.inputs)):
         def flush_output() -> None:
             nonlocal builder, size
             if builder is None or builder.n_entries == 0:
@@ -363,13 +364,13 @@ class HummockStateStore(MemoryStateStore):
     def commit(self, epoch: int) -> None:
         if epoch <= self.committed_epoch:
             return
-        from ..common.tracing import CAT_STORAGE, trace_span
+        from ..common.tracing import CAT_STORAGE, span
         deltas: Dict[int, Dict[bytes, Optional[bytes]]] = {}
         for e in sorted(k for k in self._pending if k <= epoch):
             for table_id, buf in self._pending[e].items():
                 deltas.setdefault(table_id, {}).update(buf)
-        with trace_span("HummockStateStore.commit", CAT_STORAGE,
-                        epoch=epoch, tid="storage", tables=len(deltas)):
+        with span("HummockStateStore.commit", epoch=epoch,
+                  cat=CAT_STORAGE, tid="storage", tables=len(deltas)):
             name = self._write_l0(epoch, deltas) if deltas else None
             try:
                 self.manager.commit_epoch(epoch, name)

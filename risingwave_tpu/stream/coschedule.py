@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.fetch import PendingFlush, async_fetch
+from ..common.tracing import CAT_EPOCH, span
 from ..ops.fused_multi import (
     append_state, build_group_epoch, gather_job_flush_chunk, index_state,
     multi_agg_finish, multi_agg_probe, remove_state, stack_states,
@@ -214,12 +215,23 @@ class CoGroup:
         """Resolve the in-flight flush: one packed fetch (already
         streaming — usually landed) for all J jobs, then per-job gather
         windows against the pending pre-finish state. Returns
-        {job: [StreamChunk, ...]}."""
+        {job: [StreamChunk, ...]}. The wait on the device and the
+        decode are sibling spans of the caller's epoch."""
         p = self.pending
         if p is None:
             p = self.begin_flush()
         self.pending = None
-        packed_h = np.asarray(p.fetch.result())
+        with span("cosched.epoch_wait", epoch=None, stage="epoch_wait",
+                  wait="device", cat=CAT_EPOCH, tid="conductor"):
+            packed_h = np.asarray(p.fetch.result())
+        with span("cosched.flush_decode", epoch=None, stage="flush_decode",
+                  cat=CAT_EPOCH, tid="conductor") as decode:
+            out = self._decode_flush(p, packed_h)
+            decode.set(dirty_groups=int(packed_h[:, 0].sum()),
+                       chunks=sum(len(c) for c in out.values()))
+        return out
+
+    def _decode_flush(self, p: "PendingFlush", packed_h) -> dict:
         out: dict = {}
         for j, name in enumerate(self.names):
             n_dirty, overflow = int(packed_h[j, 0]), int(packed_h[j, 1])

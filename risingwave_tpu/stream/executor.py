@@ -65,23 +65,25 @@ class SingleInputExecutor(Executor):
         yield watermark
 
     async def execute(self) -> AsyncIterator[Message]:
-        from .metrics import barrier_timer
+        from .metrics import ChunkClock, barrier_timer
         stats = self.stats
+        clock = ChunkClock(stats)
         async for msg in self.input.execute():
             if isinstance(msg, StreamChunk):
                 stats.chunks_in += 1
                 stats.capacity_rows_in += msg.capacity
-                async for out in self.map_chunk(msg):
+                async for out in clock.atimed(self.map_chunk(msg)):
                     stats.chunks_out += 1
                     yield out
             elif isinstance(msg, ChunkBatch):
                 stats.batches_in += 1
                 stats.batch_chunks_in += msg.num_chunks
                 stats.capacity_rows_in += msg.num_chunks * msg.chunk_capacity
-                async for out in self.map_chunk_batch(msg):
+                async for out in clock.atimed(self.map_chunk_batch(msg)):
                     stats.chunks_out += 1
                     yield out
             elif isinstance(msg, Barrier):
+                clock.emit(self.identity, msg.epoch.curr)
                 with barrier_timer(stats, self.identity, msg.epoch.curr):
                     outs = [out async for out in self.on_barrier(msg)]
                 for out in outs:

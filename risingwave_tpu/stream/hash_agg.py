@@ -35,6 +35,7 @@ import numpy as np
 
 from ..common.chunk import DEFAULT_CHUNK_CAPACITY, Column, StreamChunk
 from ..common.fetch import fetch
+from ..common.tracing import CAT_STORAGE, span
 from ..common.types import INT64, Field, Schema
 from ..expr.agg import AggCall
 from ..ops.grouped_agg import AggCore, AggState, load_rows_into_state
@@ -256,7 +257,9 @@ class HashAggExecutor(SingleInputExecutor):
         # through the async-fetch helper: the packed copy starts
         # streaming at enqueue, and the tick-path lint
         # (sync-fetch-discipline) can reason about one crossing
-        n_dirty, overflow, n_live = (int(x) for x in fetch(packed))
+        with span("agg.flush_wait", epoch=barrier.epoch.curr, wait="device",
+                  tid=self.identity):
+            n_dirty, overflow, n_live = (int(x) for x in fetch(packed))
         if overflow:
             raise RuntimeError(
                 f"{self.identity}: group table overflow (capacity "
@@ -278,11 +281,7 @@ class HashAggExecutor(SingleInputExecutor):
             self._pending_clean.clear()
             cleaned = True
         if barrier.checkpoint and self.state_table is not None:
-            from ..common.tracing import CAT_STORAGE, trace_span
-            with trace_span(f"{self.identity}.checkpoint", CAT_STORAGE,
-                            epoch=barrier.epoch.curr, tid=self.identity,
-                            groups=n_live):
-                self._checkpoint_to_state_table(barrier.epoch.curr)
+            self._checkpoint_to_state_table(barrier.epoch.curr)
             if (self.hbm_group_budget is not None
                     and n_live > self.hbm_group_budget):
                 self._evict_cold()
@@ -330,9 +329,18 @@ class HashAggExecutor(SingleInputExecutor):
         """Flush groups dirtied since the last checkpoint to the durable tier.
 
         Host sync is bounded by the checkpoint delta, mirroring the
-        reference's incremental StateTable.commit (state_table.rs:783)."""
+        reference's incremental StateTable.commit (state_table.rs:783).
+        The span lives HERE so that both callers have it: ``on_barrier``
+        and the co-scheduled tick, which borrows this executor as its
+        persistence engine."""
+        with span("agg.state_delta", epoch=epoch, stage="state_delta",
+                  cat=CAT_STORAGE, tid=self.identity) as delta:
+            self._stage_state_delta(epoch, delta)
+
+    def _stage_state_delta(self, epoch: int, delta) -> None:
         st = self.state
         idx = np.nonzero(np.asarray(st.ckpt_dirty))[0]
+        delta.set(dirty_groups=len(idx), bytes_staged=0)
         if len(idx):
             from ..native import codec as _native_codec
             codec = _native_codec()
@@ -353,6 +361,9 @@ class HashAggExecutor(SingleInputExecutor):
                     codec.encode_value_rows(datas, masks, types, ins_idx)))
                 dels = codec.encode_keys(keys_d, keys_m, pk_t, del_idx)
                 self.state_table.stage_encoded(puts, dels)
+                delta.set(bytes_staged=sum(map(len, puts))
+                          + sum(map(len, puts.values()))
+                          + sum(map(len, dels)))
             else:
                 keys_d = [np.asarray(kd)[idx] for kd in st.table.key_data]
                 keys_m = [np.asarray(km)[idx] for km in st.table.key_mask]

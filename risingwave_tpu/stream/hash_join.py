@@ -326,8 +326,10 @@ class HashJoinExecutor(Executor):
                     yield self._gather(big, jnp.int64(lo))
 
     async def execute(self):
-        from .metrics import barrier_timer
+        from ..common.tracing import now_ns
+        from .metrics import ChunkClock, barrier_timer
         stats = self.stats
+        clock = ChunkClock(stats)
         self._pending: list = []
         self._rewind_state = None
         async for ev in barrier_align(self.left, self.right, batched=True):
@@ -340,14 +342,15 @@ class HashJoinExecutor(Executor):
                                            * batch.chunk_capacity)
                 # scanned batches and the optimistic per-chunk window must
                 # not interleave rewinds — flush pending output first
-                for out in self._flush_pending():
+                for out in clock.timed(self._flush_pending()):
                     yield out
-                for out in self._consume_batch(side, batch):
+                for out in clock.timed(self._consume_batch(side, batch)):
                     yield out
             elif kind == "chunk":
                 _, side, chunk = ev
                 stats.chunks_in += 1
                 stats.capacity_rows_in += chunk.capacity
+                t_chunk = now_ns()
                 if self.null_aware_anti and side == "right":
                     self._reject_null_build_keys(chunk)
                 if self._evicted:
@@ -356,8 +359,10 @@ class HashJoinExecutor(Executor):
                         # flush the optimistic batch FIRST: fault-in
                         # replays mutate state, and a later rewind of the
                         # batch must not lose them
-                        for out in self._flush_pending():
+                        clock.add(t_chunk)
+                        for out in clock.timed(self._flush_pending()):
                             yield out
+                        t_chunk = now_ns()
                         self._fault_in(hits)
                 if self._rewind_state is None:
                     self._rewind_state = self.state
@@ -366,13 +371,15 @@ class HashJoinExecutor(Executor):
                 self.state = new_state
                 self._pending.append(
                     (side, chunk, self._pack_stats(new_state, big), big))
+                clock.add(t_chunk)
                 if len(self._pending) >= self.emit_batch:
-                    for out in self._flush_pending():
+                    for out in clock.timed(self._flush_pending()):
                         yield out
             elif kind == "barrier":
                 barrier = ev[1]
-                for out in self._flush_pending():
+                for out in clock.timed(self._flush_pending()):
                     yield out
+                clock.emit(self.identity, barrier.epoch.curr)
                 with barrier_timer(stats, self.identity, barrier.epoch.curr):
                     self._check_flags()
                     if barrier.checkpoint:

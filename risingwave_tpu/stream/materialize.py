@@ -44,9 +44,9 @@ class MaterializeExecutor(SingleInputExecutor):
         # and could strand them pending forever (reference: HummockManager.
         # commit_epoch is driven by meta after barrier collection, not by
         # materialize).
-        from ..common.tracing import CAT_STORAGE, trace_span
-        with trace_span(f"{self.identity}.seal", CAT_STORAGE,
-                        epoch=barrier.epoch.curr, tid=self.identity):
+        from ..common.tracing import CAT_STORAGE, span
+        with span(f"{self.identity}.seal", epoch=barrier.epoch.curr,
+                  cat=CAT_STORAGE, tid=self.identity):
             self.table.commit(barrier.epoch.curr)
         if False:
             yield

@@ -13,10 +13,9 @@ absent from the hot path.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Iterator, Optional
 
-from ..common.tracing import CAT_BARRIER, GLOBAL_TRACE, Span
+from ..common.tracing import CAT_BARRIER, now_ns, record_span, span
 
 
 @dataclasses.dataclass
@@ -34,38 +33,97 @@ class ExecutorStats:
         return dataclasses.asdict(self)
 
 
-class _BarrierTimer:
-    __slots__ = ("stats", "identity", "epoch", "_t0", "_ts")
+class _BarrierTimer(span):
+    """The ``<identity>.barrier`` span of one executor's barrier
+    handling, also counted into its ``ExecutorStats``. The executor runs
+    on its job's task, so the parent is named: the conductor's
+    ``barrier.collect`` of the same epoch."""
 
-    def __init__(self, stats: ExecutorStats, identity: Optional[str] = None,
-                 epoch: Optional[int] = None):
+    __slots__ = ("stats",)
+
+    def __init__(self, stats: ExecutorStats, identity: str,
+                 epoch: Optional[int]):
+        super().__init__(f"{identity}.barrier", epoch=epoch,
+                         parent="barrier.collect", cat=CAT_BARRIER,
+                         tid=identity)
         self.stats = stats
-        self.identity = identity
-        self.epoch = epoch
 
-    def __enter__(self):
-        self._ts = time.time()
-        self._t0 = time.perf_counter()
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
         self.stats.barriers += 1
-        return self
-
-    def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
-        self.stats.barrier_seconds += dur
-        if self.identity is not None:
-            # the tracing seam: every identified barrier timing doubles as
-            # a per-executor span in the epoch's trace tree
-            GLOBAL_TRACE.record(Span(
-                f"{self.identity}.barrier", CAT_BARRIER, self._ts, dur,
-                epoch=self.epoch, tid=self.identity))
+        self.stats.barrier_seconds += self.dur_ns / 1e9
         return False
 
 
-def barrier_timer(stats: ExecutorStats, identity: Optional[str] = None,
+def barrier_timer(stats: ExecutorStats, identity: str,
                   epoch: Optional[int] = None) -> _BarrierTimer:
-    """Time one barrier's handling into ``stats``; with ``identity`` (and
-    ideally ``epoch``) the timing is also recorded as a tracing span."""
+    """Time one barrier's handling into ``stats`` and the epoch's span
+    tree."""
     return _BarrierTimer(stats, identity, epoch)
+
+
+class ChunkClock:
+    """Host time one executor spends handling an epoch's chunks, rolled up
+    into ONE ``<identity>.chunks`` span at the barrier (a span a chunk
+    would flood the ring). Only the executor's OWN steps are timed — each
+    resumption of its ``map_chunk`` until it hands a chunk on — so a slow
+    consumer does not show up in its producer. The span starts where the
+    first step did and lasts the summed busy time: the steps are
+    disjoint, so it ends no later than the last of them."""
+
+    __slots__ = ("stats", "first_ns", "busy_ns", "_base")
+
+    def __init__(self, stats: ExecutorStats):
+        self.stats = stats
+        self.first_ns = 0
+        self.busy_ns = 0
+        self._base = (0, 0, 0)
+
+    def add(self, t0: int) -> None:
+        if not self.busy_ns:
+            self.first_ns = t0
+        self.busy_ns += now_ns() - t0
+
+    def timed(self, steps):
+        """Iterate a synchronous generator of output chunks, timing each
+        ``next``."""
+        it = iter(steps)
+        while True:
+            t0 = now_ns()
+            try:
+                out = next(it)
+            except StopIteration:
+                self.add(t0)
+                return
+            self.add(t0)
+            yield out
+
+    async def atimed(self, steps):
+        """The same over an async generator (``map_chunk``)."""
+        it = steps.__aiter__()
+        while True:
+            t0 = now_ns()
+            try:
+                out = await it.__anext__()
+            except StopAsyncIteration:
+                self.add(t0)
+                return
+            self.add(t0)
+            yield out
+
+    def emit(self, identity: str, epoch: Optional[int]) -> None:
+        st = self.stats
+        now = (st.chunks_in + st.batch_chunks_in, st.batches_in,
+               st.capacity_rows_in)
+        record_span(f"{identity}.chunks",
+                    self.first_ns if self.busy_ns else now_ns(),
+                    self.busy_ns, epoch=epoch, parent="barrier.collect",
+                    cat=CAT_BARRIER, tid=identity,
+                    chunks=now[0] - self._base[0],
+                    batches=now[1] - self._base[1],
+                    capacity_rows=now[2] - self._base[2])
+        self._base = now
+        self.busy_ns = 0
 
 
 def iter_executors(root) -> Iterator:

@@ -187,8 +187,9 @@ class SinkExecutor(SingleInputExecutor):
             self._seq += 1
         self._pending.clear()
         if not self.degraded:
-            from ..common.barrier_ledger import timed_stage
-            with timed_stage(epoch, "sink_deliver"):
+            from ..common.tracing import span
+            with span(f"{self.identity}.deliver", epoch=epoch,
+                      stage="sink_deliver", tid=self.identity):
                 self._try_deliver(epoch)
         else:
             # degraded: the log absorbs changes up to the cap; bounded-log

@@ -506,11 +506,9 @@ class WorkerHost:
                 else:
                     failed[name] = repr(e)
 
-        from ..common.barrier_ledger import timed_stage
-        from ..common.tracing import CAT_EPOCH, trace_span
-        with trace_span("barrier.collect", CAT_EPOCH, epoch=epoch,
-                        tid="conductor", checkpoint=checkpoint), \
-                timed_stage(epoch, "worker_collect"):
+        from ..common.tracing import CAT_EPOCH, span
+        with span("barrier.collect", epoch=epoch, stage="worker_collect",
+                  cat=CAT_EPOCH, tid="conductor", checkpoint=checkpoint):
             await asyncio.gather(
                 *(collect(n, self.jobs[n]) for n in scope
                   if n in self.jobs))

@@ -249,9 +249,9 @@ echo "== UDF chaos / soak (server kills + auditor + soak seed — tier-2) =="
 # kill-mid-epoch acceptance run under pipeline_depth=2 with a
 # co-scheduled group, the crash-point sweep over the udf.* sites,
 # ctl udf serve external attach, and the ~60s soak composition (RPC
-# chaos + UDF-server kills + serving readers, auditor green) whose
-# record feeds `ctl bench trend` — slow-marked out of tier-1 per the
-# 870s wall budget
+# chaos + UDF-server kills + serving readers, auditor green, one
+# schema-stable record) — slow-marked out of tier-1 per the 870s wall
+# budget
 python -m pytest -q -p no:cacheprovider -m slow \
     tests/test_udf_plane.py \
     "$@"

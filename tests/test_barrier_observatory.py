@@ -192,7 +192,10 @@ class TestSingleProcessWaterfall:
         from risingwave_tpu.frontend.build import BuildConfig
         qn = "build_group_epoch.<locals>.coscheduled_epoch"
 
+        from risingwave_tpu.common import tracing
+
         def run(depth):
+            tracing.GLOBAL_TRACE.clear()
             with count_dispatches() as c:
                 s = Session(config=BuildConfig(coschedule=True),
                             source_chunk_capacity=CAP,
@@ -207,12 +210,28 @@ class TestSingleProcessWaterfall:
                     n_records = len(s._barrier_ledger.history())
                 finally:
                     s.close()
-                return dict(c.counts), n_records
+                return dict(c.counts), n_records, tracing.epoch_spans()
 
-        c1, n1 = run(1)
-        c2, n2 = run(2)
+        c1, n1, spans1 = run(1)
+        c2, n2, spans2 = run(2)
         assert n1 >= 5 and n2 >= 5       # the ledger observed the run
         assert c1.get(qn) == c2.get(qn) and c1.get(qn), (c1, c2)
+        # ... with the whole tick under spans (ISSUE 25): every epoch has
+        # its root and its device wait, and a deferred flush resolves
+        # under a span of the NEXT epoch at depth 2 only
+        for spans in (spans1, spans2):
+            roots = [[d["name"] for d in per_epoch].count("session.tick")
+                     for per_epoch in spans.values()]
+            # (the MV's initial barrier is no tick: it has no root)
+            assert set(roots) <= {0, 1} and sum(roots) >= 5
+        waits = [sum(d["name"] == "cosched.epoch_wait"
+                     for per_epoch in spans.values() for d in per_epoch)
+                 for spans in (spans1, spans2)]
+        assert waits[0] == waits[1] >= 5
+        deferred = [sum(d["name"] == "cosched.resolve_deferred"
+                        for per_epoch in spans.values() for d in per_epoch)
+                    for spans in (spans1, spans2)]
+        assert deferred[0] == 0 < deferred[1]
 
     def test_chrome_trace_exports_barrier_flow_events(self):
         s = _ticked_session()
@@ -253,10 +272,14 @@ slow_epoch_capture_capacity = 3
             s.close()
 
     def test_defaults_keep_legacy_sizes(self):
-        s = Session()
+        from risingwave_tpu.common.config import load_config
+        from risingwave_tpu.common.tracing import GLOBAL_TRACE
+        s = Session(rw_config=load_config(None))
         try:
             assert s._barrier_ledger.capacity == 256
             assert s._slow_epochs.maxlen == 16
+            # the span ring holds a 400-barrier run (ISSUE 25; was 4096)
+            assert GLOBAL_TRACE.capacity == 16384
         finally:
             s.close()
 
@@ -300,7 +323,8 @@ class TestTelemetryCatalog:
             cols = [c for c, _ in s.last_select_schema]
             rows2 = s.run_sql("SELECT * FROM rw_barrier_history")
             cols2 = [c for c, _ in s.last_select_schema]
-            assert [f"{st}_ms" for st in ALL_STAGES] == cols2[5:-1]
+            assert cols2[5:7] == ["tick_ms", "compiles"]
+            assert [f"{st}_ms" for st in ALL_STAGES] == cols2[7:-1]
             assert len(rows2) == len(hist)
         finally:
             s.close()

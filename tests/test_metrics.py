@@ -79,7 +79,7 @@ def test_trace_recorder_ring_and_drain():
 
     rec = TraceRecorder(capacity=4)
     for i in range(10):
-        rec.record(Span(f"s{i}", "epoch", float(i), 0.001, epoch=i))
+        rec.record(Span(f"s{i}", "epoch", i * 1000, 1000, epoch=i))
     spans = rec.snapshot()
     assert [s.name for s in spans] == ["s6", "s7", "s8", "s9"]  # bounded
     assert rec.epochs() == [6, 7, 8, 9]

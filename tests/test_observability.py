@@ -83,6 +83,19 @@ def test_worker_spans_merge_into_chrome_trace(cluster):
     events = [e for e in obj["traceEvents"] if e.get("ph") == "X"]
     worker_events = [e for e in events if e["pid"] == 1]   # worker 0
     assert any(e["cat"] == "barrier" for e in worker_events)
+    # federated spans keep their tree: ids, parents and the epoch, on
+    # the host-wide monotonic clock (ISSUE 25)
+    by_id = {e["args"]["id"]: e for e in worker_events}
+    collects = [e for e in worker_events if e["name"] == "barrier.collect"]
+    assert collects
+    children = [e for e in worker_events
+                if e["args"].get("parent") in {c["args"]["id"]
+                                               for c in collects}]
+    assert children
+    for e in children:
+        parent = by_id[e["args"]["parent"]]
+        assert parent["args"]["epoch"] == e["args"]["epoch"]
+        assert parent["ts"] <= e["ts"]
     metas = [e for e in obj["traceEvents"] if e.get("ph") == "M"]
     names = {m["args"]["name"] for m in metas}
     assert {"session", "worker-0"} <= names
@@ -111,11 +124,11 @@ def test_stats_span_outbox_resends_until_acked(tmp_path):
 
     GLOBAL_TRACE.clear()
     h = WorkerHost(str(tmp_path), worker_id=0)
-    GLOBAL_TRACE.record(Span("a", "barrier", 0.0, 0.001, epoch=1))
+    GLOBAL_TRACE.record(Span("a", "barrier", 0, 1000, epoch=1))
     r1 = h.handle_stats({"type": "stats"})
     assert [s["name"] for s in r1["spans"]] == ["a"]
     # reply lost: the next request carries a stale ack -> resend + new
-    GLOBAL_TRACE.record(Span("b", "barrier", 0.0, 0.001, epoch=2))
+    GLOBAL_TRACE.record(Span("b", "barrier", 0, 1000, epoch=2))
     r2 = h.handle_stats({"type": "stats", "span_ack": r1["span_seq"] - 1})
     assert [s["name"] for s in r2["spans"]] == ["a", "b"]
     # reply processed: acking the current seq clears the outbox

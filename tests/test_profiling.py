@@ -1,8 +1,8 @@
 """Device profiling plane (common/profiling.py, ISSUE 12): per-dispatch
 cost/memory telemetry keyed by the dispatch-counter qualnames, the
-cluster-wide HBM ledger, AOT roofline analysis, and the bench trend
-folding — plus the wiring surfaces (Session.metrics()["profiling"] /
-["dispatch"], Prometheus, ctl profile/bench)."""
+cluster-wide HBM ledger and AOT roofline analysis — plus the wiring
+surfaces (Session.metrics()["profiling"] / ["dispatch"], Prometheus,
+ctl profile)."""
 
 import json
 import os
@@ -15,9 +15,8 @@ import pytest
 
 from risingwave_tpu.common.dispatch_count import count_dispatches
 from risingwave_tpu.common.profiling import (
-    GLOBAL_PROFILER, aot_analysis, bench_trend, hbm_ledger,
-    load_bench_history, profile_dispatch, render_roofline_table,
-    render_trend_table, roofline_report,
+    GLOBAL_PROFILER, aot_analysis, hbm_ledger, profile_dispatch,
+    render_roofline_table, roofline_report,
 )
 from risingwave_tpu.common.tracing import CAT_DISPATCH, GLOBAL_TRACE
 
@@ -277,89 +276,6 @@ def test_chip_peaks_keyed_by_device_kind():
         chip_peaks()                    # the attached device: a CPU here
     with pytest.raises(UnknownChipError):
         chip_peaks(1e14, None, device_kind="TPU v99")
-
-
-# ---------------------------------------------------------------------------
-# bench trend
-# ---------------------------------------------------------------------------
-
-
-def _write_round(dirpath, n, parsed, rc=0):
-    with open(os.path.join(dirpath, f"BENCH_r{n:02d}.json"), "w") as f:
-        json.dump({"n": n, "rc": rc, "parsed": parsed}, f)
-
-
-def test_bench_trend_flags_rate_drop_and_latency_rise(tmp_path):
-    d = str(tmp_path)
-    _write_round(d, 1, {"rows_per_sec": 100.0, "p99_ms": 5.0})
-    _write_round(d, 2, {"rows_per_sec": 120.0, "p99_ms": 4.0})
-    _write_round(d, 3, {"rows_per_sec": 60.0, "p99_ms": 9.0})
-    trend = bench_trend(load_bench_history(d), tolerance=0.2)
-    assert set(trend["regressions"]) == {"rows_per_sec", "p99_ms"}
-    f = trend["fields"]["rows_per_sec"]
-    assert not f["lower_is_better"] and f["best"] == 120.0 \
-        and f["latest"] == 60.0
-    assert trend["fields"]["p99_ms"]["lower_is_better"]
-    table = render_trend_table(trend)
-    assert "REGRESSED" in table
-
-
-def test_bench_trend_within_tolerance_not_flagged(tmp_path):
-    d = str(tmp_path)
-    _write_round(d, 1, {"rows_per_sec": 100.0})
-    _write_round(d, 2, {"rows_per_sec": 90.0})   # -10% < 20% tolerance
-    trend = bench_trend(load_bench_history(d))
-    assert trend["regressions"] == []
-
-
-def test_bench_trend_partial_records_and_nested_fields(tmp_path):
-    d = str(tmp_path)
-    _write_round(d, 1, {"serving": {"qps": 50.0}})
-    with open(os.path.join(d, "BENCH_partial.json"), "w") as f:
-        f.write(json.dumps({"phase": "serving",
-                            "record": {"serving": {"qps": 10.0}}}) + "\n")
-        f.write("not json\n")                     # tolerated
-    trend = bench_trend(load_bench_history(d))
-    assert "serving.qps" in trend["regressions"]
-    assert [p["value"] for p in
-            trend["fields"]["serving.qps"]["points"]] == [50.0, 10.0]
-
-
-def _write_lost_chip_history(dirpath):
-    """The shape of the five driver records this repo once carried (the
-    files are gone; ROADMAP.md states what they showed): one healthy
-    chip number in round 2, then rounds whose chip phase failed and
-    recorded ``value: 0.0``."""
-    _write_round(dirpath, 1, {"value": 0.0}, rc=2)
-    _write_round(dirpath, 2, {"value": 96644.6})
-    for n in (3, 4, 5):
-        _write_round(dirpath, n, {"value": 0.0, "tpu_error": "init"})
-
-
-def test_bench_trend_over_checked_in_rounds(tmp_path):
-    """A history in which later rounds lost the chip folds into a trend
-    whose headline 'value' field regresses vs the one healthy round."""
-    d = str(tmp_path)
-    _write_lost_chip_history(d)
-    history = load_bench_history(d)
-    assert len(history) >= 5
-    trend = bench_trend(history)
-    assert "value" in trend["fields"]
-    assert "value" in trend["regressions"]
-
-
-@pytest.mark.slow
-def test_ctl_bench_trend_cli(tmp_path):
-    d = str(tmp_path)
-    _write_lost_chip_history(d)
-    res = subprocess.run(
-        [sys.executable, "-m", "risingwave_tpu", "ctl", "bench", "trend",
-         "--bench-dir", d, "--json"],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO)
-    assert res.returncode == 0, res.stderr
-    trend = json.loads(res.stdout)
-    assert "value" in trend["regressions"]
 
 
 @pytest.mark.slow

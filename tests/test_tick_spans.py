@@ -1,0 +1,614 @@
+"""One span primitive over the whole of ``Session.tick()`` (ISSUE 25).
+
+Every barrier leaves a tree of spans in the process ring, rooted at
+``session.tick``, on one monotonic clock, and — whenever a profiler
+session runs — the same spans in the profiler's trace. Pinned here, on
+the CPU at tiny sizes and with no timing thresholds: the span names and
+parents of both tick paths (they are a contract: PERF.md, the benchmark's
+readers and ``docs/observability.md`` key on them), interval nesting, the
+ledger's account of the whole tick, what survives ``Session.close()``,
+and that none of it adds a dispatch or renames a program.
+"""
+
+import asyncio
+import glob
+import os
+import re
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from risingwave_tpu.common import tracing
+from risingwave_tpu.common.barrier_ledger import ALL_STAGES
+from risingwave_tpu.common.tracing import GLOBAL_TRACE, Span, TraceRecorder
+from risingwave_tpu.frontend import Session
+from risingwave_tpu.frontend.build import BuildConfig
+
+CAP = 64
+CHUNKS = 16
+BID_DDL = """CREATE SOURCE bid (auction BIGINT, bidder BIGINT,
+    price BIGINT, channel VARCHAR, url VARCHAR, date_time TIMESTAMP,
+    extra VARCHAR) WITH (connector = 'nexmark', nexmark_table = 'bid')"""
+MV = ("CREATE MATERIALIZED VIEW q5 AS SELECT window_start, auction, "
+      "count(*) AS num FROM TUMBLE(bid, date_time, INTERVAL '10' SECOND) "
+      "GROUP BY window_start, auction")
+
+PATHS = ("fused", "exec")
+NEW_STAGES = ("source_feed", "epoch_dispatch", "epoch_wait", "flush_decode",
+              "state_delta")
+
+#: span → parent, per path; ``None`` = no parent. Spans of category
+#: "dispatch" (one per profiled jit call) hang under whatever enqueued them
+#: and are not part of the contract.
+CONDUCTOR = {
+    "session.tick": None,
+    "source.feed": "session.tick",
+    "barrier.inject": "session.tick",
+    "barrier.collect": "session.tick",
+    "Materialize.chunks": "barrier.collect",
+    "Materialize.barrier": "barrier.collect",
+    "Materialize.seal": "Materialize.barrier",
+}
+EVERY_BARRIER = {
+    "fused": {**CONDUCTOR,
+              "cosched.dispatch": "session.tick",
+              "cosched.flush_begin": "session.tick",
+              "cosched.epoch_wait": "session.tick",
+              "cosched.flush_decode": "session.tick"},
+    "exec": {**CONDUCTOR,
+             **{f"{ident}.{kind}": "barrier.collect"
+                for ident in ("RowIdGen", "Project", "HashAgg")
+                for kind in ("chunks", "barrier")},
+             "agg.flush_wait": "HashAgg.barrier"},
+}
+CHECKPOINT_ONLY = {
+    "fused": {"agg.state_delta": "session.tick",
+              "cosched.restack": "session.tick",
+              "checkpoint.commit": "session.tick",
+              "DurableStateStore.commit": "checkpoint.commit"},
+    "exec": {"agg.state_delta": "HashAgg.barrier",
+             "checkpoint.commit": "session.tick",
+             "DurableStateStore.commit": "checkpoint.commit"},
+}
+#: never owed to a reader: they occur only sometimes
+SOMETIMES = {"xla.compile", "cosched.resolve_deferred"}
+
+
+def open_session(path: str, data_dir=None, **kw) -> Session:
+    s = Session(config=BuildConfig(coschedule=(path == "fused")),
+                source_chunk_capacity=CAP, chunks_per_tick=CHUNKS,
+                checkpoint_frequency=3, data_dir=data_dir, **kw)
+    s.run_sql(BID_DDL)
+    s.run_sql(MV)
+    return s
+
+
+def run(path: str, tmp_path, ticks: int = 7):
+    """A session of ``ticks`` barriers on a cleared ring. Returns
+    ``(session, {epoch: [span dict]})``."""
+    s = open_session(path, str(tmp_path / path))
+    GLOBAL_TRACE.clear()
+    first = s.epoch + 1
+    for _ in range(ticks):
+        s.tick()
+    spans = {e: v for e, v in tracing.epoch_spans().items() if e >= first}
+    assert sorted(spans) == list(range(first, first + ticks))
+    return s, spans
+
+
+def contract(spans: list) -> list:
+    """The spans a reader may count on: no per-dispatch spans, none of
+    the sometimes-spans, not the barrier-latency summary."""
+    return [d for d in spans if d["cat"] != tracing.CAT_DISPATCH
+            and d["name"] not in SOMETIMES
+            and not d["name"].startswith("epoch ")]
+
+
+# -- (1, 2, 8) names and parents, every epoch, both paths ---------------------
+
+@pytest.mark.parametrize("path", PATHS)
+def test_every_epoch_has_exactly_the_contract_spans(path, tmp_path):
+    s, by_epoch = run(path, tmp_path)
+    try:
+        history = {r["epoch"]: r for r in s._barrier_ledger.history()}
+        for epoch, spans in by_epoch.items():
+            want = dict(EVERY_BARRIER[path])
+            if history[epoch]["checkpoint"]:
+                want.update(CHECKPOINT_ONLY[path])
+            by_id = {d["id"]: d for d in spans}
+            got = {}
+            for d in contract(spans):
+                parent = by_id[d["parent"]]["name"] if d["parent"] else None
+                got.setdefault(d["name"], set()).add(parent)
+            assert {n: {p} for n, p in want.items()} == got, epoch
+            assert all(d["epoch"] == epoch for d in spans)
+            # the barrier's latency interval rides on a track of its own
+            (summary,) = [d for d in spans if d["name"] == f"epoch {epoch}"]
+            assert summary["parent"] is None
+            roots = [d for d in contract(spans) if d["parent"] is None]
+            assert [d["name"] for d in roots] == ["session.tick"]
+    finally:
+        s.close()
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_no_span_takes_the_harness_annotation_names(path, tmp_path):
+    """The benchmark zips its own ``tick`` annotations with its barriers;
+    a program span of that name would mislabel them."""
+    s, by_epoch = run(path, tmp_path, ticks=4)
+    try:
+        names = {d["name"] for spans in by_epoch.values() for d in spans}
+        assert not names & {"tick", "tick.checkpoint"}
+    finally:
+        s.close()
+
+
+# -- (3) intervals ------------------------------------------------------------
+
+@pytest.mark.parametrize("path", PATHS)
+def test_children_lie_inside_parents_and_conductor_siblings_are_disjoint(
+        path, tmp_path):
+    s, by_epoch = run(path, tmp_path)
+    try:
+        for spans in by_epoch.values():
+            by_id = {d["id"]: d for d in spans}
+            for d in spans:
+                if d["parent"] is None:
+                    continue
+                p = by_id[d["parent"]]
+                assert p["start_ns"] <= d["start_ns"], (d["name"], p["name"])
+                assert d["start_ns"] + d["dur_ns"] \
+                    <= p["start_ns"] + p["dur_ns"], (d["name"], p["name"])
+            (tick,) = [d for d in spans if d["name"] == "session.tick"]
+            kids = sorted((d for d in spans if d["parent"] == tick["id"]),
+                          key=lambda d: d["start_ns"])
+            for a, b in zip(kids, kids[1:]):
+                assert a["start_ns"] + a["dur_ns"] <= b["start_ns"], \
+                    (a["name"], b["name"])
+            assert sum(d["dur_ns"] for d in kids) <= tick["dur_ns"]
+    finally:
+        s.close()
+
+
+# -- (4) the ledger accounts for the whole tick -------------------------------
+
+@pytest.mark.parametrize("path", PATHS)
+def test_ledger_record_spans_the_tick_and_history_shows_it(path, tmp_path):
+    s, by_epoch = run(path, tmp_path)
+    try:
+        seen = set()
+        for rec in s._barrier_ledger.history():
+            if rec["epoch"] not in by_epoch:
+                continue
+            st = rec["stages"]
+            assert rec["tick_ms"] >= st["inject"] + rec["total_ms"]
+            assert rec["compiles"] >= 0
+            (tick,) = [d for d in by_epoch[rec["epoch"]]
+                       if d["name"] == "session.tick"]
+            assert rec["tick_ms"] == pytest.approx(tick["dur_ns"] / 1e6,
+                                                   abs=1e-3)
+            assert "source_feed" in st
+            seen |= set(st)
+        assert set(NEW_STAGES) <= set(ALL_STAGES)
+        owed = {"fused": set(NEW_STAGES),
+                "exec": {"source_feed", "state_delta"}}[path]
+        assert owed <= seen
+        cols = ["tick_ms", "compiles"] + [f"{x}_ms" for x in NEW_STAGES]
+        rows = s.run_sql(f"SELECT epoch, {', '.join(cols)} "
+                         "FROM rw_barrier_history")
+        by = {r[0]: dict(zip(cols, r[1:])) for r in rows}
+        for epoch in by_epoch:
+            assert by[epoch]["tick_ms"] > 0
+            assert by[epoch]["source_feed_ms"] is not None
+    finally:
+        s.close()
+
+
+# -- (6) the state delta: checkpoint barriers only, both callers --------------
+
+def agg_engine(s: Session, path: str):
+    if path == "fused":
+        return s._cosched_engines["q5"][0]
+    from risingwave_tpu.stream.metrics import iter_executors
+    (agg,) = [ex for ex in iter_executors(s.jobs["q5"].pipeline)
+              if ex.identity == "HashAgg"]
+    return agg
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_state_delta_on_checkpoints_only_counts_the_rows_it_writes(
+        path, tmp_path):
+    s = open_session(path, str(tmp_path / path))
+    try:
+        table = agg_engine(s, path).state_table
+        written = []
+        for name in ("stage_encoded", "insert", "delete"):
+            real = getattr(table, name)
+
+            def spy(*a, _real=real, _name=name):
+                written[-1] += (len(a[0]) + len(a[1])
+                                if _name == "stage_encoded" else 1)
+                return _real(*a)
+            setattr(table, name, spy)
+        GLOBAL_TRACE.clear()
+        epochs = []
+        for _ in range(7):
+            written.append(0)
+            s.tick()
+            epochs.append(s.epoch)
+        by_epoch = tracing.epoch_spans()
+        history = {r["epoch"]: r for r in s._barrier_ledger.history()}
+        checkpoints = 0
+        for epoch, n_written in zip(epochs, written):
+            deltas = [d for d in by_epoch[epoch]
+                      if d["name"] == "agg.state_delta"]
+            if not history[epoch]["checkpoint"]:
+                assert deltas == [] and n_written == 0
+                continue
+            checkpoints += 1
+            (delta,) = deltas
+            assert delta["args"]["dirty_groups"] == n_written > 0
+            assert delta["args"]["bytes_staged"] >= 0
+            assert history[epoch]["stages"]["state_delta"] > 0
+        assert checkpoints == 2
+    finally:
+        s.close()
+
+
+# -- (7) the shared clock: the same spans in a profiler's trace ---------------
+
+def host_annotations(log_dir: str) -> list:
+    from jax.profiler import ProfileData
+    (xplane,) = glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(xplane).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                stats = dict(ev.stats)
+                if "epoch" in stats:
+                    out.append({"name": ev.name, "epoch": int(stats["epoch"]),
+                                "wait": stats.get("wait"),
+                                "start_ns": int(ev.start_ns),
+                                "dur_ns": int(ev.duration_ns)})
+    return out
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_profiler_trace_holds_the_same_spans_on_a_shared_clock(
+        path, tmp_path):
+    s = open_session(path, str(tmp_path / path))
+    try:
+        for _ in range(2):
+            s.tick()                      # compile outside the trace
+        GLOBAL_TRACE.clear()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        log_dir = str(tmp_path / "trace")
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        try:
+            for _ in range(3):
+                s.tick()
+        finally:
+            jax.profiler.stop_trace()
+        notes = host_annotations(log_dir)
+        # everything recorded around a body is annotated; roll-ups and the
+        # latency summary have no body to annotate
+        ring = [d for spans in tracing.epoch_spans().values() for d in spans
+                if not d["name"].endswith(".chunks")
+                and not d["name"].startswith("epoch ")
+                and d["name"] != "xla.compile"]
+        assert len(ring) >= 3 * 8
+        key = lambda d: (d["epoch"], d["name"])       # noqa: E731
+        by_key: dict = {}
+        for n in sorted(notes, key=lambda n: n["start_ns"]):
+            by_key.setdefault(key(n), []).append(n)
+        paired = []
+        for d in sorted(ring, key=lambda d: d["start_ns"]):
+            assert by_key.get(key(d)), f"not in the trace: {key(d)}"
+            paired.append((d, by_key[key(d)].pop(0)))
+        assert not any(by_key.values()), "annotations the ring lacks"
+        offsets = []
+        for d, n in paired:
+            assert abs(d["dur_ns"] - n["dur_ns"]) < 1e6, d["name"]
+            assert d["wait"] == n["wait"]
+            offsets.append(n["start_ns"] - d["start_ns"])
+        # one clock rate: the two time bases differ by a constant
+        assert max(offsets) - min(offsets) < 1e6
+        # and the same nesting, read from the trace's own intervals
+        note_of = {d["id"]: n for d, n in paired}
+        for d, n in paired:
+            if d["parent"] in note_of:
+                p = note_of[d["parent"]]
+                assert p["start_ns"] <= n["start_ns"]
+                assert n["start_ns"] + n["dur_ns"] \
+                    <= p["start_ns"] + p["dur_ns"]
+    finally:
+        s.close()
+
+
+# -- (9) the per-operator chunk roll-up ---------------------------------------
+
+def test_chunk_rollup_counts_sixteen_chunks_per_operator(tmp_path):
+    s, by_epoch = run("exec", tmp_path, ticks=4)
+    try:
+        for spans in by_epoch.values():
+            rollups = {d["name"]: d for d in spans
+                       if d["name"].endswith(".chunks")}
+            for ident in ("RowIdGen", "HashAgg"):
+                args = rollups[f"{ident}.chunks"]["args"]
+                assert args["chunks"] == CHUNKS
+                assert args["capacity_rows"] == CHUNKS * CAP
+                assert args["batches"] == 0
+            # one roll-up per operator instance, never one span a chunk
+            assert len([d for d in spans
+                        if d["name"] == "HashAgg.chunks"]) == 1
+    finally:
+        s.close()
+
+
+def test_chunk_rollup_excludes_the_consumers_time():
+    """A slow downstream does not inflate its upstream's roll-up: only
+    the operator's own steps are timed."""
+    from risingwave_tpu.common.chunk import make_chunk
+    from risingwave_tpu.common.types import INT64, Field, Schema
+    from risingwave_tpu.stream.executor import SingleInputExecutor
+    from risingwave_tpu.stream.message import Barrier
+    from risingwave_tpu.stream.source import MockSource
+
+    schema = Schema((Field("k", INT64),))
+    chunks = [make_chunk(schema, [(i,)]) for i in range(4)]
+
+    class Upstream(SingleInputExecutor):
+        identity = "Upstream"
+
+        def __init__(self, input):
+            super().__init__(input)
+            self.schema = input.schema
+
+    class SlowConsumer(SingleInputExecutor):
+        identity = "SlowConsumer"
+
+        def __init__(self, input):
+            super().__init__(input)
+            self.schema = input.schema
+
+        async def map_chunk(self, chunk):
+            time.sleep(0.05)
+            yield chunk
+
+    async def drive():
+        pipeline = SlowConsumer(Upstream(
+            MockSource(schema, chunks + [Barrier.new(5)])))
+        async for msg in pipeline.execute():
+            if isinstance(msg, Barrier):
+                return
+
+    GLOBAL_TRACE.clear()
+    asyncio.run(drive())
+    rollups = {d["name"]: d for d in tracing.epoch_spans()[5]}
+    assert rollups["Upstream.chunks"]["args"]["chunks"] == 4
+    assert rollups["SlowConsumer.chunks"]["dur_ns"] >= 4 * 0.05 * 1e9
+    assert rollups["Upstream.chunks"]["dur_ns"] < 0.05 * 1e9
+
+
+# -- (10) a compile inside a barrier ------------------------------------------
+
+def test_recompile_inside_a_barrier_is_a_span_and_a_counter(tmp_path):
+    s = open_session("exec", str(tmp_path / "exec"))
+    try:
+        for _ in range(3):
+            s.tick()                      # steady state: nothing compiles
+        feed = s.feeds[0]
+        real = feed.generator
+        fresh = jax.jit(lambda x: x * 2 + 1)
+        fired = []
+
+        def generator():
+            if not fired:
+                fired.append(fresh(np.arange(7)))
+            return real()
+        feed.generator = generator
+        GLOBAL_TRACE.clear()
+        s.tick()
+        epoch = s.epoch
+        s.tick()
+        by_epoch = tracing.epoch_spans()
+        (compile_span,) = [d for d in by_epoch[epoch]
+                           if d["name"] == "xla.compile"]
+        assert compile_span["args"]["seconds"] > 0
+        assert compile_span["args"]["cache"] in ("hit", "miss", "off")
+        assert not [d for d in by_epoch[epoch + 1]
+                    if d["name"] == "xla.compile"]
+        records = {r["epoch"]: r for r in s._barrier_ledger.history()}
+        assert records[epoch]["compiles"] == 1
+        assert records[epoch + 1]["compiles"] == 0
+    finally:
+        s.close()
+
+
+# -- (11) the run's spans survive the run; a small ring drops whole epochs ----
+
+def test_epoch_spans_answer_after_close(tmp_path):
+    s, by_epoch = run("fused", tmp_path, ticks=4)
+    s.close()
+    after = tracing.epoch_spans()
+    assert set(by_epoch) <= set(after)
+    for epoch, spans in by_epoch.items():
+        assert [d["id"] for d in after[epoch]] == [d["id"] for d in spans]
+
+
+def test_small_ring_drops_whole_oldest_epochs_never_half_of_one():
+    rec = TraceRecorder(capacity=10)
+    for epoch in range(1, 5):
+        for i in range(4):
+            rec.record(Span(f"s{i}", "epoch", epoch * 100 + i, 1,
+                            epoch=epoch, id=epoch * 10 + i))
+    # the ring holds the last 10 of 16 spans: epoch 2 lost two of its four
+    assert len(rec.snapshot(epoch=2)) == 2
+    answer = rec.epoch_spans()
+    assert sorted(answer) == [3, 4]
+    assert all(len(spans) == 4 for spans in answer.values())
+    # late spans of a complete epoch push out the rest of epoch 2's and
+    # one of epoch 3's
+    for i in range(3):
+        rec.record(Span("late", "storage", 450 + i, 1, epoch=4, id=90 + i))
+    answer = rec.epoch_spans()
+    assert sorted(answer) == [4] and len(answer[4]) == 7
+    # shrinking the ring counts as losing spans too
+    rec.set_capacity(3)
+    assert rec.epoch_spans() == {}
+    rec.clear()
+    rec.record(Span("x", "epoch", 1, 1, epoch=9))
+    assert sorted(rec.epoch_spans()) == [9]
+
+
+def test_default_ring_holds_a_run_of_both_cells():
+    """400 barriers at the measured span rate of either tick path (17 a
+    barrier here, up to 22 on a checkpoint) fit the default ring with
+    room (docs/observability.md 'Sizing the ring')."""
+    from risingwave_tpu.common.config import StreamingConfig
+    assert TraceRecorder().capacity == StreamingConfig().trace_ring_capacity
+    assert TraceRecorder().capacity >= 400 * 40
+
+
+# -- (13) export and federation carry the new fields --------------------------
+
+def test_export_and_federation_carry_the_span_fields(tmp_path):
+    s, by_epoch = run("exec", tmp_path, ticks=3)
+    try:
+        events = [e for e in s.export_chrome_trace()["traceEvents"]
+                  if e.get("ph") == "X"]
+        by_id = {e["args"]["id"]: e for e in events}
+        waits = [e for e in events if e["name"] == "agg.flush_wait"]
+        assert waits and all(e["args"]["wait"] == "device" for e in waits)
+        for e in waits:
+            assert by_id[e["args"]["parent"]]["name"] == "HashAgg.barrier"
+            assert e["ts"] >= by_id[e["args"]["parent"]]["ts"]
+        rollup = next(e for e in events if e["name"] == "HashAgg.chunks")
+        assert rollup["args"]["chunks"] == CHUNKS
+    finally:
+        s.close()
+    # the stats-frame codec: a worker's span dicts re-ingest whole
+    shipped = [d for spans in by_epoch.values() for d in spans]
+    rec = TraceRecorder()
+    rec.ingest(shipped, pid=3)
+    back = rec.snapshot()
+    assert [(b.id, b.parent, b.wait, b.start_ns, b.dur_ns, b.args)
+            for b in back] == [(d["id"], d["parent"], d["wait"],
+                                d["start_ns"], d["dur_ns"], d["args"])
+                               for d in shipped]
+    assert {b.pid for b in back} == {3}
+
+
+# -- (14) the programs keep the names the roofline metric finds them by -------
+
+def test_jitted_epoch_programs_keep_their_module_names(tmp_path):
+    import jax.numpy as jnp
+    fused = open_session("fused", str(tmp_path / "fused"))
+    execp = open_session("exec", str(tmp_path / "exec"))
+    try:
+        group = next(iter(fused._cosched.groups.values()))
+        starts = jnp.asarray(group.starts, jnp.int64)
+        nos = jnp.asarray(group.batch_nos, jnp.int64)
+        packed, ranks = group._probe(group.stacked)
+        agg = agg_engine(execp, "exec")
+        chunk = execp.feeds[0].generator()
+        _, rank = agg._probe(agg.state)
+        lowered = {
+            "jit_coscheduled_epoch": group._epoch.lower(
+                group.stacked, starts, group._keys(), nos, 2),
+            "jit_probe": group._probe.lower(group.stacked),
+            "jit_finish": group._finish.lower(group.stacked),
+            "jit_gather": group._gather.lower(
+                group.stacked, ranks, jnp.int64(0), jnp.int64(0)),
+            "jit__probe": agg._probe.lower(agg.state),
+            "jit_finish_flush": agg._finish.lower(agg.state),
+            "jit_gather_flush_chunk": agg._gather.lower(
+                agg.state, rank, jnp.int64(0)),
+        }
+        for name, low in lowered.items():
+            assert f"module @{name} " in low.as_text()[:200], name
+        # the per-chunk step is jitted from a closure: ask the function
+        assert agg._apply.__wrapped__.__name__ == "_apply_chunk" or \
+            "apply_chunk" in agg._apply.lower(
+                agg.state, chunk, agg._str_ranks(),
+                agg._lru()).as_text()[:200]
+    finally:
+        fused.close()
+        execp.close()
+
+
+# -- named scopes inside the two epoch programs -------------------------------
+
+@pytest.mark.parametrize("path,scopes", [
+    ("fused", ("source_gen", "project", "table_probe", "lane_apply")),
+    ("exec", ("table_probe", "lane_apply")),
+])
+def test_epoch_programs_carry_named_scopes(path, scopes, tmp_path):
+    import jax.numpy as jnp
+    s = open_session(path, str(tmp_path / path))
+    try:
+        if path == "fused":
+            group = next(iter(s._cosched.groups.values()))
+            low = group._epoch.lower(
+                group.stacked, jnp.asarray(group.starts, jnp.int64),
+                group._keys(), jnp.asarray(group.batch_nos, jnp.int64), 2)
+            flush = {"flush_probe": group._probe.lower(group.stacked),
+                     "flush_finish": group._finish.lower(group.stacked)}
+        else:
+            agg = agg_engine(s, path)
+            low = agg._apply.lower(agg.state, s.feeds[0].generator(),
+                                   agg._str_ranks(), agg._lru())
+            flush = {"flush_probe": agg._probe.lower(agg.state),
+                     "flush_finish": agg._finish.lower(agg.state)}
+        text = low.as_text(debug_info=True)
+        for scope in scopes:
+            assert re.search(rf"{scope}\)?/", text), scope
+        for scope, lowered in flush.items():
+            # under vmap the scope reads "vmap(flush_probe)/"
+            assert re.search(rf"{scope}\)?/",
+                             lowered.as_text(debug_info=True)), scope
+    finally:
+        s.close()
+
+
+# -- scripts/idle_by_span.py: gaps split at span boundaries -------------------
+
+def test_idle_gaps_are_charged_to_the_innermost_span():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "idle_by_span", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "scripts", "idle_by_span.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    s = 1_000_000_000
+    raw = {"annotations": [["session.tick", 0, 10 * s],
+                           ["source.feed", 0, 3 * s],
+                           ["barrier.collect", 4 * s, 5 * s],
+                           ["HashAgg.barrier", 6 * s, 2 * s],
+                           ["session.tick", 11 * s, 1 * s]],
+           "devices": [{"plane": "/device:TPU:0",
+                        "ops": [["%a", 1 * s, 1 * s], ["%b", 7 * s, 2 * s],
+                                ["%c", 11 * s, 1 * s]],
+                        "programs": [["jit_step(1)", 1 * s, 1 * s],
+                                     ["jit_flush(2)", 7 * s, 2 * s],
+                                     ["jit_step(1)", 11 * s, 1 * s]]}]}
+    out = mod.attribute(raw)
+    assert out["window_s"] == 12 and out["busy_s"] == 4
+    by_span = dict(out["idle_by_span_s"])
+    # idle: [0,1) feed; [2,3) feed, [3,4) tick, [4,6) collect, [6,7) agg;
+    # [9,10) tick; [10,11) between ticks
+    assert by_span == {"source.feed": 2, "session.tick": 2,
+                       "barrier.collect": 2, "HashAgg.barrier": 1,
+                       "between ticks": 1}
+    assert sum(by_span.values()) == out["idle_s"] == 8
+    by_pair = dict(out["idle_by_span_after_program_s"])
+    assert by_pair["source.feed after start"] == 1
+    assert by_pair["barrier.collect after jit_step"] == 2
+    assert by_pair["between ticks after jit_flush"] == 1

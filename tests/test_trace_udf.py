@@ -1,8 +1,7 @@
-"""Tracing dump, UDFs, telemetry (coverage #85/#14/#8)."""
+"""Tracing dump, UDFs (coverage #85/#14)."""
 
 import pytest
 
-from risingwave_tpu.common.telemetry import TelemetryManager
 from risingwave_tpu.common.types import FLOAT64, INT64, VARCHAR
 from risingwave_tpu.expr.udf import drop_udf, register_udf
 from risingwave_tpu.frontend import Session
@@ -71,20 +70,6 @@ class TestUdf:
     def test_name_collision_rejected(self):
         with pytest.raises(ValueError, match="already exists"):
             register_udf("lower", lambda s_: s_, [VARCHAR], VARCHAR)
-
-
-class TestTelemetry:
-    def test_disabled_by_default(self):
-        tm = TelemetryManager()
-        assert tm.report() is None and tm.reports == []
-
-    def test_report_shape(self):
-        s = Session()
-        s.run_sql("CREATE TABLE t (k BIGINT PRIMARY KEY)")
-        tm = TelemetryManager(enabled=True)
-        r = tm.report(s)
-        assert r["job_counts"]["tables"] == 1
-        assert tm.reports == [r]
 
 
 class TestDropUdfGuard:
