@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.chunk import DEFAULT_CHUNK_CAPACITY, Column, StreamChunk
-from ..common.fetch import fetch
+from ..common.fetch import async_fetch, fetch
 from ..common.tracing import CAT_STORAGE, span
 from ..common.types import INT64, Field, Schema
 from ..expr.agg import AggCall
@@ -42,6 +42,13 @@ from ..ops.grouped_agg import AggCore, AggState, load_rows_into_state
 from ..storage.state_table import StateTable
 from .executor import Executor, SingleInputExecutor
 from .message import Barrier
+
+
+#: rows of one checkpoint-delta window (``AggCore.ckpt_delta_window``); a
+#: smaller table is its own window. The window's device time is linear in
+#: its rows, dirty or not (0.24-0.29 us a row on a v5e, PERF.md PR 26), so
+#: a window far above the delta gathers and moves rows nobody reads.
+_DELTA_WINDOW_ROWS = 1 << 13
 
 
 class HashAggExecutor(SingleInputExecutor):
@@ -135,6 +142,11 @@ class HashAggExecutor(SingleInputExecutor):
         self._apply_batch = jax.jit(_apply_batch, donate_argnums=donate)
         self._gather = jax.jit(self.core.gather_flush_chunk)
         self._finish = jax.jit(self.core.finish_flush)
+        # the checkpoint's delta window reads the state and leaves it in
+        # place (not donated); a device trace shows it under its own name,
+        # jit_ckpt_delta_window
+        self._delta_window = jax.jit(self.core.ckpt_delta_window,
+                                     static_argnums=(2,))
 
         # barrier probe: ONE packed scalar fetch per barrier instead of
         # separate overflow + n_dirty + per-chunk cardinality syncs. The
@@ -338,22 +350,36 @@ class HashAggExecutor(SingleInputExecutor):
             self._stage_state_delta(epoch, delta)
 
     def _stage_state_delta(self, epoch: int, delta) -> None:
+        """Select and gather the dirty groups ON THE DEVICE
+        (``AggCore.ckpt_delta_window``) and fetch only those rows: what
+        crosses to the host follows the delta, not the table's capacity.
+        Window 0 answers ``n_dirty``; a delta larger than one window
+        costs ``ceil(n_dirty / G)`` of them."""
         st = self.state
-        idx = np.nonzero(np.asarray(st.ckpt_dirty))[0]
-        delta.set(dirty_groups=len(idx), bytes_staged=0)
-        if len(idx):
+        G = min(st.ckpt_dirty.shape[0], _DELTA_WINDOW_ROWS)
+        wins = [fetch(self._delta_window(st, np.int32(0), G))]
+        n_dirty = int(wins[0][0])
+        more = [async_fetch(self._delta_window(st, np.int32(lo), G))
+                for lo in range(G, n_dirty, G)]
+        wins += [f.result() for f in more]
+        # every window but the last is full and the valid rows lead, so the
+        # first n_dirty rows of the concatenation are the delta, in
+        # ascending slot order
+        keys_d, keys_m, lanes = jax.tree_util.tree_map(
+            lambda *xs: np.concatenate(xs)[:n_dirty], *(w[2:] for w in wins))
+        delta.set(dirty_groups=n_dirty, bytes_staged=0, windows=len(wins),
+                  bytes_fetched=sum(
+                      x.nbytes for x in jax.tree_util.tree_leaves(wins)))
+        if n_dirty:
+            idx = np.arange(n_dirty)
+            live = lanes[0] > 0
             from ..native import codec as _native_codec
             codec = _native_codec()
             if codec is not None:
-                keys_d = [np.asarray(kd) for kd in st.table.key_data]
-                keys_m = [np.asarray(km) for km in st.table.key_mask]
-                lanes = [np.asarray(l) for l in st.lanes]
                 datas = keys_d + lanes
-                ones = np.ones(lanes[0].shape, bool)
-                masks = keys_m + [ones] * len(lanes)
+                masks = keys_m + (np.ones(n_dirty, bool),) * len(lanes)
                 types = self.state_table.schema.types
                 nk = len(keys_d)
-                live = lanes[0][idx] > 0
                 ins_idx, del_idx = idx[live], idx[~live]
                 pk_t = list(types[:nk])
                 puts = dict(zip(
@@ -365,18 +391,13 @@ class HashAggExecutor(SingleInputExecutor):
                           + sum(map(len, puts.values()))
                           + sum(map(len, dels)))
             else:
-                keys_d = [np.asarray(kd)[idx] for kd in st.table.key_data]
-                keys_m = [np.asarray(km)[idx] for km in st.table.key_mask]
-                lanes = [np.asarray(l)[idx] for l in st.lanes]
-                for r in range(len(idx)):
+                for r in idx:
                     key_vals = [
                         keys_d[c][r].item() if keys_m[c][r] else None
                         for c in range(len(keys_d))
                     ]
-                    lane_vals = [lanes[j][r].item()
-                                 for j in range(len(lanes))]
-                    row = tuple(key_vals) + tuple(lane_vals)
-                    if lanes[0][r] > 0:
+                    row = tuple(key_vals) + tuple(l[r].item() for l in lanes)
+                    if live[r]:
                         self.state_table.insert(row)
                     else:
                         self.state_table.delete(row)

@@ -125,6 +125,28 @@ def test_fused_agg_epoch_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def test_ckpt_delta_window_compiles_for_v5e_without_a_scatter(one_chip):
+    """The checkpoint delta's window at deployment size (the benchmark's
+    2^21-slot table, the executor's 8,192-row window): gathers only —
+    a scatter over the capacity is what the contract rules out."""
+    from risingwave_tpu.common import INT64
+    from risingwave_tpu.expr.agg import count_star
+    from risingwave_tpu.ops.grouped_agg import AggCore
+    from risingwave_tpu.stream.hash_agg import _DELTA_WINDOW_ROWS
+
+    core = AggCore((INT64, INT64), (0, 1), [count_star()],
+                   table_capacity=1 << 21, out_capacity=4096)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(core.init_state))
+    lo = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile_for(
+        jax.jit(core.ckpt_delta_window, static_argnums=(2,)),
+        state, lo, _DELTA_WINDOW_ROWS)
+    text = compiled.as_text()
+    assert " gather(" in text and " scatter(" not in text
+
+
 # ---------------------------------------------------------------------------
 # Tier 2: lower for platform "tpu" (StableHLO + embedded Mosaic payload)
 # ---------------------------------------------------------------------------

@@ -76,8 +76,10 @@ CHECKPOINT_ONLY = {
 SOMETIMES = {"xla.compile", "cosched.resolve_deferred"}
 
 
-def open_session(path: str, data_dir=None, **kw) -> Session:
-    s = Session(config=BuildConfig(coschedule=(path == "fused")),
+def open_session(path: str, data_dir=None, capacity: int = 1 << 16,
+                 **kw) -> Session:
+    s = Session(config=BuildConfig(coschedule=(path == "fused"),
+                                   agg_table_capacity=capacity),
                 source_chunk_capacity=CAP, chunks_per_tick=CHUNKS,
                 checkpoint_frequency=3, data_dir=data_dir, **kw)
     s.run_sql(BID_DDL)
@@ -251,10 +253,37 @@ def test_state_delta_on_checkpoints_only_counts_the_rows_it_writes(
             (delta,) = deltas
             assert delta["args"]["dirty_groups"] == n_written > 0
             assert delta["args"]["bytes_staged"] >= 0
+            assert delta["args"]["windows"] >= 1
+            assert delta["args"]["bytes_fetched"] > 0
             assert history[epoch]["stages"]["state_delta"] > 0
         assert checkpoints == 2
     finally:
         s.close()
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_state_delta_fetches_the_dirty_rows_not_the_table(path, tmp_path):
+    """The same traffic into a table eight times the size moves the same
+    bytes across the link: a count, so it holds on the CPU too."""
+    fetched = {}
+    for capacity in (1 << 13, 1 << 16):
+        s = open_session(path, str(tmp_path / f"{path}{capacity}"),
+                         capacity=capacity)
+        try:
+            GLOBAL_TRACE.clear()
+            for _ in range(6):
+                s.tick()
+            fetched[capacity] = [
+                (d["args"]["dirty_groups"], d["args"]["windows"],
+                 d["args"]["bytes_fetched"])
+                for spans in tracing.epoch_spans().values() for d in spans
+                if d["name"] == "agg.state_delta"]
+        finally:
+            s.close()
+    small, large = fetched.values()
+    assert len(small) == 2 and small == large
+    assert all(0 < dirty < 1 << 13 and windows == 1
+               for dirty, windows, _ in small)
 
 
 # -- (7) the shared clock: the same spans in a profiler's trace ---------------
