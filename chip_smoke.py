@@ -131,14 +131,15 @@ class Phase:
 def sizes(tiny: bool) -> dict:
     if tiny:
         return dict(chunk=256, chunks_per_tick=2, ticks=12, more_ticks=2,
-                    agg_slots=1 << 13, join_keys=1 << 9, join_width=64,
+                    agg_slots=1 << 13, join_keys=1 << 9, join_width=16,
                     rank_shapes=((512, 128), (256, 16)),
                     match_shape=(1 << 9, 128))
     # a deployment, not a unit test: 32 barriers x 16 chunks x 4096 rows
     # = 2,097,152 bid events, 3 checkpoints, 2^20-slot agg tables and a
-    # 2^15-key x 128-lane join arena per side
+    # 2^17-key x 16-lane join arena per side (person ids are unique on the
+    # shared event clock: 49,840 join keys over the 35 barriers)
     return dict(chunk=4096, chunks_per_tick=16, ticks=32, more_ticks=3,
-                agg_slots=1 << 20, join_keys=1 << 15, join_width=128,
+                agg_slots=1 << 20, join_keys=1 << 17, join_width=16,
                 rank_shapes=((4096, 128), (1024, 16)),
                 match_shape=(1 << 15, 128))
 

@@ -53,6 +53,13 @@ FIRST_CATEGORY_ID = 10
 HOT_AUCTION_RATIO = 100
 HOT_BIDDER_RATIO = 100
 NUM_CATEGORIES = 5
+# the seller rule of NEXmark's AuctionGenerator: with probability
+# 1 - 1/HOT_SELLERS_RATIO the first id of the newest HOT_SELLER_RATIO-person
+# batch, else uniform over the newest ``active_people`` ids plus
+# PERSON_ID_LEAD ids not yet issued
+HOT_SELLER_RATIO = 100
+HOT_SELLERS_RATIO = 4
+PERSON_ID_LEAD = 10
 
 _CHANNELS = ["Google", "Facebook", "Baidu", "Apple"]
 _US_STATES = ["AZ", "CA", "ID", "OR", "WY"]
@@ -69,12 +76,18 @@ class NexmarkConfig:
 
 
 class NexmarkGenerator:
-    """Generates Bid / Auction / Person chunks with a shared event clock."""
+    """Generates Bid / Auction / Person chunks. Person and auction rows are
+    the person and auction events of ONE NEXmark event sequence (event ``e``
+    is a person where ``e % 50 < 1``, an auction where ``e % 50 < 4``), with
+    that sequence's ids and timestamps; the bid stream counts its own events
+    (50 bids to an auction epoch), as its recorded consumers expect."""
 
     def __init__(self, config: NexmarkConfig = NexmarkConfig(), seed: int = 42):
         self.cfg = config
         self.rng = np.random.default_rng(seed)
-        self.events_so_far = 0
+        self.events_so_far = 0      # the bid stream's own event count
+        self.persons_so_far = 0
+        self.auctions_so_far = 0
         # pre-intern the small string vocabularies
         self._channel_ids = np.array(
             [GLOBAL_STRING_DICT.intern(c) for c in _CHANNELS], np.int32)
@@ -99,8 +112,7 @@ class NexmarkGenerator:
         """Event timestamps (us) for the next n events of this stream's clock."""
         ids = np.arange(self.events_so_far, self.events_so_far + n, dtype=np.int64)
         self.events_so_far += n
-        us_per_event = 1_000_000 // max(self.cfg.events_per_second, 1)
-        return self.cfg.start_time_us + ids * max(us_per_event, 1), ids
+        return self._event_time(ids), ids
 
     def _last_auction_id(self, event_ids: np.ndarray) -> np.ndarray:
         epoch = event_ids // TOTAL_PROPORTION
@@ -110,9 +122,9 @@ class NexmarkGenerator:
         epoch = event_ids // TOTAL_PROPORTION
         return FIRST_PERSON_ID + epoch * PERSON_PROPORTION
 
-    def _mk_col(self, data: np.ndarray, dtype) -> Column:
-        return Column(jnp.asarray(data.astype(dtype)),
-                      jnp.ones(len(data), jnp.bool_))
+    def _event_time(self, event_ids: np.ndarray) -> np.ndarray:
+        us_per_event = 1_000_000 // max(self.cfg.events_per_second, 1)
+        return self.cfg.start_time_us + event_ids * max(us_per_event, 1)
 
     def _chunk(self, schema: Schema, arrays: list[np.ndarray], n: int) -> StreamChunk:
         cap = self.cfg.chunk_capacity
@@ -151,16 +163,30 @@ class NexmarkGenerator:
             BID_SCHEMA, [auction, bidder, price, channel, url, ts, extra], n)
 
     def next_auction_chunk(self, n: Optional[int] = None) -> StreamChunk:
+        """The next ``n`` auctions of the ONE NEXmark event sequence: the
+        j-th auction is event ``50*(j // 3) + 1 + j % 3`` and has the id
+        ``FIRST_AUCTION_ID + j``."""
         n = n or self.cfg.chunk_capacity
-        ts, eids = self._advance(n)
-        ids = FIRST_AUCTION_ID + np.arange(n, dtype=np.int64) + (
-            self._last_auction_id(eids[:1])[0] - FIRST_AUCTION_ID)
+        j = np.arange(self.auctions_so_far, self.auctions_so_far + n,
+                      dtype=np.int64)
+        self.auctions_so_far += n
+        epoch = j // AUCTION_PROPORTION
+        ts = self._event_time(epoch * TOTAL_PROPORTION + PERSON_PROPORTION
+                              + j % AUCTION_PROPORTION)
+        ids = FIRST_AUCTION_ID + j
         item = self._item_ids[self.rng.integers(0, len(self._item_ids), n)]
         desc = np.full(n, self._empty, np.int32)
         initial = self.rng.integers(1, 1000, n).astype(np.int64)
         reserve = initial + self.rng.integers(0, 1000, n)
         expires = ts + self.rng.integers(1_000_000, 60_000_000, n)
-        seller = self._last_person_id(eids)
+        # persons issued so far: one per epoch, the epoch's own included
+        people = epoch * PERSON_PROPORTION + 1
+        hot = self.rng.integers(0, HOT_SELLERS_RATIO, n) > 0
+        hot_seller = ((people - 1) // HOT_SELLER_RATIO) * HOT_SELLER_RATIO
+        active = np.minimum(people, self.cfg.active_people)
+        cold_seller = people - active + np.floor(
+            self.rng.random(n) * (active + PERSON_ID_LEAD)).astype(np.int64)
+        seller = FIRST_PERSON_ID + np.where(hot, hot_seller, cold_seller)
         category = FIRST_CATEGORY_ID + self.rng.integers(0, NUM_CATEGORIES, n)
         extra = np.full(n, self._empty, np.int32)
         return self._chunk(
@@ -169,9 +195,14 @@ class NexmarkGenerator:
             n)
 
     def next_person_chunk(self, n: Optional[int] = None) -> StreamChunk:
+        """The next ``n`` persons of the same sequence: the k-th person is
+        event ``50*k`` and has the id ``FIRST_PERSON_ID + k``."""
         n = n or self.cfg.chunk_capacity
-        ts, eids = self._advance(n)
-        ids = self._last_person_id(eids)
+        k = np.arange(self.persons_so_far, self.persons_so_far + n,
+                      dtype=np.int64)
+        self.persons_so_far += n
+        ts = self._event_time(k * TOTAL_PROPORTION)
+        ids = FIRST_PERSON_ID + k
         name = self._name_ids[self.rng.integers(0, len(self._name_ids), n)]
         email = np.full(n, self._empty, np.int32)
         card = np.full(n, self._empty, np.int32)

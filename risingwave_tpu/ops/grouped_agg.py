@@ -171,7 +171,6 @@ class AggCore:
         op0 = jnp.where(cur_live, OP_UPDATE_DELETE, OP_DELETE)   # prev row
         op1 = jnp.where(prev_live, OP_UPDATE_INSERT, OP_INSERT)  # cur row
         ops = interleave(op0, op1).astype(jnp.int8)
-        vis = interleave(prev_live & valid, cur_live & valid)
 
         cols = []
         for kd, km in zip(state.table.key_data, state.table.key_mask):
@@ -179,6 +178,16 @@ class AggCore:
             cols.append(Column(interleave(d, d), interleave(m, m)))
         prev_outs = self.outputs(prev_g)
         cur_outs = self.outputs(cur_g)
+        # a group touched again whose output row did not change emits
+        # nothing (reference: AggGroup::build_change returns no change
+        # where prev_outputs == curr_outputs): a GROUP BY without an
+        # aggregate then emits each group once, not an update pair of two
+        # equal rows on every later touch
+        same = prev_live & cur_live
+        for (pd, pm), (cd, cm) in zip(prev_outs, cur_outs):
+            same = same & (pm == cm) & ((pd.astype(cd.dtype) == cd) | ~cm)
+        emit = valid & ~same
+        vis = interleave(prev_live & emit, cur_live & emit)
         for (pd, pm), (cd, cm) in zip(prev_outs, cur_outs):
             cols.append(Column(interleave(pd.astype(cd.dtype), cd),
                                interleave(pm, cm)))

@@ -238,20 +238,34 @@ class JoinCore:
 
     def _pass(self, state: JoinState, chunk: StreamChunk, sel: jax.Array,
               is_insert: bool, side: str, step=None):
-        cap, W = self.capacity, self.W
         N = chunk.capacity
         A = state.left if side == "left" else state.right
         B = state.right if side == "left" else state.left
         a_key_idx = self.left_keys if side == "left" else self.right_keys
         a_key_cols = [chunk.columns[i] for i in a_key_idx]
-        idx = jnp.arange(N)
 
         has_null_key = jnp.zeros(N, jnp.bool_)
         for c in a_key_cols:
             has_null_key = has_null_key | ~c.mask
         match_ok = sel & ~has_null_key
 
-        # ---- probe the opposite side (all rows at once)
+        with jax.named_scope("join_probe"):
+            B, probed = self._probe(B, chunk, a_key_cols, match_ok,
+                                    is_insert, side, step)
+        with jax.named_scope("join_insert" if is_insert else "join_delete"):
+            A = self._update_own(A, chunk, a_key_cols, sel, is_insert,
+                                 probed[1], step)
+        state = (state.replace(left=A, right=B) if side == "left"
+                 else state.replace(left=B, right=A))
+        with jax.named_scope("join_emit"):
+            out = self._emit(chunk, sel, is_insert, side, *probed)
+        return state, out
+
+    def _probe(self, B: JoinSideState, chunk: StreamChunk, a_key_cols,
+               match_ok, is_insert: bool, side: str, step):
+        """Probe the opposite side for all rows at once and maintain its
+        degrees; returns it and what ``_emit`` takes of the probe."""
+        cap, W = self.capacity, self.W
         b_slot, b_found = ht_lookup(B.ht, a_key_cols, match_ok)
         bs = jnp.where(b_found, b_slot, 0)
         occ_b = B.occupied[bs] & b_found[:, None]                      # [N, W]
@@ -284,8 +298,16 @@ class JoinCore:
         if step is not None:
             B = B.replace(lru=B.lru.at[jnp.where(b_found, b_slot, cap)]
                           .max(step, mode="drop"))
+        return B, (matches, c_cnt, r, t, d0, b_datas, b_masks)
 
-        # ---- own-side arena update
+    def _update_own(self, A: JoinSideState, chunk: StreamChunk, a_key_cols,
+                    sel, is_insert: bool, c_cnt, step) -> JoinSideState:
+        """The input side's arena: place the inserted rows (each with its
+        degree ``c_cnt``, the matches the probe found), or tombstone the
+        deleted ones."""
+        cap, W = self.capacity, self.W
+        N = chunk.capacity
+        idx = jnp.arange(N)
         if is_insert:
             a_ht, a_slot, _, ht_ovf = ht_lookup_or_insert(A.ht, a_key_cols, sel)
             a_ok = sel & (a_slot < cap)
@@ -356,13 +378,7 @@ class JoinCore:
             if step is not None:
                 A = A.replace(lru=A.lru.at[jnp.where(a_found, a_slot, cap)]
                               .max(step, mode="drop"))
-
-        state = (state.replace(left=A, right=B) if side == "left"
-                 else state.replace(left=B, right=A))
-
-        out = self._emit(chunk, sel, is_insert, side, matches, c_cnt, r, t, d0,
-                         b_datas, b_masks)
-        return state, out
+        return A
 
     def _emit(self, chunk, sel, is_insert: bool, side: str, matches, c_cnt,
               r, t, d0, b_datas, b_masks):

@@ -111,7 +111,9 @@ class ChunkClock:
             self.add(t0)
             yield out
 
-    def emit(self, identity: str, epoch: Optional[int]) -> None:
+    def emit(self, identity: str, epoch: Optional[int], **args) -> None:
+        """``args``: further counts of the epoch the operator keeps itself
+        (the hash join's rows in per side, chunks out, rewinds, grows)."""
         st = self.stats
         now = (st.chunks_in + st.batch_chunks_in, st.batches_in,
                st.capacity_rows_in)
@@ -121,7 +123,7 @@ class ChunkClock:
                     cat=CAT_BARRIER, tid=identity,
                     chunks=now[0] - self._base[0],
                     batches=now[1] - self._base[1],
-                    capacity_rows=now[2] - self._base[2])
+                    capacity_rows=now[2] - self._base[2], **args)
         self._base = now
         self.busy_ns = 0
 

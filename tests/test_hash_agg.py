@@ -158,3 +158,49 @@ def test_materialized_pipeline():
     mv = MaterializeExecutor(ex, StateTable(store, 1, ex.schema, [0]))
     run(drain(mv))
     assert sorted(mv.rows()) == [(1, 2, 40), (2, 1, 20)]
+
+
+# -- a touched group whose output row did not move emits nothing (ISSUE 27) ---
+
+def _epochs(calls, *epochs):
+    msgs = [Barrier.new(1)]
+    for i, rows in enumerate(epochs):
+        msgs += [make_chunk(IN_SCHEMA, rows), Barrier.new(i + 2)]
+    ex = HashAggExecutor(MockSource(IN_SCHEMA, msgs), [0], calls)
+    chunks, _ = run(drain(wrap_debug(ex)))
+    return agg_rows(chunks, ex.schema)
+
+
+def test_group_by_without_aggregate_emits_each_group_once():
+    """NEXmark q8's dedup: a group touched in a later epoch changes no
+    column, so no update pair of two equal rows goes downstream (a hash
+    join below would tombstone and re-insert the row every time)."""
+    got = _epochs([], [(1, 10), (2, 20), (1, 5)], [(1, 7), (3, 1)],
+                  [(2, 2), (2, 3)])
+    assert sorted(got) == [(OP_INSERT, (1,)), (OP_INSERT, (2,)),
+                           (OP_INSERT, (3,))]
+
+
+def test_unmoved_max_emits_nothing_a_moved_one_an_update_pair():
+    got = _epochs([agg("max", 1, INT64)], [(1, 10), (2, 20)],
+                  [(1, 7), (2, 25)])
+    assert sorted(got[:2]) == [(OP_INSERT, (1, 10)), (OP_INSERT, (2, 20))]
+    assert got[2:] == [(OP_UPDATE_DELETE, (2, 20)),
+                       (OP_UPDATE_INSERT, (2, 25))]
+
+
+def test_count_still_emits_an_update_pair_on_every_touch():
+    got = _epochs([count_star()], [(1, 10)], [(1, 7)])
+    assert got == [(OP_INSERT, (1, 1)), (OP_UPDATE_DELETE, (1, 1)),
+                   (OP_UPDATE_INSERT, (1, 2))]
+
+
+def test_a_group_that_dies_and_one_reborn_are_not_suppressed():
+    dead = make_chunk(IN_SCHEMA, [(1, 10)], ops=[OP_DELETE])
+    msgs = [Barrier.new(1), make_chunk(IN_SCHEMA, [(1, 10)]), Barrier.new(2),
+            dead, Barrier.new(3), make_chunk(IN_SCHEMA, [(1, 10)]),
+            Barrier.new(4)]
+    ex = HashAggExecutor(MockSource(IN_SCHEMA, msgs), [0], [])
+    chunks, _ = run(drain(wrap_debug(ex)))
+    assert agg_rows(chunks, ex.schema) == [
+        (OP_INSERT, (1,)), (OP_DELETE, (1,)), (OP_INSERT, (1,))]

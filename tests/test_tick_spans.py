@@ -641,3 +641,121 @@ def test_idle_gaps_are_charged_to_the_innermost_span():
     assert by_pair["source.feed after start"] == 1
     assert by_pair["barrier.collect after jit_step"] == 2
     assert by_pair["between ticks after jit_flush"] == 1
+
+
+# -- the hash join's spans, counters and named scopes (ISSUE 27) --------------
+
+Q8_DDL = (
+    """CREATE SOURCE person (id BIGINT, name VARCHAR, email_address VARCHAR,
+    credit_card VARCHAR, city VARCHAR, state VARCHAR, date_time TIMESTAMP,
+    extra VARCHAR) WITH (connector = 'nexmark', nexmark_table = 'person',
+    rows_per_chunk = 64)""",
+    """CREATE SOURCE auction (id BIGINT, item_name VARCHAR,
+    description VARCHAR, initial_bid BIGINT, reserve BIGINT,
+    date_time TIMESTAMP, expires TIMESTAMP, seller BIGINT, category BIGINT,
+    extra VARCHAR) WITH (connector = 'nexmark', nexmark_table = 'auction',
+    rows_per_chunk = 192)""")
+Q8_MV = """CREATE MATERIALIZED VIEW q8 AS
+    SELECT P.id, P.name, P.starttime FROM (
+        SELECT id, name, window_start AS starttime, window_end AS endtime
+        FROM TUMBLE(person, date_time, INTERVAL '10' SECOND)
+        GROUP BY id, name, window_start, window_end) P
+    JOIN (
+        SELECT seller, window_start AS starttime, window_end AS endtime
+        FROM TUMBLE(auction, date_time, INTERVAL '10' SECOND)
+        GROUP BY seller, window_start, window_end) A
+    ON P.id = A.seller AND P.starttime = A.starttime
+       AND P.endtime = A.endtime"""
+Q8_CHUNKS = 2
+
+
+def open_q8(data_dir) -> Session:
+    s = Session(config=BuildConfig(chunk_capacity=128,
+                                   agg_table_capacity=1 << 12,
+                                   join_key_capacity=1 << 12,
+                                   join_bucket_width=1),
+                chunks_per_tick=Q8_CHUNKS, checkpoint_frequency=3,
+                data_dir=data_dir)
+    for ddl in Q8_DDL:
+        s.run_sql(ddl)
+    s.run_sql(Q8_MV)
+    return s
+
+
+def join_engine(s: Session):
+    from risingwave_tpu.stream.metrics import iter_executors
+    (join,) = [ex for ex in iter_executors(s.jobs["q8"].pipeline)
+               if ex.identity == "HashJoin"]
+    return join
+
+
+@pytest.fixture(scope="module")
+def q8_run(tmp_path_factory):
+    s = open_q8(str(tmp_path_factory.mktemp("q8") / "db"))
+    GLOBAL_TRACE.clear()
+    first = s.epoch + 1
+    for _ in range(7):
+        s.tick()
+    spans = {e: v for e, v in tracing.epoch_spans().items() if e >= first}
+    history = {r["epoch"]: r for r in s._barrier_ledger.history()}
+    yield s, spans, history
+    s.close()
+
+
+@pytest.mark.parametrize("kind", ["ordinary", "checkpoint"])
+def test_join_spans_and_their_args_on_both_kinds_of_barrier(q8_run, kind):
+    s, by_epoch, history = q8_run
+    epochs = [e for e in by_epoch
+              if history[e]["checkpoint"] == (kind == "checkpoint")]
+    assert len(epochs) >= 2
+    for epoch in epochs:
+        spans = by_epoch[epoch]
+        by_id = {d["id"]: d for d in spans}
+
+        def parent_of(d):
+            return by_id[d["parent"]]["name"] if d["parent"] else None
+
+        (chunks,) = [d for d in spans if d["name"] == "HashJoin.chunks"]
+        assert parent_of(chunks) == "barrier.collect"
+        args = chunks["args"]
+        # every person is a new (id, name, window) group: all of the
+        # barrier's persons reach the join's left input, once
+        assert args["rows_in_left"] == Q8_CHUNKS * 64
+        assert 0 < args["rows_in_right"] <= Q8_CHUNKS * 192
+        assert args["chunks"] >= 2 and args["chunks_out"] >= 0
+        assert args["rewinds"] == 0 and args["grows"] == 0
+        waits = [d for d in spans if d["name"] == "join.emit_wait"]
+        assert waits and all(d["wait"] == "device" for d in waits)
+        assert {parent_of(d) for d in waits} == {"barrier.collect"}
+        deltas = [d for d in spans if d["name"] == "join.state_delta"]
+        if kind == "ordinary":
+            assert deltas == []
+            continue
+        assert [d["args"]["side"] for d in deltas] == ["left", "right"]
+        assert {parent_of(d) for d in deltas} == {"HashJoin.barrier"}
+        for d in deltas:
+            assert d["args"]["dirty_rows"] > 0
+            assert d["args"]["bytes_fetched"] > d["args"]["bytes_staged"] > 0
+        assert history[epoch]["stages"]["state_delta"] > 0
+    # the left side's dirty rows of a checkpoint are the persons since the
+    # one before it
+    if kind == "checkpoint":
+        left = [d["args"]["dirty_rows"] for e in epochs[1:]
+                for d in by_epoch[e] if d["name"] == "join.state_delta"
+                and d["args"]["side"] == "left"]
+        assert left == [3 * Q8_CHUNKS * 64] * len(left)
+
+
+def test_join_programs_carry_their_names_and_scopes(q8_run):
+    s, _by_epoch, _history = q8_run
+    join = join_engine(s)
+    from risingwave_tpu.common.chunk import physical_chunk
+    ch = physical_chunk(join.core.left_schema, [], 128)
+    low = join._apply["left"].lower(join.state, ch, None)
+    assert "module @jit_join_step_left " in low.as_text()[:200]
+    text = low.as_text(debug_info=True)
+    for scope in ("join_probe", "join_insert", "join_emit"):
+        assert re.search(rf"{scope}\)?/", text), scope
+    assert join._apply["right"].__wrapped__.__name__ == "join_step_right"
+    assert join._gather.__wrapped__.__name__ == "join_gather"
+    assert join._pack_stats.__wrapped__.__name__ == "join_pack_stats"
