@@ -136,7 +136,7 @@ class StreamJob:
                           capacity: int = 1024) -> list[Message]:
         """Initial feed for a new subscriber: current MV rows as insert
         chunks (the backfill snapshot), before live deltas resume."""
-        rows = list(self.table.scan_all())
+        rows = list(self.pipeline.scan_all())
         msgs: list[Message] = []
         for i in range(0, len(rows), capacity):
             msgs.append(physical_chunk(

@@ -4558,7 +4558,7 @@ class Session:
                     for r in self._remote_scan(name, mv.schema)]
         job = self.jobs[name]
         rows = []
-        for phys in job.table.scan_all():
+        for phys in job.pipeline.scan_all():
             rows.append(tuple(
                 None if v is None else mv.schema[i].type.to_python(v)
                 for i, v in enumerate(phys[:n_vis])))

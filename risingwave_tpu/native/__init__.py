@@ -91,6 +91,12 @@ class RowCodec:
     def __init__(self, lib: ctypes.CDLL):
         self.lib = lib
 
+    @staticmethod
+    def supports(types) -> bool:
+        """True iff the codec has code for every one of ``types`` (LIST,
+        STRUCT and JSONB rows stay with the Python encoders)."""
+        return all(t.kind.name in _CODE_BY_KIND for t in types)
+
     def _prep_columns(self, datas: Sequence[np.ndarray],
                       masks: Sequence[np.ndarray], types) -> tuple:
         """-> (codes, data_ptrs, mask_ptrs, blob_ptrs, off_ptrs, keepalive,

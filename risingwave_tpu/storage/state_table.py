@@ -70,6 +70,23 @@ class StateTable:
             self._puts.pop(k, None)
             self._puts_enc[k] = v
 
+    def stage_ops(self, keys: Sequence[bytes], values: Sequence[bytes],
+                  is_put: Sequence[bool]) -> None:
+        """An ORDERED batch already in durable form — the MV egress path
+        (stream/materialize.py): ``keys[i]`` is put or deleted as
+        ``is_put[i]`` says, in that order; ``values`` holds one encoded row
+        per put, in the same order. Semantically identical to insert()/
+        delete() row by row: the last operation on a pk wins."""
+        value = iter(values)
+        for k, put in zip(keys, is_put):
+            self._puts.pop(k, None)
+            if put:
+                self._dels.discard(k)
+                self._puts_enc[k] = next(value)
+            else:
+                self._puts_enc.pop(k, None)
+                self._dels.add(k)
+
     def update(self, old_row: Sequence[Any], new_row: Sequence[Any]) -> None:
         ko, kn = self.key_of(old_row), self.key_of(new_row)
         if ko != kn:

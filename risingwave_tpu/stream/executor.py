@@ -64,6 +64,12 @@ class SingleInputExecutor(Executor):
     async def on_watermark(self, watermark: Watermark):
         yield watermark
 
+    def epoch_counts(self) -> dict:
+        """Further args of the epoch's ``<identity>.chunks`` span: counts
+        the operator keeps itself, asked for once a barrier, after
+        ``on_barrier``."""
+        return {}
+
     async def execute(self) -> AsyncIterator[Message]:
         from .metrics import ChunkClock, barrier_timer
         stats = self.stats
@@ -83,9 +89,10 @@ class SingleInputExecutor(Executor):
                     stats.chunks_out += 1
                     yield out
             elif isinstance(msg, Barrier):
-                clock.emit(self.identity, msg.epoch.curr)
                 with barrier_timer(stats, self.identity, msg.epoch.curr):
                     outs = [out async for out in self.on_barrier(msg)]
+                clock.emit(self.identity, msg.epoch.curr,
+                           **self.epoch_counts())
                 for out in outs:
                     stats.chunks_out += 1
                     yield out

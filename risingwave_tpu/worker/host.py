@@ -776,7 +776,7 @@ class WorkerHost:
                     "error": f"job {name!r} hosts no table on this worker"}
         schema = job.pipeline.schema
         types = [f.type for f in schema]
-        rows = list(job.table.scan_all())
+        rows = list(job.pipeline.scan_all())
         rv = getattr(job, "root_vnodes", None)
         if rv is not None:
             # vnode-distributed root MV: serve only the owned range. A

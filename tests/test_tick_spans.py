@@ -49,6 +49,7 @@ CONDUCTOR = {
     "barrier.collect": "session.tick",
     "Materialize.chunks": "barrier.collect",
     "Materialize.barrier": "barrier.collect",
+    "materialize.fetch_wait": "Materialize.barrier",
     "Materialize.seal": "Materialize.barrier",
 }
 EVERY_BARRIER = {
