@@ -697,9 +697,10 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
         out["q5"] = s.run_sql(Q5_SELECT)
         if mesh:
             check(not s.metrics()["coschedule"]["jobs"]
-                  and len(s._shardfused_engines) == 1,
+                  and [e.kind.name for e in s._fused.engines.values()]
+                  == ["shardfused"],
                   "mesh session did not take the sharded fused epoch")
-            group = s._shardfused_engines["q5"][3]
+            (group,) = s._fused.groups()
             spread_over(mesh, "q5",
                         jax.tree_util.tree_leaves(group.stacked), out)
         else:

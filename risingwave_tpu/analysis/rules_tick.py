@@ -34,23 +34,24 @@ from .core import Finding, Module, Package, Rule, register
 
 PKG = "risingwave_tpu"
 
-#: the tick path's root set: Session's tick drivers plus every fused
-#: engine's per-tick surface (the callgraph cannot statically type
-#: ``group.run_epoch(...)`` receivers, so the engine methods are roots
-#: in their own right — "reachable from _tick_impl through the
-#: engines"). Method-name sets keep checkpoint/recovery/debug surfaces
+#: the tick path's root set: Session's tick drivers, the fused-job
+#: registry's driver, and every fused group's per-tick surface (the
+#: callgraph cannot statically type ``self._fused.tick(...)`` or
+#: ``group.run_epoch(...)`` receivers, so those methods are roots in
+#: their own right — "reachable from _tick_impl through the engines").
+#: Method-name sets keep checkpoint/recovery/debug surfaces
 #: (export_host, merged_group_values) out of scope: they run on the
 #: durable path, not per tick.
+_GROUP_TICK = ("run_epoch", "flush", "begin_flush", "finish_flush")
 TICK_ROOTS = (
     ("frontend/session.py", ("Session",),
-     ("_tick_impl", "_cosched_tick", "_shardfused_tick",
-      "_complete_oldest_impl", "_drain_fused_pipeline",
-      "_push_cosched_outs", "_push_shardfused_outs")),
-    ("stream/coschedule.py", ("CoGroup",),
-     ("run_epoch", "flush", "begin_flush", "finish_flush")),
+     ("_tick_impl", "_complete_oldest_impl")),
+    ("stream/fused_jobs.py", ("FusedJobs",), ("tick", "drain")),
+    ("stream/coschedule.py", ("JobAxisGroup", "CoGroup"), _GROUP_TICK),
+    ("stream/tick_compiler.py", ("PaddedHeteroGroup", "MegaGroup"),
+     _GROUP_TICK),
     ("parallel/fused.py", None,      # every engine class in the module
-     ("run_epoch", "flush", "begin_flush", "finish_flush",
-      "_settle", "_settled_packed")),
+     _GROUP_TICK + ("_settle", "_settled_packed")),
 )
 
 #: the one module allowed to call jax.device_get on the tick path
@@ -59,7 +60,8 @@ EXEMPT_MODULES = ("common/fetch.py",)
 #: modules where a bare np.asarray(<call>/<attr>) is treated as a
 #: device-value materialization (the engine drivers); elsewhere
 #: np.asarray over host rows is routine
-DEVICE_DRIVER_MODULES = ("stream/coschedule.py", "parallel/fused.py",
+DEVICE_DRIVER_MODULES = ("stream/coschedule.py", "stream/tick_compiler.py",
+                         "stream/fused_jobs.py", "parallel/fused.py",
                          "ops/", "frontend/session.py")
 
 

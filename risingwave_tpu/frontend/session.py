@@ -35,6 +35,7 @@ from ..storage.state_store import MemoryStateStore
 from ..storage.state_table import StateTable
 from ..stream.eowc import WatermarkFilterExecutor
 from ..stream.executor import Executor
+from ..stream.fused_jobs import MARKER_PREFIXES, FusedJobs
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Mutation, MutationKind
 from ..stream.row_id_gen import RowIdGenExecutor
@@ -536,14 +537,11 @@ class Session:
         if role == "writer":
             self.meta.advance_epoch_clock(self.epoch)
         self.jobs: dict[str, StreamJob] = {}          # mv/table name -> job
-        # epoch co-scheduler: eligible MVs' epochs batched into one
-        # dispatch per tick (stream/coschedule.py; [streaming]
-        # coschedule = true). Engines map job -> (flush HashAggExecutor,
-        # output queue, device source cursor).
-        from ..stream.coschedule import CoScheduler
-        self._cosched = CoScheduler()
-        self._cosched_engines: dict[str, tuple] = {}
-        self._cosched_markers: set[str] = set()
+        # fused jobs (stream/fused_jobs.py): eligible source+agg MVs run
+        # their whole epoch inside one scheduler's group dispatch —
+        # co-scheduled ([streaming] coschedule = true), tick-compiled
+        # (tick_compiler = true) or mesh-sharded (coschedule + a mesh)
+        self._fused = FusedJobs(self, SqlError)
         # asynchronous epoch pipeline ([streaming] pipeline_depth,
         # docs/performance.md "Pipelined tick"): depth >= 2 defers each
         # fused group's packed flush fetch to the NEXT tick, so epoch
@@ -552,31 +550,6 @@ class Session:
         # barriers, FLUSH, DDL and recovery, so committed state is
         # bit-exact vs the synchronous path
         self.pipeline_depth = max(1, int(pipeline_depth))
-        self._pipeline_stats = {"deferred_flushes": 0, "drains": 0}
-        # mesh-sharded fused MVs (ops/fused_sharded.py): with a mesh AND
-        # the coschedule opt-in, eligible MVs join a signature-keyed
-        # K-jobs × S-shards group (parallel/fused.ShardedCoGroup) — a
-        # whole group ticks as ONE dispatch per epoch across all chips.
-        # Engines map job -> (flush/persistence HashAggExecutor, output
-        # queue, device source cursor, its ShardedCoGroup).
-        self._shardfused = None        # lazy ShardedCoScheduler
-        self._shardfused_engines: dict[str, tuple] = {}
-        self._shardfused_markers: set[str] = set()
-        # the heterogeneous tick compiler (stream/tick_compiler.py;
-        # [streaming] tick_compiler = true): eligible MVs — even
-        # DISSIMILAR ones — join a compiled dispatch schedule
-        # (shape-class padded supergroups + jitted mega-epochs),
-        # recompiled lazily on DDL. Engines map job -> (flush
-        # HashAggExecutor, output queue, device source cursor).
-        from ..stream.tick_compiler import TickCompiler
-        self._hetero = TickCompiler()
-        self._hetero_engines: dict[str, tuple] = {}
-        self._hetero_markers: set[str] = set()
-        # epochs run by fused engines this session has since dropped,
-        # per dispatch qualname — the profiler's counts are cumulative,
-        # so the live per_epoch invariant ratio must keep dividing by
-        # these epochs after a DROP + re-CREATE
-        self._dispatch_epochs_retired: dict[str, int] = {}
         self.feeds: list[_SourceFeed] = []
         self.backfills: list[_BackfillRef] = []
         # DML rendezvous (reference: DmlManager, src/source/src/
@@ -699,41 +672,16 @@ class Session:
         resched_cfg: dict[str, object] = {}
         for piece in ddl:
             line = piece.strip()
-            if line.startswith("-- coschedule"):
-                # the job was built as a co-scheduled fused group member
-                # (stream/coschedule.py); its durable layout only decodes
-                # on that path — _create_mv refuses a mismatched replay
-                self._cosched_markers.add(
-                    line[len("-- coschedule"):].strip())
-                continue
-            if line.startswith("-- shardfused"):
-                # mesh-sharded fused MV (ops/fused_sharded.py): replay
-                # routes back down that path (re-sharding onto THIS
-                # session's mesh by replaying the vnode mapping) or
-                # refuses loudly — marker-directed in both directions,
-                # like the coschedule marker above
-                self._shardfused_markers.add(
-                    line[len("-- shardfused"):].strip())
-                continue
-            if line.startswith("-- hetero"):
-                # tick-compiled MV (stream/tick_compiler.py): replay
-                # routes back into the compiled schedule or refuses
-                # loudly — marker-directed in both directions, same as
-                # the coschedule marker above
-                self._hetero_markers.add(line[len("-- hetero"):].strip())
+            if self._fused.parse_marker(line):
                 continue
             if not line.startswith("-- reschedule"):
-                if (resched_cfg or self._cosched_markers
-                        or self._shardfused_markers
-                        or self._hetero_markers) \
+                if (resched_cfg or any(self._fused.markers.values())) \
                         and "drop" in line.lower():
                     try:
                         for stmt in parse_sql(piece):
                             if isinstance(stmt, A.DropStatement):
                                 resched_cfg.pop(stmt.name, None)
-                                self._cosched_markers.discard(stmt.name)
-                                self._shardfused_markers.discard(stmt.name)
-                                self._hetero_markers.discard(stmt.name)
+                                self._fused.forget(stmt.name)
                     except Exception:  # noqa: BLE001 - replay parses below
                         pass
                 continue
@@ -780,9 +728,8 @@ class Session:
         self._recovering = True
         try:
             for piece in ddl:
-                if piece.strip().startswith(("-- reschedule",
-                                             "-- coschedule",
-                                             "-- hetero")):
+                if piece.strip().startswith(("-- reschedule",)
+                                            + MARKER_PREFIXES):
                     continue
                 for stmt in parse_sql(piece):
                     name = getattr(stmt, "name", None)
@@ -1156,17 +1103,7 @@ class Session:
         self.feeds.clear()
         self.backfills.clear()
         self._table_queues.clear()
-        from ..stream.coschedule import CoScheduler
-        self._cosched = CoScheduler()
-        self._cosched_engines.clear()
-        self._cosched_markers.clear()
-        self._shardfused = None
-        self._shardfused_engines.clear()
-        self._shardfused_markers.clear()
-        from ..stream.tick_compiler import TickCompiler
-        self._hetero = TickCompiler()
-        self._hetero_engines.clear()
-        self._hetero_markers.clear()
+        self._fused.reset()
         self._dead_jobs.clear()
         self._jobs_to_recover.clear()
         # discard staged-but-uncommitted state: fully discarded is the
@@ -1582,80 +1519,26 @@ class Session:
                     "fragment graph; restart with the same multi-worker "
                     "topology (or DROP and re-CREATE it)")
             return self._create_mv_remote(stmt)
-        cosched_plan = None
-        if not pk_prefix and getattr(self.config, "coschedule", False) \
-                and self.config.mesh is not None \
-                and self.config.agg_hbm_budget is None \
-                and (not self._recovering
-                     or stmt.name in self._shardfused_markers):
-            # mesh-sharded fused path (ops/fused_sharded.py): with a mesh
-            # AND the fused opt-in, an eligible MV's whole epoch runs as
-            # one dispatch across all chips; ineligible shapes fall
-            # through to the mesh-sharded EXECUTORS (parallel/
-            # executors.py) below. Recovery is marker-directed in both
-            # directions, and re-shards onto THIS session's mesh size by
-            # replaying the vnode mapping over the committed rows.
-            res, cosched_plan = self._try_shardfused_mv(stmt)
-            if res is not None:
-                return res
-        if self._recovering and stmt.name in self._shardfused_markers:
-            raise SqlError(
-                f"MV {stmt.name!r} was created mesh-sharded fused; reopen "
-                "the session with a device mesh ([streaming] mesh_shape / "
-                "BuildConfig.mesh) and [streaming] coschedule = true — or "
-                "DROP and re-CREATE it")
-        if not pk_prefix \
-                and getattr(self.config, "tick_compiler", False) \
-                and self.config.mesh is None \
-                and self.config.fragment_parallelism <= 1 \
-                and self.config.agg_hbm_budget is None \
-                and (not self._recovering
-                     or stmt.name in self._hetero_markers):
-            # the heterogeneous tick compiler (stream/tick_compiler.py):
-            # an eligible MV joins the compiled dispatch schedule even
-            # when no signature-equal sibling exists — shape-class
-            # padding / mega-epoch concatenation replace the exact-
-            # signature grouping rule. Wins over ``coschedule`` when
-            # both are set; ineligible shapes fall through. Recovery is
-            # marker-directed in both directions, like coschedule.
-            res, cosched_plan = self._try_hetero_mv(stmt)
-            if res is not None:
-                return res
-        if self._recovering and stmt.name in self._hetero_markers:
-            raise SqlError(
-                f"MV {stmt.name!r} was created tick-compiled; reopen the "
-                "session with [streaming] tick_compiler = true and a "
-                "compatible config (no mesh, fragment_parallelism 1, "
-                "no agg_hbm_budget) — or DROP and re-CREATE it")
-        if not pk_prefix and getattr(self.config, "coschedule", False) \
-                and self.config.mesh is None \
-                and self.config.fragment_parallelism <= 1 \
-                and self.config.agg_hbm_budget is None \
-                and (not self._recovering
-                     or stmt.name in self._cosched_markers):
-            # agg_hbm_budget: the co-scheduled flush has no eviction
-            # path, so budgeted configs stay on the executor pipeline.
-            # Recovery gate: a solo-created MV's table-id layout differs
-            # from the co-scheduled one — replay it down the path that
-            # wrote it, marker-directed in BOTH directions.
-            res, cosched_plan = self._try_coschedule_mv(stmt)
-            if res is not None:
-                return res
-        if self._recovering and stmt.name in self._cosched_markers:
-            # the durable agg/split tables were laid out by the
-            # co-scheduled builder; decoding them through the executor
-            # path would shift table ids — refuse loudly
-            raise SqlError(
-                f"MV {stmt.name!r} was created co-scheduled; reopen the "
-                "session with [streaming] coschedule = true and a "
-                "co-schedulable config (no mesh, fragment_parallelism 1, "
-                "no agg_hbm_budget) — or DROP and re-CREATE it")
+        id0 = self.catalog._next_table_id   # for reschedule id replay
+        # fused jobs (stream/fused_jobs.py): an eligible source+agg MV
+        # runs inside a scheduler's group dispatch; ineligible shapes
+        # fall through to the executors below, reusing the plan. A
+        # recovered MV replays down the path that wrote it, or refuses.
+        fused, fused_plan = self._fused.route(
+            stmt, self.config, self._recovering, pk_prefix)
+        if fused is not None:
+            self.feeds.append(_SourceFeed(
+                fused.queue, lambda: None, reader=fused.cursor,
+                state_table=fused.split_state, job=stmt.name))
+            return self._launch_mv(
+                stmt, fused_plan, list(fused_plan.pk), fused.materialize,
+                (fused.agg.state_table.table_id,), id0, [fused.queue],
+                init_msgs=[(fused.queue, [])])
         n_feeds0 = len(self.feeds)
         n_bf0 = len(self.backfills)
-        id0 = self.catalog._next_table_id   # for reschedule id replay
         (plan, pipeline, ctx, queues, init_msgs,
          scan_leaf_queues) = self._build_query_pipeline(
-            stmt.query, plan=cosched_plan)
+            stmt.query, plan=fused_plan)
         mv_table_id = self.catalog.next_table_id()
         mv_pk = list(plan.pk)
         if pk_prefix:
@@ -1670,23 +1553,32 @@ class Session:
         # (no _maybe_rebackfill here: scan leaves re-run their own backfill
         # from the persisted cursor — created-but-never-checkpointed
         # recovery is the empty-progress case of stream/backfill.py)
-        n_visible = sum(1 for f in plan.schema if not f.name.startswith("_"))
+        for f in self.feeds[n_feeds0:]:
+            f.job = stmt.name
+        for b in self.backfills[n_bf0:]:
+            b.job = stmt.name
+        return self._launch_mv(stmt, plan, mv_pk, mat, ctx.state_table_ids,
+                               id0, queues, ctx.actors, init_msgs)
+
+    def _launch_mv(self, stmt: A.CreateMaterializedView, plan, mv_pk: list,
+                   mat: MaterializeExecutor, state_table_ids, id0: int,
+                   queues: list, actors: Sequence = (),
+                   init_msgs: Sequence = ()) -> list:
+        """Enter a built MV into the catalog, start its job and join it
+        to the barrier stream at the current epoch."""
         mv = MaterializedViewDef(
-            stmt.name, plan.schema, tuple(mv_pk), table_id=mv_table_id,
+            stmt.name, plan.schema, tuple(mv_pk), table_id=mat.table.table_id,
             definition="")
-        mv.n_visible = n_visible  # type: ignore[attr-defined]
-        mv.state_table_ids = tuple(ctx.state_table_ids)  # type: ignore[attr-defined]
+        mv.n_visible = sum(  # type: ignore[attr-defined]
+            1 for f in plan.schema if not f.name.startswith("_"))
+        mv.state_table_ids = tuple(state_table_ids)  # type: ignore[attr-defined]
         # reschedule metadata: the query AST + the id range the build
         # consumed (allocation order is deterministic, so a rebuild can
         # replay the same ids over the same durable state tables)
         mv.query_ast = stmt.query  # type: ignore[attr-defined]
         mv.table_id_range = (id0, self.catalog._next_table_id)  # type: ignore[attr-defined]
         self.catalog_writer.add_mv(mv)
-        for f in self.feeds[n_feeds0:]:
-            f.job = stmt.name
-        for b in self.backfills[n_bf0:]:
-            b.job = stmt.name
-        job = StreamJob(stmt.name, mat, queues, actors=ctx.actors)
+        job = StreamJob(stmt.name, mat, queues, actors=actors)
         self.jobs[stmt.name] = job
         job.start(self.loop)
         # the next barrier announces the new downstream to the graph
@@ -1699,565 +1591,6 @@ class Session:
             q.push(Barrier.new(self.epoch))
         self._await(job.wait_barrier(self.epoch))
         return []
-
-    # ------------------------------------------------- co-scheduled MV jobs --
-
-    def _try_coschedule_mv(self, stmt: A.CreateMaterializedView):
-        """Route an eligible source+agg plan into the epoch co-scheduler
-        (stream/coschedule.py): the group of all such MVs ticks in ONE
-        fused dispatch per epoch. Returns ``(result, plan)``; result is
-        None when the shape is ineligible (the solo executor fallback —
-        which reuses ``plan`` instead of planning the query twice)."""
-        from ..stream.coschedule import match_coschedulable
-        if not any(sd.connector == "nexmark"
-                   for sd in self.catalog.sources.values()):
-            # cheap gate: without an eligible source no plan can match —
-            # skip the extra planning pass the match would need
-            return None, None
-        plan = self._plan(stmt.query, lenient=self._recovering)
-        m = match_coschedulable(plan)
-        if m is None:
-            return None, plan
-        return self._create_mv_coscheduled(stmt, plan, m), plan
-
-    def _create_mv_coscheduled(self, stmt: A.CreateMaterializedView,
-                               plan, m) -> list:
-        """Build one co-scheduled fused MV job: ingest happens inside the
-        group's single vmapped dispatch; a real HashAggExecutor (over a
-        dummy source, never executed) is kept as the flush/persistence
-        engine so state-table checkpointing and recovery load are the
-        executor path's own code; the MV pipeline is a plain
-        QueueSource → Materialize fed by the group's barrier flush."""
-        from ..common.types import INT64, VARCHAR
-        from ..connector import NexmarkConfig
-        from ..connector.nexmark import DeviceBidGenerator
-        from ..stream.coschedule import (
-            DeviceSourceCursor, FusedJobSpec, agg_signature,
-            declared_chunk_fn,
-        )
-        from ..stream.hash_agg import HashAggExecutor, agg_state_schema
-        from ..stream.project import ProjectExecutor
-        from ..stream.source import MockSource
-
-        # group membership changes restack the job axis: resolve any
-        # deferred flush first (pipeline_depth >= 2)
-        self._drain_fused_pipeline()
-        id0 = self.catalog._next_table_id
-        proj = ProjectExecutor(MockSource(m.source.schema, []),
-                               list(m.exprs), names=m.proj_names)
-        key_fields = [proj.schema[i] for i in m.group_keys]
-        st = StateTable(self.store, self.catalog.next_table_id(),
-                        agg_state_schema(key_fields, m.agg_calls),
-                        list(range(len(m.group_keys))))
-        agg = HashAggExecutor(
-            proj, list(m.group_keys), list(m.agg_calls), state_table=st,
-            table_capacity=self.config.agg_table_capacity,
-            out_capacity=self.config.chunk_capacity)
-        # split-state table: the device generator's event/epoch cursor,
-        # persisted per checkpoint epoch exactly like a connector reader
-        split_st = StateTable(
-            self.store, self.catalog.next_table_id(),
-            Schema((Field("split_id", VARCHAR),
-                    Field("next_offset", INT64))), [0])
-        cursor = DeviceSourceCursor()
-        if self._recovering:
-            offsets = {VARCHAR.to_python(r[0]): int(r[1])
-                       for r in split_st.scan_all()}
-            if offsets:
-                cursor.seek(offsets)
-        mv_table_id = self.catalog.next_table_id()
-        q = QueueSource(plan.schema)
-        mat = MaterializeExecutor(
-            q, StateTable(self.store, mv_table_id, plan.schema,
-                          list(plan.pk)))
-        # honor the declared source's rows_per_chunk exactly like the
-        # host reader does (connector/factory.py make_reader)
-        rate = (m.source.options or {}).get("rows_per_chunk")
-        rows_per_chunk = int(rate) if rate else self.source_chunk_capacity
-        # seed parity with the solo executor path: every nexmark reader
-        # is seeded with the session seed (factory.make_reader), so the
-        # same CREATE yields the same stream regardless of the flag
-        src_cfg = NexmarkConfig(chunk_capacity=rows_per_chunk)
-        gen = DeviceBidGenerator(src_cfg, seed=self.seed)
-        source_sig = ("nexmark_bid", src_cfg.chunk_capacity,
-                      src_cfg.events_per_second, src_cfg.active_people,
-                      src_cfg.in_flight_auctions, src_cfg.start_time_us,
-                      m.col_map,
-                      tuple(sorted((m.source.options or {}).items())))
-        spec = FusedJobSpec(
-            kind="agg",
-            signature=agg_signature(agg.core, m.exprs, rows_per_chunk,
-                                    source_sig),
-            chunk_fn=declared_chunk_fn(gen.chunk_fn(), m.col_map),
-            exprs=tuple(m.exprs), core=agg.core,
-            rows_per_chunk=rows_per_chunk, seed=self.seed)
-
-        mv = MaterializedViewDef(stmt.name, plan.schema, tuple(plan.pk),
-                                 table_id=mv_table_id, definition="")
-        mv.n_visible = sum(  # type: ignore[attr-defined]
-            1 for f in plan.schema if not f.name.startswith("_"))
-        mv.state_table_ids = (st.table_id,)  # type: ignore[attr-defined]
-        mv.query_ast = stmt.query  # type: ignore[attr-defined]
-        mv.table_id_range = (  # type: ignore[attr-defined]
-            id0, self.catalog._next_table_id)
-        self.catalog_writer.add_mv(mv)
-        job = StreamJob(stmt.name, mat, [q])
-        self.jobs[stmt.name] = job
-        job.start(self.loop)
-        self.feeds.append(_SourceFeed(q, lambda: None, reader=cursor,
-                                      state_table=split_st,
-                                      job=stmt.name))
-        self._cosched.add(stmt.name, spec, agg.state,
-                          start=cursor.events, batch_no=cursor.epochs)
-        self._cosched_engines[stmt.name] = (agg, q, cursor)
-        if self.data_dir is not None and not self._recovering:
-            self.store.log.log_ddl(  # type: ignore[attr-defined]
-                f"-- coschedule {stmt.name}")
-        self._pending_mutation = Mutation(MutationKind.ADD, stmt.name)
-        q.push(Barrier.new(self.epoch))
-        self._await(job.wait_barrier(self.epoch))
-        return []
-
-    def _push_cosched_outs(self, outs: dict) -> None:
-        """Feed a resolved group flush into each member MV's
-        Materialize queue (they ride the next barrier)."""
-        for name, chunks in outs.items():
-            q = self._cosched_engines[name][1]
-            for ch in chunks:
-                q.push(ch)
-
-    def _cosched_tick(self, epoch: int, checkpoint: bool,
-                      generate: bool) -> None:
-        """Per-tick driver: ONE fused dispatch per group covers every
-        member MV's epoch; the group flush feeds each job's Materialize
-        queue; checkpoint barriers reuse the HashAggExecutor's own
-        state-table delta flush, then restack once.
-
-        Pipelined cadence (docs/performance.md "Pipelined tick"): the
-        LAST tick's deferred flushes resolve first (their packed fetch
-        has been streaming while the host ran the previous barrier, and
-        their chunks ride THIS barrier), then EVERY group's next epoch
-        is enqueued before any flush decode — the device queue stays
-        full while Python gathers. With ``pipeline_depth >= 2`` the new
-        flush stays pending into the next tick; checkpoint barriers
-        (and generate-off ticks) resolve it synchronously, so committed
-        state is bit-exact vs the synchronous path."""
-        from ..common.tracing import CAT_EPOCH, span
-        k = self.chunks_per_tick
-        groups = list(self._cosched.groups.values())
-
-        def conductor(name: str, stage: Optional[str]):
-            return span(name, epoch=epoch, stage=stage, cat=CAT_EPOCH,
-                        tid="conductor")
-
-        # 1. resolve last tick's deferred flushes (pipeline_depth >= 2);
-        #    the wait and the decode inside carry the stages
-        if any(group.pending is not None for group in groups):
-            with conductor("cosched.resolve_deferred", None):
-                for group in groups:
-                    if group.pending is not None:
-                        self._push_cosched_outs(group.finish_flush())
-        # 2. enqueue every group's epoch (cross-engine overlap)
-        ran = generate and k > 0
-        if ran:
-            with conductor("cosched.dispatch", "epoch_dispatch"):
-                for group in groups:
-                    group.run_epoch(k)
-                    for j, name in enumerate(group.names):
-                        cursor = self._cosched_engines[name][2]
-                        cursor.events = group.starts[j]
-                        cursor.epochs = group.batch_nos[j]
-        # 3. enqueue every group's probe + start its packed fetch BEFORE
-        #    decoding any of them
-        with conductor("cosched.flush_begin", "epoch_dispatch"):
-            for group in groups:
-                group.begin_flush()
-        if self.pipeline_depth >= 2 and ran and not checkpoint:
-            # 4a. defer resolution to the next tick / drain point: epoch
-            # N+1 will dispatch before this packed fetch resolves
-            self._pipeline_stats["deferred_flushes"] += len(groups)
-            return
-        # 4b. synchronous resolution (depth 1, checkpoint, or idle tick)
-        for group in groups:
-            self._push_cosched_outs(group.finish_flush())
-            if checkpoint:
-                ckpt_states = []
-                for name in group.names:
-                    agg = self._cosched_engines[name][0]
-                    with conductor("cosched.restack", "state_delta"):
-                        agg.state = group.state_of(name)
-                    agg._checkpoint_to_state_table(epoch)
-                    ckpt_states.append(agg.state)
-                with conductor("cosched.restack", "state_delta"):
-                    group.set_states(ckpt_states)
-
-    # ------------------------------------------ tick-compiled fused MV jobs --
-
-    def _try_hetero_mv(self, stmt: A.CreateMaterializedView):
-        """Route an eligible source+agg plan into the tick compiler
-        (stream/tick_compiler.py): UNEQUAL jobs are fused into minimal
-        dispatches — shape-class supergroups (padded + vmapped) plus
-        jitted mega-epochs for the singletons. Returns ``(result,
-        plan)``; result is None when the shape is ineligible (the solo
-        executor fallback, which reuses ``plan``)."""
-        from ..stream.coschedule import match_coschedulable
-        if not any(sd.connector == "nexmark"
-                   for sd in self.catalog.sources.values()):
-            return None, None
-        plan = self._plan(stmt.query, lenient=self._recovering)
-        m = match_coschedulable(plan)
-        if m is None:
-            return None, plan
-        return self._create_mv_hetero(stmt, plan, m), plan
-
-    def _create_mv_hetero(self, stmt: A.CreateMaterializedView,
-                          plan, m) -> list:
-        """Build one tick-compiled fused MV job. Mirrors
-        ``_create_mv_coscheduled`` — a real HashAggExecutor (never
-        executed) remains the flush/persistence engine so state-table
-        checkpointing and recovery load are the executor path's own
-        code — but registration goes to the TickCompiler, which
-        skeletonizes the plan and re-buckets the whole job set into
-        shape-class supergroups + mega-epochs on the next tick."""
-        from ..common.types import INT64, VARCHAR
-        from ..connector import NexmarkConfig
-        from ..connector.nexmark import DeviceBidGenerator
-        from ..stream.coschedule import (
-            DeviceSourceCursor, FusedJobSpec, agg_signature,
-            declared_chunk_fn,
-        )
-        from ..stream.hash_agg import HashAggExecutor, agg_state_schema
-        from ..stream.project import ProjectExecutor
-        from ..stream.source import MockSource
-
-        # registration dissolves every group (schedule recompile):
-        # resolve any deferred flush first (pipeline_depth >= 2)
-        self._drain_fused_pipeline()
-        id0 = self.catalog._next_table_id
-        proj = ProjectExecutor(MockSource(m.source.schema, []),
-                               list(m.exprs), names=m.proj_names)
-        key_fields = [proj.schema[i] for i in m.group_keys]
-        st = StateTable(self.store, self.catalog.next_table_id(),
-                        agg_state_schema(key_fields, m.agg_calls),
-                        list(range(len(m.group_keys))))
-        agg = HashAggExecutor(
-            proj, list(m.group_keys), list(m.agg_calls), state_table=st,
-            table_capacity=self.config.agg_table_capacity,
-            out_capacity=self.config.chunk_capacity)
-        split_st = StateTable(
-            self.store, self.catalog.next_table_id(),
-            Schema((Field("split_id", VARCHAR),
-                    Field("next_offset", INT64))), [0])
-        cursor = DeviceSourceCursor()
-        if self._recovering:
-            offsets = {VARCHAR.to_python(r[0]): int(r[1])
-                       for r in split_st.scan_all()}
-            if offsets:
-                cursor.seek(offsets)
-        mv_table_id = self.catalog.next_table_id()
-        q = QueueSource(plan.schema)
-        mat = MaterializeExecutor(
-            q, StateTable(self.store, mv_table_id, plan.schema,
-                          list(plan.pk)))
-        rate = (m.source.options or {}).get("rows_per_chunk")
-        rows_per_chunk = int(rate) if rate else self.source_chunk_capacity
-        src_cfg = NexmarkConfig(chunk_capacity=rows_per_chunk)
-        gen = DeviceBidGenerator(src_cfg, seed=self.seed)
-        source_sig = ("nexmark_bid", src_cfg.chunk_capacity,
-                      src_cfg.events_per_second, src_cfg.active_people,
-                      src_cfg.in_flight_auctions, src_cfg.start_time_us,
-                      m.col_map,
-                      tuple(sorted((m.source.options or {}).items())))
-        spec = FusedJobSpec(
-            kind="agg",
-            signature=agg_signature(agg.core, m.exprs, rows_per_chunk,
-                                    source_sig),
-            chunk_fn=declared_chunk_fn(gen.chunk_fn(), m.col_map),
-            exprs=tuple(m.exprs), core=agg.core,
-            rows_per_chunk=rows_per_chunk, seed=self.seed)
-
-        mv = MaterializedViewDef(stmt.name, plan.schema, tuple(plan.pk),
-                                 table_id=mv_table_id, definition="")
-        mv.n_visible = sum(  # type: ignore[attr-defined]
-            1 for f in plan.schema if not f.name.startswith("_"))
-        mv.state_table_ids = (st.table_id,)  # type: ignore[attr-defined]
-        mv.query_ast = stmt.query  # type: ignore[attr-defined]
-        mv.table_id_range = (  # type: ignore[attr-defined]
-            id0, self.catalog._next_table_id)
-        self.catalog_writer.add_mv(mv)
-        job = StreamJob(stmt.name, mat, [q])
-        self.jobs[stmt.name] = job
-        job.start(self.loop)
-        self.feeds.append(_SourceFeed(q, lambda: None, reader=cursor,
-                                      state_table=split_st,
-                                      job=stmt.name))
-        self._hetero.add(stmt.name, spec, agg.state,
-                         n_source_cols=len(m.col_map),
-                         start=cursor.events, batch_no=cursor.epochs)
-        self._fold_hetero_retired()
-        self._hetero_engines[stmt.name] = (agg, q, cursor)
-        if self.data_dir is not None and not self._recovering:
-            self.store.log.log_ddl(  # type: ignore[attr-defined]
-                f"-- hetero {stmt.name}")
-        self._pending_mutation = Mutation(MutationKind.ADD, stmt.name)
-        q.push(Barrier.new(self.epoch))
-        self._await(job.wait_barrier(self.epoch))
-        return []
-
-    def _fold_hetero_retired(self) -> None:
-        """Fold dissolved groups' epochs-run into the retirement ledger
-        so the dispatch/epoch invariant (``per_epoch == 1.0``) survives
-        schedule recompilation: the counts a dead group accumulated
-        still back the dispatches it issued."""
-        for qn, n in self._hetero.take_retired().items():
-            self._dispatch_epochs_retired[qn] = (
-                self._dispatch_epochs_retired.get(qn, 0) + n)
-
-    def _push_hetero_outs(self, outs: dict) -> None:
-        for name, chunks in outs.items():
-            q = self._hetero_engines[name][1]
-            for ch in chunks:
-                q.push(ch)
-
-    def _hetero_tick(self, epoch: int, checkpoint: bool,
-                     generate: bool) -> None:
-        """Per-tick driver for the tick compiler: one dispatch per
-        compiled group (shape-class supergroup or mega-epoch) covers
-        every member MV's epoch. Mirrors ``_cosched_tick`` — pipelined
-        cadence, deferred flush at ``pipeline_depth >= 2``, checkpoint
-        write-back through each job's own HashAggExecutor — but the
-        schedule is (re)compiled lazily here, only when DDL has marked
-        it dirty since the last tick."""
-        self._hetero.ensure_compiled()
-        k = self.chunks_per_tick
-        groups = list(self._hetero.groups)
-        # 1. resolve last tick's deferred flushes (pipeline_depth >= 2)
-        for group in groups:
-            if group.pending is not None:
-                self._push_hetero_outs(group.finish_flush())
-        # 2. enqueue every group's epoch (cross-group overlap)
-        ran = generate and k > 0
-        if ran:
-            for group in groups:
-                group.run_epoch(k)
-                for j, name in enumerate(group.names):
-                    cursor = self._hetero_engines[name][2]
-                    cursor.events = group.starts[j]
-                    cursor.epochs = group.batch_nos[j]
-        # 3. enqueue every group's probe + packed fetch before decoding
-        for group in groups:
-            group.begin_flush()
-        if self.pipeline_depth >= 2 and ran and not checkpoint:
-            self._pipeline_stats["deferred_flushes"] += len(groups)
-            return
-        # 4. synchronous resolution (depth 1, checkpoint, or idle tick)
-        for group in groups:
-            self._push_hetero_outs(group.finish_flush())
-            if checkpoint:
-                ckpt_states = []
-                for name in group.names:
-                    agg = self._hetero_engines[name][0]
-                    agg.state = group.state_of(name)
-                    agg._checkpoint_to_state_table(epoch)
-                    ckpt_states.append(agg.state)
-                group.set_states(ckpt_states)
-
-    # ------------------------------------------- mesh-sharded fused MV jobs --
-
-    def _try_shardfused_mv(self, stmt: A.CreateMaterializedView):
-        """Route an eligible source+agg plan onto the mesh-sharded fused
-        path (ops/fused_sharded.py + parallel/fused.py): the MV's whole
-        epoch — generation, projection, the in-dispatch all_to_all vnode
-        shuffle, aggregation — is ONE dispatch across every chip of
-        ``config.mesh``. Eligibility is exactly the co-scheduler's shape
-        match; anything else returns ``(None, plan)`` and builds the
-        mesh-sharded executor pipeline instead."""
-        from ..stream.coschedule import match_coschedulable
-        if not any(sd.connector == "nexmark"
-                   for sd in self.catalog.sources.values()):
-            return None, None
-        plan = self._plan(stmt.query, lenient=self._recovering)
-        m = match_coschedulable(plan)
-        if m is None:
-            return None, plan
-        return self._create_mv_sharded_fused(stmt, plan, m), plan
-
-    def _create_mv_sharded_fused(self, stmt: A.CreateMaterializedView,
-                                 plan, m) -> list:
-        """Build one mesh-sharded fused MV job. Mirrors
-        ``_create_mv_coscheduled``: a real HashAggExecutor (never
-        executed) is the flush/persistence engine, so the state-table
-        checkpoint delta and the durable layout are the executor path's
-        own code; the MV pipeline is QueueSource → Materialize fed by
-        the sharded group flush. TWO differences: state placement —
-        per-shard AggCore states live stacked under ``P('shard')`` and
-        recovery re-shards the committed rows onto THIS session's mesh
-        by replaying the vnode mapping (parallel/fused.py
-        ``load_shard_states``), so an 8-shard checkpoint reopens cleanly
-        on a 4-shard mesh — and multiplexing: signature-equal MVs join
-        ONE K-jobs × S-shards group (ShardedCoGroup, fusion surface 6),
-        so the whole group is one dispatch per tick, not one per MV."""
-        from ..common.types import INT64, VARCHAR
-        from ..connector import NexmarkConfig
-        from ..connector.nexmark import DeviceBidGenerator
-        from ..parallel.fused import ShardedCoScheduler, load_shard_states
-        from ..stream.coschedule import (
-            DeviceSourceCursor, FusedJobSpec, agg_signature,
-            declared_chunk_fn,
-        )
-        from ..stream.hash_agg import HashAggExecutor, agg_state_schema
-        from ..stream.project import ProjectExecutor
-        from ..stream.source import MockSource
-
-        # group membership changes restack the job axis: resolve any
-        # deferred flush first (pipeline_depth >= 2)
-        self._drain_fused_pipeline()
-        id0 = self.catalog._next_table_id
-        proj = ProjectExecutor(MockSource(m.source.schema, []),
-                               list(m.exprs), names=m.proj_names)
-        key_fields = [proj.schema[i] for i in m.group_keys]
-        st = StateTable(self.store, self.catalog.next_table_id(),
-                        agg_state_schema(key_fields, m.agg_calls),
-                        list(range(len(m.group_keys))))
-        # state_table attached AFTER construction: the executor's own
-        # recovery load would pull EVERY shard's rows into one solo
-        # table — the sharded load below re-partitions them instead
-        agg = HashAggExecutor(
-            proj, list(m.group_keys), list(m.agg_calls), state_table=None,
-            table_capacity=self.config.agg_table_capacity,
-            out_capacity=self.config.chunk_capacity)
-        agg.state_table = st
-        mesh = self.config.mesh
-        n_shards = mesh.devices.size
-        states = None
-        if self._recovering:
-            rows = list(st.scan_all())
-            if rows:
-                states = load_shard_states(agg.core, rows, n_shards)
-        split_st = StateTable(
-            self.store, self.catalog.next_table_id(),
-            Schema((Field("split_id", VARCHAR),
-                    Field("next_offset", INT64))), [0])
-        cursor = DeviceSourceCursor()
-        if self._recovering:
-            offsets = {VARCHAR.to_python(r[0]): int(r[1])
-                       for r in split_st.scan_all()}
-            if offsets:
-                cursor.seek(offsets)
-        mv_table_id = self.catalog.next_table_id()
-        q = QueueSource(plan.schema)
-        mat = MaterializeExecutor(
-            q, StateTable(self.store, mv_table_id, plan.schema,
-                          list(plan.pk)))
-        rate = (m.source.options or {}).get("rows_per_chunk")
-        rows_per_chunk = int(rate) if rate else self.source_chunk_capacity
-        src_cfg = NexmarkConfig(chunk_capacity=rows_per_chunk)
-        gen = DeviceBidGenerator(src_cfg, seed=self.seed)
-        source_sig = ("nexmark_bid", src_cfg.chunk_capacity,
-                      src_cfg.events_per_second, src_cfg.active_people,
-                      src_cfg.in_flight_auctions, src_cfg.start_time_us,
-                      m.col_map,
-                      tuple(sorted((m.source.options or {}).items())))
-        spec = FusedJobSpec(
-            kind="agg",
-            signature=agg_signature(agg.core, m.exprs, rows_per_chunk,
-                                    source_sig),
-            chunk_fn=declared_chunk_fn(gen.chunk_fn(), m.col_map),
-            exprs=tuple(m.exprs), core=agg.core,
-            rows_per_chunk=rows_per_chunk, seed=self.seed)
-        if self._shardfused is None or self._shardfused.mesh is not mesh:
-            self._shardfused = ShardedCoScheduler(mesh)
-        group = self._shardfused.add(
-            stmt.name, spec, shard_states=states, start=cursor.events,
-            batch_no=cursor.epochs)
-
-        mv = MaterializedViewDef(stmt.name, plan.schema, tuple(plan.pk),
-                                 table_id=mv_table_id, definition="")
-        mv.n_visible = sum(  # type: ignore[attr-defined]
-            1 for f in plan.schema if not f.name.startswith("_"))
-        mv.state_table_ids = (st.table_id,)  # type: ignore[attr-defined]
-        mv.query_ast = stmt.query  # type: ignore[attr-defined]
-        mv.table_id_range = (  # type: ignore[attr-defined]
-            id0, self.catalog._next_table_id)
-        self.catalog_writer.add_mv(mv)
-        job = StreamJob(stmt.name, mat, [q])
-        self.jobs[stmt.name] = job
-        job.start(self.loop)
-        self.feeds.append(_SourceFeed(q, lambda: None, reader=cursor,
-                                      state_table=split_st,
-                                      job=stmt.name))
-        self._shardfused_engines[stmt.name] = (agg, q, cursor, group)
-        self._shardfused_markers.add(stmt.name)
-        if self.data_dir is not None and not self._recovering:
-            self.store.log.log_ddl(  # type: ignore[attr-defined]
-                f"-- shardfused {stmt.name}")
-        self._pending_mutation = Mutation(MutationKind.ADD, stmt.name)
-        q.push(Barrier.new(self.epoch))
-        self._await(job.wait_barrier(self.epoch))
-        return []
-
-    def _push_shardfused_outs(self, outs: dict) -> None:
-        for name, chunks in outs.items():
-            q = self._shardfused_engines[name][1]
-            for ch in chunks:
-                q.push(ch)
-
-    def _shardfused_tick(self, epoch: int, checkpoint: bool,
-                         generate: bool) -> None:
-        """Per-tick driver: ONE dispatch per K×S group covers every
-        member MV's whole epoch across all chips; the group flush (one
-        packed [n, J, 3] fetch) feeds each job's Materialize queue;
-        checkpoint barriers write every (job, shard) delta through each
-        job's own state-table flush, then restack once per group.
-        Pipelined cadence exactly as ``_cosched_tick``; the sharded
-        grow-retry drains inside ``finish_flush`` before anything else
-        dispatches, and sharded epochs never donate, so the deferred
-        handle's pre-finish state stays valid for the gathers."""
-        k = self.chunks_per_tick
-        groups = list(self._shardfused.groups.values())
-        for group in groups:
-            if group.pending is not None:
-                self._push_shardfused_outs(group.finish_flush())
-        ran = generate and k > 0
-        if ran:
-            for group in groups:
-                group.run_epoch(k)
-                for j, name in enumerate(group.names):
-                    cursor = self._shardfused_engines[name][2]
-                    cursor.events = group.starts[j]
-                    cursor.epochs = group.batch_nos[j]
-        for group in groups:
-            group.begin_flush()
-        if self.pipeline_depth >= 2 and ran and not checkpoint:
-            self._pipeline_stats["deferred_flushes"] += len(groups)
-            return
-        for group in groups:
-            self._push_shardfused_outs(group.finish_flush())
-            if checkpoint:
-                group.checkpoint(
-                    {name: self._shardfused_engines[name][0]
-                     for name in group.names}, epoch)
-
-    def _drain_fused_pipeline(self) -> None:
-        """Resolve every deferred fused flush and feed its chunks to the
-        job queues (they ride the next barrier). The pipeline's drain
-        points — DDL, DROP, scoped recovery, checkpoint ticks — call
-        this so membership changes and durable cuts never race an
-        in-flight packed fetch. No-op when nothing is pending (always,
-        at pipeline_depth = 1)."""
-        for group in list(self._cosched.groups.values()):
-            if group.pending is not None:
-                self._push_cosched_outs(group.finish_flush())
-                self._pipeline_stats["drains"] += 1
-        for group in list(self._hetero.groups):
-            if group.pending is not None:
-                self._push_hetero_outs(group.finish_flush())
-                self._pipeline_stats["drains"] += 1
-        if self._shardfused is not None:
-            for group in list(self._shardfused.groups.values()):
-                if group.pending is not None:
-                    self._push_shardfused_outs(group.finish_flush())
-                    self._pipeline_stats["drains"] += 1
 
     # ------------------------------------------------------ remote MV jobs --
 
@@ -3317,7 +2650,7 @@ class Session:
         # drain pipelined epochs first: the rebuilt jobs will only see
         # barriers from the NEXT injection on, so nothing may stay in
         # flight across the rebuild (dead jobs are tolerated by collect)
-        self._drain_fused_pipeline()
+        self._fused.drain()
         self._drain_inflight()
         subtree = [name] + self._downstream_names(job)
         non_mv = [n for n in subtree if n not in self.catalog.mvs]
@@ -3511,7 +2844,7 @@ class Session:
                 stmt, kind="index", name=ix_name, if_exists=True))
         # a deferred fused flush must resolve BEFORE membership changes
         # restack the job axis (and before its chunks would be lost)
-        self._drain_fused_pipeline()
+        self._fused.drain()
         self._drain_inflight()
         # free the object's durable state (tombstoned in the manifest so
         # recovery and compaction skip it)
@@ -3523,38 +2856,7 @@ class Session:
             # the job's source feeds die with it: free their split-state
             # tables (collect BEFORE teardown filters them away)
             dead_feeds = [f for f in self.feeds if f.job == stmt.name]
-            group = self._cosched.jobs.get(stmt.name)
-            self._cosched.remove(stmt.name)
-            if group is not None and group.n_jobs == 0 and group.epochs_run:
-                # the job emptied its group: its epochs leave the live
-                # registry, so retire them for the per_epoch ratio
-                qn = "build_group_epoch.<locals>.coscheduled_epoch"
-                self._dispatch_epochs_retired[qn] = \
-                    self._dispatch_epochs_retired.get(qn, 0) \
-                    + group.epochs_run
-            self._cosched_engines.pop(stmt.name, None)
-            self._cosched_markers.discard(stmt.name)
-            if stmt.name in self._hetero.jobs:
-                # dissolve-then-recompile: the member's groups retire
-                # their epochs into the compiler ledger; fold it so the
-                # per_epoch invariant ratio survives the DROP
-                self._hetero.remove(stmt.name)
-                self._fold_hetero_retired()
-            self._hetero_engines.pop(stmt.name, None)
-            self._hetero_markers.discard(stmt.name)
-            dead_sf = self._shardfused_engines.pop(stmt.name, None)
-            if dead_sf is not None and self._shardfused is not None:
-                _states, sf_group = self._shardfused.remove(stmt.name)
-                if sf_group is not None and sf_group.n_jobs == 0 \
-                        and sf_group.epochs_run:
-                    # the job emptied its K×S group: retire its epochs
-                    # for the per_epoch invariant ratio, like coschedule
-                    qn = ("build_sharded_group_epoch.<locals>"
-                          ".sharded_coscheduled_epoch")
-                    self._dispatch_epochs_retired[qn] = \
-                        self._dispatch_epochs_retired.get(qn, 0) \
-                        + sf_group.epochs_run
-            self._shardfused_markers.discard(stmt.name)
+            self._fused.drop(stmt.name)
             if stmt.name in self.jobs:
                 job = self.jobs.pop(stmt.name)
                 # full shared teardown: also clears _dead_jobs / worker
@@ -3843,23 +3145,12 @@ class Session:
                             fed += 1
                             fed_rows += chunk.capacity
                 feed_span.set(chunks=fed, capacity_rows=fed_rows)
-        if self._cosched.jobs:
-            # co-scheduled groups: one fused dispatch per group covers
-            # every member MV's epoch; flush chunks land on the job
-            # queues BEFORE the barrier below
-            self._cosched_tick(epoch, checkpoint,
-                               generate and not self.paused)
-        if self._hetero.jobs:
-            # tick-compiled groups: the compiler's minimal dispatch
-            # schedule (shape-class supergroups + mega-epochs) covers
-            # every registered MV's epoch in a handful of dispatches
-            self._hetero_tick(epoch, checkpoint,
-                              generate and not self.paused)
-        if self._shardfused_engines:
-            # mesh-sharded fused MVs: one dispatch per MV per epoch
-            # across ALL chips (ops/fused_sharded.py)
-            self._shardfused_tick(epoch, checkpoint,
-                                  generate and not self.paused)
+        if self._fused.engines:
+            # fused jobs: one dispatch per group covers every member
+            # MV's epoch; flush chunks land on the job queues BEFORE the
+            # barrier below
+            self._fused.tick(epoch, checkpoint,
+                             generate and not self.paused)
         import time as _time
         # barrier observatory: open this epoch's waterfall record and
         # time the inject stage (host-side clock only — zero added
@@ -4658,25 +3949,9 @@ class Session:
                 for se in self._slow_epochs
             ],
             "storage": self._storage_metrics(),
-            # epoch co-scheduler: group membership + epochs run
-            # (stream/coschedule.py)
-            "coschedule": self._cosched.stats(),
-            # heterogeneous tick compiler: dispatch schedule shape +
-            # per-job cost attribution (stream/tick_compiler.py)
-            "hetero": {**self._hetero.stats(),
-                       "attribution": self._hetero.attribution()},
-            # mesh-sharded fused MVs: shard count + group size + epochs
-            # + grow-retry events per job (ops/fused_sharded.py,
-            # parallel/fused.ShardedCoGroup — signature-equal MVs share
-            # one K×S group, so their stats coincide by design)
-            "shardfused": {
-                name: {"shards": g.n, "epochs_run": g.epochs_run,
-                       "recv_width": g.recv_width,
-                       "route_grows": g.route_grows,
-                       "group_jobs": g.n_jobs}
-                for name, (_, _, _, g) in
-                self._shardfused_engines.items()
-            },
+            # fused jobs, one entry per scheduler: "coschedule",
+            # "hetero" (with "attribution"), "shardfused"
+            **self._fused.stats(),
             # serving plane (frontend/serving.py): plan-cache hit/miss,
             # two-phase task counts, partials merged, read latency p50/p99
             "serving": self._serving.metrics(),
@@ -4802,24 +4077,7 @@ class Session:
         # (fused engines report dispatches ÷ epochs_run)
         dispatch = {"counts": GLOBAL_PROFILER.counts(), "per_epoch": {}}
         counts = dispatch["counts"]
-        epochs_by_name: dict = dict(self._dispatch_epochs_retired)
-        for g in self._cosched.groups.values():
-            if g.epochs_run:
-                epochs_by_name[
-                    "build_group_epoch.<locals>.coscheduled_epoch"] = \
-                    epochs_by_name.get(
-                        "build_group_epoch.<locals>.coscheduled_epoch", 0) \
-                    + g.epochs_run
-        if self._shardfused is not None:
-            qn = "build_sharded_group_epoch.<locals>.sharded_coscheduled_epoch"
-            for g in self._shardfused.groups.values():
-                if g.epochs_run:
-                    epochs_by_name[qn] = epochs_by_name.get(qn, 0) \
-                        + g.epochs_run
-        for g in self._hetero.groups:
-            if g.epochs_run:
-                epochs_by_name[g.epoch_qualname] = \
-                    epochs_by_name.get(g.epoch_qualname, 0) + g.epochs_run
+        epochs_by_name = self._fused.epochs_by_qualname()
         for qn, epochs in epochs_by_name.items():
             if qn in counts and epochs:
                 dispatch["per_epoch"][qn] = round(counts[qn] / epochs, 4)
@@ -4828,17 +4086,10 @@ class Session:
 
     def _pipeline_metrics(self) -> dict:
         from ..common.profiling import GLOBAL_PROFILER
-        pending = sum(1 for g in self._cosched.groups.values()
-                      if g.pending is not None)
-        pending += sum(1 for g in self._hetero.groups
-                       if g.pending is not None)
-        if self._shardfused is not None:
-            pending += sum(1 for g in self._shardfused.groups.values()
-                           if g.pending is not None)
         return {
             "depth": self.pipeline_depth,
-            "pending_flushes": pending,
-            **self._pipeline_stats,
+            "pending_flushes": self._fused.pending_flushes(),
+            **self._fused.pipeline_stats,
             **GLOBAL_PROFILER.pipeline_stats(),
         }
 

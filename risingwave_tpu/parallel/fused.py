@@ -57,6 +57,7 @@ from ..ops.fused_sharded import (
 )
 from ..ops.grouped_agg import load_rows_into_state
 from ..ops.hash_table import ht_lookup_or_insert
+from ..stream.coschedule import JobAxisGroup, restack_span
 from .sharded_agg import SHARD_AXIS
 
 _NEG = np.iinfo(np.int64).min
@@ -778,7 +779,12 @@ def reshard_q3_payloads(core, payloads: Sequence, new_n: int) -> list:
 # ---------------------------------------------------------------------------
 
 
-class ShardedCoGroup(_GrowRetryMixin):
+#: dispatch_count / profiler identity of the K×S group epoch
+SHARDED_GROUP_EPOCH_FN = \
+    "build_sharded_group_epoch.<locals>.sharded_coscheduled_epoch"
+
+
+class ShardedCoGroup(_GrowRetryMixin, JobAxisGroup):
     """One signature's job set sharded over a mesh: K signature-equal
     source+agg MVs × S shards tick in ONE dispatch per epoch
     (ops/fused_sharded.build_sharded_group_epoch — the sixth fusion
@@ -789,6 +795,8 @@ class ShardedCoGroup(_GrowRetryMixin):
     group-wide (one overflowing job replays the whole group's epoch from
     the untouched previous state — deterministic, so the retry is
     exact for every member)."""
+
+    epoch_qualname = SHARDED_GROUP_EPOCH_FN
 
     def __init__(self, mesh, spec, recv_width: int = 2):
         if spec.kind != "agg":
@@ -828,10 +836,6 @@ class ShardedCoGroup(_GrowRetryMixin):
             self.mesh, width)
 
     # -- membership -----------------------------------------------------------
-
-    @property
-    def n_jobs(self) -> int:
-        return len(self.names)
 
     def add(self, name: str, shard_states: Optional[Sequence] = None,
             start: int = 0, seed: int = 0, batch_no: int = 0) -> None:
@@ -890,12 +894,6 @@ class ShardedCoGroup(_GrowRetryMixin):
                 for s in range(self.n)]
 
     # -- ticking --------------------------------------------------------------
-
-    def _keys(self):
-        if self._base_keys is None:
-            self._base_keys = jnp.stack(
-                [jax.random.PRNGKey(s) for s in self.seeds])
-        return self._base_keys
 
     def _settle(self) -> None:
         while self._pending is not None:
@@ -992,14 +990,6 @@ class ShardedCoGroup(_GrowRetryMixin):
             out[name] = chunks
         return out
 
-    def flush(self) -> dict:
-        """Synchronous barrier flush (begin + finish in one call) —
-        exactly ShardedFusedAgg.flush per member, the pre-pipeline
-        cadence and still the default."""
-        if self.pending is None:
-            self.begin_flush()
-        return self.finish_flush()
-
     # -- durability -----------------------------------------------------------
 
     def checkpoint(self, engines: dict, epoch: int) -> None:
@@ -1013,14 +1003,16 @@ class ShardedCoGroup(_GrowRetryMixin):
             engine = engines[name]
             shard_states = []
             for s in range(self.n):
-                engine.state = jax.tree_util.tree_map(
-                    lambda x, s=s, j=self.names.index(name): x[s, j],
-                    self.stacked)
+                with restack_span(epoch):
+                    engine.state = jax.tree_util.tree_map(
+                        lambda x, s=s, j=self.names.index(name): x[s, j],
+                        self.stacked)
                 engine._checkpoint_to_state_table(epoch)
                 shard_states.append(engine.state)
             per_job.append(stack_states(shard_states))
-        self.stacked = self._put(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs, axis=1), *per_job))
+        with restack_span(epoch):
+            self.stacked = self._put(jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs, axis=1), *per_job))
 
 
 class ShardedCoScheduler:

@@ -92,12 +92,95 @@ def join_signature(core, exprs, rows_per_chunk: int,
             core.band_us)
 
 
-class CoGroup:
+#: dispatch_count / profiler identity of the co-scheduled group epoch
+GROUP_EPOCH_FN = "build_group_epoch.<locals>.coscheduled_epoch"
+
+
+def restack_span(epoch: int):
+    """A checkpoint moving state out of / back into a group's job axis."""
+    return span("cosched.restack", epoch=epoch, stage="state_delta",
+                cat=CAT_EPOCH, tid="conductor")
+
+
+class JobAxisGroup:
+    """What every fused scheduler's group shares (CoGroup here, the tick
+    compiler's PaddedHeteroGroup / MegaGroup, parallel/fused.py's
+    ShardedCoGroup): jobs along a leading axis, per-job identity as data.
+    stream/fused_jobs.py drives any of them through ``names``,
+    ``starts``, ``batch_nos``, ``pending``, ``epochs_run``,
+    ``epoch_qualname``, ``run_epoch(k)``, ``begin_flush()``,
+    ``finish_flush()`` and ``checkpoint(engines, epoch)``."""
+
+    epoch_qualname: str          # profiler identity of the epoch dispatch
+    names: list
+    seeds: list
+    pending: Optional[PendingFlush]
+    _base_keys = None
+
+    @property
+    def n_jobs(self) -> int:
+        return len(self.names)
+
+    def _keys(self):
+        # stacked per-job base keys, rebuilt only on membership change;
+        # the per-epoch fold happens INSIDE the group dispatch
+        if self._base_keys is None:
+            self._base_keys = jnp.stack(
+                [jax.random.PRNGKey(s) for s in self.seeds])
+        return self._base_keys
+
+    def flush(self) -> dict:
+        """Synchronous barrier flush (begin + finish in one call): one
+        vmapped probe, ONE packed fetch, per-job gathers, one vmapped
+        finish — the pre-pipeline cadence, still the default."""
+        if self.pending is None:
+            self.begin_flush()
+        return self.finish_flush()
+
+    def finish_flush(self) -> dict:
+        """Resolve the in-flight flush: one packed [J, 3] fetch (already
+        streaming — usually landed) for all J jobs, then the group's
+        per-job gather windows against the pending pre-finish state
+        (``_decode_flush``). Returns {job: [StreamChunk, ...]}. The wait
+        on the device and the decode are sibling spans of the caller's
+        epoch."""
+        p = self.pending
+        if p is None:
+            p = self.begin_flush()
+        self.pending = None
+        with span("cosched.epoch_wait", epoch=None, stage="epoch_wait",
+                  wait="device", cat=CAT_EPOCH, tid="conductor"):
+            packed_h = np.asarray(p.fetch.result())
+        with span("cosched.flush_decode", epoch=None, stage="flush_decode",
+                  cat=CAT_EPOCH, tid="conductor") as decode:
+            out = self._decode_flush(p, packed_h)
+            decode.set(dirty_groups=int(packed_h[:, 0].sum()),
+                       chunks=sum(len(c) for c in out.values()))
+        return out
+
+    def checkpoint(self, engines: dict, epoch: int) -> None:
+        """Write every job's delta through its OWN HashAggExecutor
+        persistence engine (``engines``: job → executor), then restack
+        the job axis once instead of J in-place scatters."""
+        ckpt_states = []
+        for name in self.names:
+            agg = engines[name]
+            with restack_span(epoch):
+                agg.state = self.state_of(name)
+            agg._checkpoint_to_state_table(epoch)
+            ckpt_states.append(agg.state)
+        with restack_span(epoch):
+            self.set_states(ckpt_states)
+
+
+class CoGroup(JobAxisGroup):
     """One signature's job set: stacked state + compiled group steps.
 
     The authoritative per-job state lives in ``self.stacked``;
     ``state_of``/``set_state`` give solo-shaped views for checkpointing
     and bit-exactness tests."""
+
+    epoch_qualname = GROUP_EPOCH_FN
 
     def __init__(self, spec: FusedJobSpec, donate: bool = True):
         self.kind = spec.kind
@@ -121,10 +204,6 @@ class CoGroup:
         self.pending: Optional[PendingFlush] = None
 
     # -- membership -----------------------------------------------------------
-
-    @property
-    def n_jobs(self) -> int:
-        return len(self.names)
 
     def add(self, name: str, state, start: int = 0, seed: int = 0,
             batch_no: int = 0) -> None:
@@ -166,14 +245,6 @@ class CoGroup:
 
     # -- ticking --------------------------------------------------------------
 
-    def _keys(self):
-        # stacked per-job base keys, rebuilt only on membership change;
-        # the per-epoch fold happens INSIDE the group dispatch
-        if self._base_keys is None:
-            self._base_keys = jnp.stack(
-                [jax.random.PRNGKey(s) for s in self.seeds])
-        return self._base_keys
-
     def run_epoch(self, k: int):
         """ONE dispatch: every member job advances k chunks. For join
         groups the epoch's flush outputs are held for ``flush()``."""
@@ -211,26 +282,6 @@ class CoGroup:
         self.stacked = self._finish(self.stacked)
         return self.pending
 
-    def finish_flush(self) -> dict:
-        """Resolve the in-flight flush: one packed fetch (already
-        streaming — usually landed) for all J jobs, then per-job gather
-        windows against the pending pre-finish state. Returns
-        {job: [StreamChunk, ...]}. The wait on the device and the
-        decode are sibling spans of the caller's epoch."""
-        p = self.pending
-        if p is None:
-            p = self.begin_flush()
-        self.pending = None
-        with span("cosched.epoch_wait", epoch=None, stage="epoch_wait",
-                  wait="device", cat=CAT_EPOCH, tid="conductor"):
-            packed_h = np.asarray(p.fetch.result())
-        with span("cosched.flush_decode", epoch=None, stage="flush_decode",
-                  cat=CAT_EPOCH, tid="conductor") as decode:
-            out = self._decode_flush(p, packed_h)
-            decode.set(dirty_groups=int(packed_h[:, 0].sum()),
-                       chunks=sum(len(c) for c in out.values()))
-        return out
-
     def _decode_flush(self, p: "PendingFlush", packed_h) -> dict:
         out: dict = {}
         for j, name in enumerate(self.names):
@@ -248,14 +299,6 @@ class CoGroup:
                 lo += self.core.groups_per_chunk
             out[name] = chunks
         return out
-
-    def flush(self) -> dict:
-        """Synchronous barrier flush (begin + finish in one call): one
-        vmapped probe, ONE packed fetch, per-job gathers, one vmapped
-        finish — the pre-pipeline cadence, still the default."""
-        if self.pending is None:
-            self.begin_flush()
-        return self.finish_flush()
 
 
 class CoScheduler:

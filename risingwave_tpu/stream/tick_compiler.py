@@ -28,14 +28,14 @@ MVs triggers ONE compile, not 200 restacks). Dissolving a schedule
 writes every job's state/cursor back into its job record and retires
 each group's epochs-run counters (``take_retired``) so the live
 ``per_epoch`` dispatch-ratio invariant stays 1.0 across recompiles —
-the same ledger discipline Session applies to dropped co-scheduled
-groups.
+the same ledger discipline stream/fused_jobs.py applies to dropped
+co-scheduled groups.
 
-Both group kinds expose the CoGroup tick API (``run_epoch`` /
-``begin_flush`` / ``finish_flush`` / ``state_of`` / ``set_states``) so
-frontend/session.py drives them with the same pipeline-depth deferral
-and checkpoint write-back as equal groups, and each job keeps its own
-HashAggExecutor-backed flush engine — checkpoint/recovery is unchanged
+Both group kinds are ``JobAxisGroup``s (stream/coschedule.py), so the
+one fused tick driver (stream/fused_jobs.py) gives them the same
+pipeline-depth deferral and checkpoint write-back as equal groups, and
+each job keeps its own HashAggExecutor-backed flush engine —
+checkpoint/recovery is unchanged
 (``_checkpoint_to_state_table`` is capacity-agnostic, so padded states
 persist through the job's own engine).
 """
@@ -45,7 +45,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,7 +58,7 @@ from ..ops.fused_hetero import (
 from ..ops.fused_multi import (
     gather_job_flush_chunk, index_state, multi_agg_finish, stack_states,
 )
-from .coschedule import FusedJobSpec, _expr_sig
+from .coschedule import FusedJobSpec, JobAxisGroup, _expr_sig
 
 #: dispatch_count / profiler identities of the two compiled surfaces
 PADDED_EPOCH_FN = "build_padded_group_epoch.<locals>.padded_epoch"
@@ -145,11 +144,10 @@ class HeteroJob:
 # ---------------------------------------------------------------------------
 
 
-class PaddedHeteroGroup:
-    """Tier 1: one shape class, one vmapped dispatch. Mirrors
-    stream/coschedule.CoGroup's tick API; per-job literals ride as
-    stacked parameter data and every member's state lives padded at
-    the class-max capacity."""
+class PaddedHeteroGroup(JobAxisGroup):
+    """Tier 1: one shape class, one vmapped dispatch; per-job literals
+    ride as stacked parameter data and every member's state lives
+    padded at the class-max capacity."""
 
     kind = "padded"
     epoch_qualname = PADDED_EPOCH_FN
@@ -190,23 +188,12 @@ class PaddedHeteroGroup:
         self.epochs_run = 0
         self.flush_weights = dict.fromkeys(self.names, 0)
         self.pending: Optional[PendingFlush] = None
-        self._base_keys = None
         self._epoch = build_padded_group_epoch(
             base.spec.chunk_fn, base.skel_exprs, self.core,
             self.rows_per_chunk, donate)
         self._probe = padded_agg_probe(self.core)
         self._finish = multi_agg_finish(self.core)
         self._gather = gather_job_flush_chunk(self.core)
-
-    @property
-    def n_jobs(self) -> int:
-        return len(self.names)
-
-    def _keys(self):
-        if self._base_keys is None:
-            self._base_keys = jnp.stack(
-                [jax.random.PRNGKey(s) for s in self.seeds])
-        return self._base_keys
 
     def state_of(self, name: str):
         return index_state(self.stacked, self.names.index(name))
@@ -234,12 +221,7 @@ class PaddedHeteroGroup:
         self.stacked = self._finish(self.stacked)
         return self.pending
 
-    def finish_flush(self) -> dict:
-        p = self.pending
-        if p is None:
-            p = self.begin_flush()
-        self.pending = None
-        packed_h = np.asarray(p.fetch.result())
+    def _decode_flush(self, p: PendingFlush, packed_h) -> dict:
         out: dict = {}
         for j, name in enumerate(self.names):
             n_dirty, overflow = int(packed_h[j, 0]), int(packed_h[j, 1])
@@ -258,13 +240,8 @@ class PaddedHeteroGroup:
             out[name] = chunks
         return out
 
-    def flush(self) -> dict:
-        if self.pending is None:
-            self.begin_flush()
-        return self.finish_flush()
 
-
-class MegaGroup:
+class MegaGroup(JobAxisGroup):
     """Tier 2: heterogeneous epoch bodies concatenated in ONE compiled
     dispatch. States stay a per-job tuple (no shape relation between
     members); the barrier is one probe dispatch / one packed [J, 3]
@@ -285,21 +262,10 @@ class MegaGroup:
         self.epochs_run = 0
         self.flush_weights = dict.fromkeys(self.names, 0)
         self.pending: Optional[PendingFlush] = None
-        self._base_keys = None
         self._epoch = build_mega_epoch([j.spec for j in jobs], donate)
         self._probe = build_mega_agg_probe(self.cores)
         self._finish = build_mega_agg_finish(self.cores)
         self._gathers = mega_agg_gathers(self.cores)
-
-    @property
-    def n_jobs(self) -> int:
-        return len(self.names)
-
-    def _keys(self):
-        if self._base_keys is None:
-            self._base_keys = jnp.stack(
-                [jax.random.PRNGKey(s) for s in self.seeds])
-        return self._base_keys
 
     def state_of(self, name: str):
         return self.states[self.names.index(name)]
@@ -327,12 +293,7 @@ class MegaGroup:
         self.states = self._finish(self.states)
         return self.pending
 
-    def finish_flush(self) -> dict:
-        p = self.pending
-        if p is None:
-            p = self.begin_flush()
-        self.pending = None
-        packed_h = np.asarray(p.fetch.result())
+    def _decode_flush(self, p: PendingFlush, packed_h) -> dict:
         out: dict = {}
         for j, name in enumerate(self.names):
             n_dirty, overflow = int(packed_h[j, 0]), int(packed_h[j, 1])
@@ -350,11 +311,6 @@ class MegaGroup:
                 lo += self.cores[j].groups_per_chunk
             out[name] = chunks
         return out
-
-    def flush(self) -> dict:
-        if self.pending is None:
-            self.begin_flush()
-        return self.finish_flush()
 
 
 # ---------------------------------------------------------------------------
@@ -409,7 +365,7 @@ class TickCompiler:
     def _dissolve(self) -> None:
         """Tear the compiled schedule down to job records: write every
         group's states/cursors back and retire its epochs-run under its
-        dispatch qualname — the ledger Session drains via
+        dispatch qualname — the ledger stream/fused_jobs.py drains via
         ``take_retired`` to keep the per-epoch ratio exactly 1.0 across
         recompiles (ISSUE 19 satellite: DROP + re-CREATE)."""
         self.dirty = True
@@ -432,7 +388,7 @@ class TickCompiler:
 
     def take_retired(self) -> dict:
         """Drain retired epoch counts (qualname → epochs): the caller
-        folds them into its ``_dispatch_epochs_retired`` ledger."""
+        (stream/fused_jobs.py) folds them into its ``retired`` ledger."""
         out, self._retired = self._retired, {}
         return out
 

@@ -342,6 +342,48 @@ def test_session_coschedule_recovery(tmp_path):
         s2.close()
 
 
+@pytest.mark.parametrize("kind,marker,flags", [
+    ("coschedule", "-- coschedule m0", dict(coschedule=True)),
+    ("hetero", "-- hetero m0", dict(tick_compiler=True)),
+    ("shardfused", "-- shardfused m0", dict(coschedule=True, mesh_n=4)),
+])
+def test_session_marker_line_routes_back_to_its_kind(kind, marker, flags,
+                                                     tmp_path):
+    """The DDL log's marker lines are a durable format: a ``data_dir``
+    written by an earlier build must reopen. The line is spelled here,
+    not derived from the registry's table."""
+    from risingwave_tpu.frontend import Session
+    from risingwave_tpu.frontend.build import BuildConfig
+    from risingwave_tpu.parallel.sharded_agg import make_mesh
+
+    def open_(**more):
+        f = {**flags, **more}
+        n = f.pop("mesh_n", 0)
+        return Session(config=BuildConfig(
+            mesh=make_mesh(n) if n else None, agg_table_capacity=1 << 12,
+            **f), source_chunk_capacity=CAP, checkpoint_frequency=2,
+            data_dir=str(tmp_path))
+
+    s = open_()
+    s.run_sql(SRC_SQL)
+    s.run_sql(MV_SQL.format(n="m0"))
+    for _ in range(3):
+        s.tick()
+    ddl = s.store.log.ddl()
+    assert ddl.count(marker) == 1 and ddl.index(marker) == len(ddl) - 2
+    committed = dict(s.run_sql("SELECT auction, c FROM m0"))
+    s.close()
+    # reopened with every mesh-less kind's flag on: only the marker says
+    # which of them laid the tables out
+    s2 = open_(**({} if kind == "shardfused"
+                  else dict(coschedule=True, tick_compiler=True)))
+    try:
+        assert s2._fused.engines["m0"].kind.name == kind
+        assert dict(s2.run_sql("SELECT auction, c FROM m0")) == committed
+    finally:
+        s2.close()
+
+
 def test_session_solo_mv_reopened_with_flag_stays_solo(tmp_path):
     """The reverse recovery direction: an MV created WITHOUT the flag
     must replay down the executor path even when the session reopens

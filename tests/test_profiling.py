@@ -374,7 +374,7 @@ def test_session_dispatch_per_epoch_invariant_live():
         # DROP + re-CREATE must retire the dead group's epochs or the
         # ratio would read 2.0 and falsely flag a dispatch regression
         s.run_sql("DROP MATERIALIZED VIEW m0")
-        assert s._dispatch_epochs_retired[qn] == 4
+        assert s._fused.retired[qn] == 4
         s.run_sql("CREATE MATERIALIZED VIEW m0 AS SELECT auction, "
                   "count(*) AS c FROM bid GROUP BY auction")
         for _ in range(4):
@@ -420,7 +420,7 @@ def test_session_dispatch_per_epoch_invariant_tick_compiled():
         # retirement ledger. Re-CREATE before the next tick so the
         # surviving singleton never runs a mega interlude.
         s.run_sql("DROP MATERIALIZED VIEW h1")
-        assert s._dispatch_epochs_retired[PADDED_EPOCH_FN] == 4
+        assert s._fused.retired[PADDED_EPOCH_FN] == 4
         s.run_sql(mv.format(n="h1", lit=20))
         for _ in range(4):
             s.tick()

@@ -744,15 +744,30 @@ class TestSyncFetchDiscipline:
                 return jax.device_get(packed)
 
             class Session:
-                def _cosched_tick(self, epoch):
+                def _feed(self, epoch):
                     return _decode_stats(self._probe())
 
                 def _tick_impl(self, generate):
-                    return self._cosched_tick(1)
+                    self._fused.tick(1, False, generate)
+                    return self._feed(1)
+            """
+        # the registry's driver is a root of its own: the callgraph
+        # cannot type ``self._fused``
+        files["stream/fused_jobs.py"] = """
+            import jax
+
+            class FusedJobs:
+                def _push(self, outs):
+                    return jax.device_get(outs)
+
+                def tick(self, epoch, checkpoint, generate):
+                    return self._push(self._groups())
             """
         found = lint_fixture(tmp_path, files, ["sync-fetch-discipline"])
-        assert [f.path for f in found] == ["frontend/session.py"]
+        assert [f.path for f in found] == ["frontend/session.py",
+                                           "stream/fused_jobs.py"]
         assert "_decode_stats" in found[0].message
+        assert "FusedJobs._push" in found[1].message
 
     def test_block_until_ready_and_device_attr_asarray_flagged(
             self, tmp_path):

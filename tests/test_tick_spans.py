@@ -52,23 +52,32 @@ CONDUCTOR = {
     "materialize.fetch_wait": "Materialize.barrier",
     "Materialize.seal": "Materialize.barrier",
 }
+#: what the one fused driver (stream/fused_jobs.py) emits for any kind
+FUSED_DRIVER = {**CONDUCTOR,
+                "cosched.dispatch": "session.tick",
+                "cosched.flush_begin": "session.tick"}
+#: the wait / decode split of ``JobAxisGroup.finish_flush``
+FUSED_FLUSH = {"cosched.epoch_wait": "session.tick",
+               "cosched.flush_decode": "session.tick"}
 EVERY_BARRIER = {
-    "fused": {**CONDUCTOR,
-              "cosched.dispatch": "session.tick",
-              "cosched.flush_begin": "session.tick",
-              "cosched.epoch_wait": "session.tick",
-              "cosched.flush_decode": "session.tick"},
+    "fused": {**FUSED_DRIVER, **FUSED_FLUSH},
+    "hetero": {**FUSED_DRIVER, **FUSED_FLUSH},
+    # ShardedCoGroup.finish_flush (grow-retry inside) has no split yet
+    "shardfused": FUSED_DRIVER,
     "exec": {**CONDUCTOR,
              **{f"{ident}.{kind}": "barrier.collect"
                 for ident in ("RowIdGen", "Project", "HashAgg")
                 for kind in ("chunks", "barrier")},
              "agg.flush_wait": "HashAgg.barrier"},
 }
+FUSED_CHECKPOINT = {"agg.state_delta": "session.tick",
+                    "cosched.restack": "session.tick",
+                    "checkpoint.commit": "session.tick",
+                    "DurableStateStore.commit": "checkpoint.commit"}
 CHECKPOINT_ONLY = {
-    "fused": {"agg.state_delta": "session.tick",
-              "cosched.restack": "session.tick",
-              "checkpoint.commit": "session.tick",
-              "DurableStateStore.commit": "checkpoint.commit"},
+    "fused": FUSED_CHECKPOINT,
+    "hetero": FUSED_CHECKPOINT,
+    "shardfused": FUSED_CHECKPOINT,
     "exec": {"agg.state_delta": "HashAgg.barrier",
              "checkpoint.commit": "session.tick",
              "DurableStateStore.commit": "checkpoint.commit"},
@@ -79,7 +88,11 @@ SOMETIMES = {"xla.compile", "cosched.resolve_deferred"}
 
 def open_session(path: str, data_dir=None, capacity: int = 1 << 16,
                  **kw) -> Session:
-    s = Session(config=BuildConfig(coschedule=(path == "fused"),
+    from risingwave_tpu.parallel.sharded_agg import make_mesh
+    s = Session(config=BuildConfig(coschedule=path in ("fused", "shardfused"),
+                                   tick_compiler=(path == "hetero"),
+                                   mesh=(make_mesh(4) if path == "shardfused"
+                                         else None),
                                    agg_table_capacity=capacity),
                 source_chunk_capacity=CAP, chunks_per_tick=CHUNKS,
                 checkpoint_frequency=3, data_dir=data_dir, **kw)
@@ -111,10 +124,13 @@ def contract(spans: list) -> list:
 
 # -- (1, 2, 8) names and parents, every epoch, both paths ---------------------
 
-@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("path", PATHS + ("hetero", "shardfused"))
 def test_every_epoch_has_exactly_the_contract_spans(path, tmp_path):
     s, by_epoch = run(path, tmp_path)
     try:
+        if path != "exec":
+            assert s._fused.engines["q5"].kind.name == {
+                "fused": "coschedule"}.get(path, path)
         history = {r["epoch"]: r for r in s._barrier_ledger.history()}
         for epoch, spans in by_epoch.items():
             want = dict(EVERY_BARRIER[path])
@@ -213,7 +229,7 @@ def test_ledger_record_spans_the_tick_and_history_shows_it(path, tmp_path):
 
 def agg_engine(s: Session, path: str):
     if path == "fused":
-        return s._cosched_engines["q5"][0]
+        return s._fused.engines["q5"].agg
     from risingwave_tpu.stream.metrics import iter_executors
     (agg,) = [ex for ex in iter_executors(s.jobs["q5"].pipeline)
               if ex.identity == "HashAgg"]
@@ -542,7 +558,7 @@ def test_jitted_epoch_programs_keep_their_module_names(tmp_path):
     fused = open_session("fused", str(tmp_path / "fused"))
     execp = open_session("exec", str(tmp_path / "exec"))
     try:
-        group = next(iter(fused._cosched.groups.values()))
+        group = fused._fused.groups()[0]
         starts = jnp.asarray(group.starts, jnp.int64)
         nos = jnp.asarray(group.batch_nos, jnp.int64)
         packed, ranks = group._probe(group.stacked)
@@ -584,7 +600,7 @@ def test_epoch_programs_carry_named_scopes(path, scopes, tmp_path):
     s = open_session(path, str(tmp_path / path))
     try:
         if path == "fused":
-            group = next(iter(s._cosched.groups.values()))
+            group = s._fused.groups()[0]
             low = group._epoch.lower(
                 group.stacked, jnp.asarray(group.starts, jnp.int64),
                 group._keys(), jnp.asarray(group.batch_nos, jnp.int64), 2)
