@@ -20,6 +20,8 @@ two adjacent rows with the same key.
 
 from __future__ import annotations
 
+import dataclasses
+import itertools
 from typing import Any, Iterable, Optional, Sequence
 
 import jax
@@ -116,6 +118,158 @@ def stack_chunks(chunks: Sequence[StreamChunk]) -> ChunkBatch:
         lambda *xs: jnp.stack(xs), *chunks))
 
 
+@dataclasses.dataclass(frozen=True)
+class HostChunk:
+    """One chunk's columns still on the host: what a connector hands over
+    and ``stage_chunks`` takes to the device. ``arrays[i][:n]`` are the
+    physical values of column ``i``, ``masks[i][:n]`` its validity (``None``:
+    no column has a null), ``ops[:n]`` the row ops (``None``: all Insert)."""
+
+    schema: Schema
+    arrays: Sequence[np.ndarray]
+    n: int
+    capacity: int
+    masks: Optional[Sequence[np.ndarray]] = None
+    ops: Optional[np.ndarray] = None
+
+    def __post_init__(self):
+        if self.n > self.capacity:
+            raise ValueError(f"{self.n} rows > capacity {self.capacity}")
+
+    def dtypes(self) -> tuple:
+        return tuple(f.type.np_dtype for f in self.schema)
+
+    def has_flags(self) -> bool:
+        """A null or a non-Insert op: something ``n`` alone cannot give."""
+        n = self.n
+        return ((self.masks is not None
+                 and not all(m[:n].all() for m in self.masks))
+                or (self.ops is not None and bool(np.any(self.ops[:n]))))
+
+
+@dataclasses.dataclass
+class StagedCounts:
+    """What ``stage_chunks`` handed to the device, added up in the caller's
+    own object (``Session.tick`` passes one per ``source.feed`` span, whose
+    args these are): ``transfers`` — host column buffers, one per dtype
+    stack; ``bytes_staged`` — their bytes; ``dispatches`` — unpack programs
+    run. Each dispatch carries one more small argument that is counted with
+    it and not as a transfer: the int32 row count per chunk."""
+
+    transfers: int = 0
+    bytes_staged: int = 0
+    dispatches: int = 0
+
+
+def unpack_chunk(layout, ns, *stacks) -> tuple:
+    """Device side of ``stage_chunks``: slice the staged ``[K, rows, cap]``
+    stacks back into K chunks' arrays — per chunk ``(ops, vis, datas,
+    masks)``, ``masks`` empty where every mask is ``vis``."""
+    stack_of, flags, cap, k = layout
+    out = []
+    for c in range(k):
+        live = jnp.arange(cap, dtype=jnp.int32) < ns[c]
+        datas = tuple(stacks[s][c, r] for s, r in stack_of)
+        if flags:
+            # the int8 stack: one row per column mask, then the ops
+            rows = stacks[-1][c]
+            masks = tuple(rows[i] != 0 for i in range(len(stack_of)))
+            ops = rows[len(stack_of)]
+        else:
+            masks = ()
+            ops = jnp.zeros(cap, jnp.int8)  # all Insert (append-only source)
+        out.append((ops, live, datas, masks))
+    return tuple(out)
+
+
+_unpack = jax.jit(unpack_chunk, static_argnums=(0,))
+
+
+def _stage_run(run: Sequence[HostChunk],
+               counts: Optional[StagedCounts]) -> list:
+    """``stage_chunks`` for chunks of ONE dtype layout and capacity."""
+    dtypes, cap, k = run[0].dtypes(), run[0].capacity, len(run)
+    order = list(dict.fromkeys(dtypes))
+    rows = [0] * len(order)
+    stack_of = []                   # column -> (its dtype's stack, row)
+    for d in dtypes:
+        s = order.index(d)
+        stack_of.append((s, rows[s]))
+        rows[s] += 1
+    bufs = [np.zeros((k, r, cap), dt) for dt, r in zip(order, rows)]
+    flags = any(h.has_flags() for h in run)
+    if flags:
+        bufs.append(np.zeros((k, len(dtypes) + 1, cap), np.int8))
+    for c, h in enumerate(run):
+        for i, (s, r) in enumerate(stack_of):
+            bufs[s][c, r, :h.n] = h.arrays[i][:h.n]
+            if flags:
+                bufs[-1][c, i, :h.n] = (True if h.masks is None
+                                        else h.masks[i][:h.n])
+        if flags and h.ops is not None:
+            bufs[-1][c, len(dtypes), :h.n] = h.ops[:h.n]
+    ns = np.array([h.n for h in run], np.int32)
+    if counts is not None:
+        counts.transfers += len(bufs)
+        counts.bytes_staged += sum(b.nbytes for b in bufs)
+        counts.dispatches += 1
+    # an output array costs the host about as much as a small transfer (55 us
+    # each on a v5e, PERF.md §6 PR 30): a chunk without nulls gets ONE array
+    # as its vis and as every column's mask, not a copy each
+    return [StreamChunk(ops, live, tuple(
+                Column(d, m) for d, m in zip(datas, masks or (live,) * len(datas))))
+            for ops, live, datas, masks
+            in _unpack((tuple(stack_of), flags, cap, k), ns, *bufs)]
+
+
+def stage_chunks(host: Sequence[HostChunk],
+                 counts: Optional[StagedCounts] = None) -> list:
+    """The one way host columns become device chunks.
+
+    Every run of chunks with one dtype layout and capacity (a feed's chunks
+    of a barrier) is stacked by dtype into one zero-padded ``[K, rows,
+    capacity]`` host buffer per distinct dtype and handed to the device in
+    ONE jitted call (``jit_unpack_chunk``: the buffers ride as its arguments
+    and the row counts as a runtime vector, so it compiles once per layout,
+    capacity and K) which slices the K chunks' columns back out. Nothing
+    that can be computed is transferred: without nulls every mask, like
+    ``vis``, is ``arange(capacity) < n``, and without a non-Insert op
+    ``ops`` is zeros, made on the device; only a run that has either stages
+    one more int8 stack with all masks and the ops. The buffers are
+    allocated per call and never written again, so the asynchronous
+    transfer reads what was staged. ``counts``, where given, is added to."""
+    out = []
+    for _, run in itertools.groupby(
+            host, key=lambda h: (h.dtypes(), h.capacity)):
+        out.extend(_stage_run(list(run), counts))
+    return out
+
+
+def host_rows(
+    schema: Schema,
+    rows: Sequence[Sequence[Any]],
+    ops: Optional[Sequence[int]] = None,
+    capacity: int = DEFAULT_CHUNK_CAPACITY,
+    physical: bool = False,
+) -> HostChunk:
+    """Python rows → host columns (``make_chunk`` before the device)."""
+    n = len(rows)
+    datas, masks = [], []
+    for ci, field in enumerate(schema):
+        t = field.type
+        data = np.full(n, t.null_sentinel(), t.np_dtype)
+        mask = np.zeros(n, bool)
+        for ri, row in enumerate(rows):
+            v = row[ci]
+            if v is not None:
+                data[ri] = v if physical else t.to_physical(v)
+                mask[ri] = True
+        datas.append(data)
+        masks.append(mask)
+    return HostChunk(schema, datas, n, capacity, masks,
+                     None if ops is None else np.asarray(list(ops), np.int8))
+
+
 def make_chunk(
     schema: Schema,
     rows: Sequence[Sequence[Any]],
@@ -127,27 +281,7 @@ def make_chunk(
 
     ``physical=True`` takes raw physical values (state-table storage form)
     and skips logical encoding — the recovery-reload fast path."""
-    n = len(rows)
-    if n > capacity:
-        raise ValueError(f"{n} rows > capacity {capacity}")
-    if ops is None:
-        ops = [OP_INSERT] * n
-    ops_arr = np.zeros(capacity, np.int8)
-    ops_arr[:n] = np.asarray(list(ops), np.int8)
-    vis = np.zeros(capacity, bool)
-    vis[:n] = True
-    cols = []
-    for ci, field in enumerate(schema):
-        t = field.type
-        data = np.full(capacity, t.null_sentinel(), t.np_dtype)
-        mask = np.zeros(capacity, bool)
-        for ri, row in enumerate(rows):
-            v = row[ci]
-            if v is not None:
-                data[ri] = v if physical else t.to_physical(v)
-                mask[ri] = True
-        cols.append(Column(jnp.asarray(data), jnp.asarray(mask)))
-    return StreamChunk(jnp.asarray(ops_arr), jnp.asarray(vis), tuple(cols))
+    return stage_chunks([host_rows(schema, rows, ops, capacity, physical)])[0]
 
 
 def empty_chunk(schema: Schema, capacity: int = DEFAULT_CHUNK_CAPACITY) -> StreamChunk:

@@ -10,22 +10,46 @@ per source into a split-state table on checkpoint barriers and seeks
 readers on recovery — the reference's split-state checkpointing
 (src/stream/src/executor/source/state_table_handler.rs).
 
-TPU angle: readers emit fixed-capacity columnar StreamChunks (static
-shapes for XLA); ingest-side string interning happens here so device
-columns stay integer-typed.
+TPU angle: readers emit fixed-capacity columnar chunks (static shapes for
+XLA) as host columns, staged on the device a barrier at a time; ingest-side
+string interning happens here so device columns stay integer-typed.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
-from ..common.chunk import StreamChunk
+from ..common.chunk import HostChunk, StagedCounts, StreamChunk, stage_chunks
+
+
+def feed_chunks(draw: Callable[[], Optional[HostChunk]], k: int,
+                push: Callable[[StreamChunk], None],
+                counts: Optional[StagedCounts] = None) -> List[StreamChunk]:
+    """One feed's share of a barrier: draw up to ``k`` host chunks, stage
+    them together (one transfer per dtype, one dispatch) and push each.
+
+    A draw advances its reader's offsets, and the next checkpoint persists
+    them. So what was drawn is pushed even where a later draw raises (a
+    broker fetch out of retries, a file read error): offsets and queue
+    agree on every way out, and the tick that is retried goes on from
+    there."""
+    host: List[HostChunk] = []
+    try:
+        for _ in range(k):
+            chunk = draw()
+            if chunk is not None:
+                host.append(chunk)
+    finally:
+        chunks = stage_chunks(host, counts)
+        for chunk in chunks:
+            push(chunk)
+    return chunks
 
 
 class SplitReader:
     """One source instance: a set of splits read round-robin.
 
-    Offsets are *next-to-read* positions: after ``next_chunk`` returns rows
+    Offsets are *next-to-read* positions: after ``next_host_chunk`` returns rows
     ``[o, o+n)`` of split s, ``offsets[s] == o+n``. ``seek`` must make the
     subsequent chunks identical to a fresh reader fast-forwarded to the
     same offsets — that determinism is what makes source replay after
@@ -42,10 +66,17 @@ class SplitReader:
     def seek(self, offsets: Dict[str, int]) -> None:
         raise NotImplementedError
 
-    def next_chunk(self) -> Optional[StreamChunk]:
-        """Next chunk, or None when (currently) exhausted. Bounded sources
-        return None forever once drained; unbounded ones never return None."""
+    def next_host_chunk(self) -> Optional[HostChunk]:
+        """Next chunk's host columns, or None when (currently) exhausted.
+        Bounded sources return None forever once drained; unbounded ones
+        never return None. Whoever drives the reader stages a barrier's
+        host chunks together (common/chunk.stage_chunks)."""
         raise NotImplementedError
+
+    def next_chunk(self) -> Optional[StreamChunk]:
+        """``next_host_chunk`` staged on the device, one chunk at a time."""
+        host = self.next_host_chunk()
+        return None if host is None else stage_chunks([host])[0]
 
     def rows_emitted(self) -> int:
         """Rows emitted through the current offsets — an upper bound is

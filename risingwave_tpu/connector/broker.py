@@ -41,7 +41,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from ..common.chunk import StreamChunk, make_chunk
+from ..common.chunk import HostChunk, host_rows
 from ..common.types import Schema
 from .base import SplitReader
 from .parsers import parse_json_line
@@ -494,7 +494,7 @@ class BrokerSourceReader(SplitReader):
             None if v is None else f.type.to_physical(v)
             for f, v in zip(self.schema, vals))
 
-    def next_chunk(self) -> Optional[StreamChunk]:
+    def next_host_chunk(self) -> Optional[HostChunk]:
         """Round-robin over partitions; one chunk per non-empty fetch."""
         for _ in range(self._n_parts):
             p = self._rr
@@ -513,9 +513,9 @@ class BrokerSourceReader(SplitReader):
             self._offsets[split] = off + len(msgs)
             if not rows:
                 continue
-            return make_chunk(self.schema, rows,
-                              capacity=max(self.rows_per_chunk, len(rows)),
-                              physical=True)
+            return host_rows(self.schema, rows,
+                             capacity=max(self.rows_per_chunk, len(rows)),
+                             physical=True)
         return None
 
     def close(self) -> None:

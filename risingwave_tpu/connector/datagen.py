@@ -20,11 +20,9 @@ from __future__ import annotations
 import numpy as np
 from typing import Dict, List, Optional
 
-from ..common.chunk import Column, StreamChunk, make_chunk
+from ..common.chunk import HostChunk
 from ..common.types import Schema, TypeKind
 from .base import SplitReader
-
-import jax.numpy as jnp
 
 
 def _field_values(field, kind: str, start: int, end: int,
@@ -84,7 +82,7 @@ class DatagenReader(SplitReader):
             if s in self._offsets:
                 self._offsets[s] = int(o)
 
-    def next_chunk(self) -> Optional[StreamChunk]:
+    def next_host_chunk(self) -> Optional[HostChunk]:
         # serve the most-behind split first: deterministic given offsets
         # alone, so seek() needs no extra cursor state
         for split in sorted(range(self.n_splits),
@@ -98,7 +96,7 @@ class DatagenReader(SplitReader):
                 continue
             self._offsets[sid] = hi
             n = hi - lo
-            cols = []
+            arrays = []
             for f, kind, start, end in self._fields:
                 vals = _field_values(f, kind, start, end, split,
                                      self.n_splits, lo, hi)
@@ -106,12 +104,7 @@ class DatagenReader(SplitReader):
                     from ..common.types import GLOBAL_STRING_DICT
                     vals = np.array([GLOBAL_STRING_DICT.intern(
                         f"{f.name}_{int(v)}") for v in vals], np.int32)
-                arr = np.zeros(self.rows_per_chunk, f.type.np_dtype)
-                arr[:n] = vals.astype(f.type.np_dtype)
-                mask = np.zeros(self.rows_per_chunk, bool)
-                mask[:n] = True
-                cols.append(Column(jnp.asarray(arr), jnp.asarray(mask)))
-            ops = jnp.zeros(self.rows_per_chunk, jnp.int8)
-            vis = jnp.asarray(mask)
-            return StreamChunk(ops, vis, tuple(cols))
+                arrays.append(vals)
+            # datagen makes no nulls and only inserts
+            return HostChunk(self.schema, arrays, n, self.rows_per_chunk)
         return None

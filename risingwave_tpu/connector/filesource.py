@@ -13,7 +13,7 @@ from __future__ import annotations
 import os
 from typing import Dict, List, Optional
 
-from ..common.chunk import OP_INSERT, StreamChunk, make_chunk
+from ..common.chunk import OP_INSERT, HostChunk, host_rows
 from ..common.types import Schema
 from .base import SplitReader
 from .parsers import parse_csv_lines, parse_debezium_line, parse_json_line
@@ -131,7 +131,7 @@ class FileSourceReader(SplitReader):
             self._offsets[split] = start + len(body)
         return ops, rows
 
-    def next_chunk(self) -> Optional[StreamChunk]:
+    def next_host_chunk(self) -> Optional[HostChunk]:
         self._discover()
         # most-behind split first: deterministic given offsets alone
         for split in sorted(self._offsets,
@@ -140,8 +140,8 @@ class FileSourceReader(SplitReader):
             if rows:
                 phys = [tuple(f.type.to_physical(v) if v is not None else None
                               for f, v in zip(self.schema, r)) for r in rows]
-                return make_chunk(self.schema, phys, ops=ops,
-                                  capacity=max(self.rows_per_chunk,
-                                               len(phys)),
-                                  physical=True)
+                return host_rows(self.schema, phys, ops=ops,
+                                 capacity=max(self.rows_per_chunk,
+                                              len(phys)),
+                                 physical=True)
         return None

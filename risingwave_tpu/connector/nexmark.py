@@ -4,7 +4,8 @@ Counterpart of the reference's NEXmark connector
 (reference: src/connector/src/source/nexmark/source/reader.rs:41; schemas
 from src/tests/simulation/src/nexmark/create_source.sql). Generation is
 vectorized numpy on the host (a whole chunk per call — there is no per-event
-loop), producing device chunks directly. Distributions follow the NEXmark
+loop): ``*_columns`` draws a chunk's host columns, ``next_*_chunk`` stages
+them on the device (common/chunk.stage_chunks). Distributions follow the NEXmark
 spec shape: event ratio person:auction:bid = 1:3:46, hot-auction/hot-bidder
 skew, price ~ geometric, monotonically advancing event time.
 """
@@ -16,7 +17,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..common.chunk import StreamChunk, make_chunk, Column
+from ..common.chunk import Column, HostChunk, StreamChunk, stage_chunks
 from ..common.types import (
     GLOBAL_STRING_DICT, INT64, Schema, TIMESTAMP, VARCHAR,
 )
@@ -127,20 +128,17 @@ class NexmarkGenerator:
         return self.cfg.start_time_us + event_ids * max(us_per_event, 1)
 
     def _chunk(self, schema: Schema, arrays: list[np.ndarray], n: int) -> StreamChunk:
-        cap = self.cfg.chunk_capacity
-        cols = []
-        for arr, field in zip(arrays, schema):
-            buf = np.zeros(cap, field.type.np_dtype)
-            buf[:n] = arr.astype(field.type.np_dtype)
-            cols.append(Column(jnp.asarray(buf), jnp.asarray(np.arange(cap) < n)))
-        ops = jnp.zeros(cap, jnp.int8)  # all Insert (append-only source)
-        vis = jnp.asarray(np.arange(cap) < n)
-        return StreamChunk(ops, vis, tuple(cols))
+        return stage_chunks(
+            [HostChunk(schema, arrays, n, self.cfg.chunk_capacity)])[0]
 
     # -- streams --------------------------------------------------------------
 
     def next_bid_chunk(self, n: Optional[int] = None) -> StreamChunk:
         n = n or self.cfg.chunk_capacity
+        return self._chunk(BID_SCHEMA, self.bid_columns(n), n)
+
+    def bid_columns(self, n: int) -> list[np.ndarray]:
+        """Host columns of the next ``n`` bids, in BID_SCHEMA's order."""
         ts, eids = self._advance(n)
         last_auction = self._last_auction_id(eids)
         last_person = self._last_person_id(eids)
@@ -159,14 +157,16 @@ class NexmarkGenerator:
         channel = self._channel_ids[self.rng.integers(0, len(self._channel_ids), n)]
         url = self._url_ids[self.rng.integers(0, len(self._url_ids), n)]
         extra = np.full(n, self._empty, np.int32)
-        return self._chunk(
-            BID_SCHEMA, [auction, bidder, price, channel, url, ts, extra], n)
+        return [auction, bidder, price, channel, url, ts, extra]
 
     def next_auction_chunk(self, n: Optional[int] = None) -> StreamChunk:
+        n = n or self.cfg.chunk_capacity
+        return self._chunk(AUCTION_SCHEMA, self.auction_columns(n), n)
+
+    def auction_columns(self, n: int) -> list[np.ndarray]:
         """The next ``n`` auctions of the ONE NEXmark event sequence: the
         j-th auction is event ``50*(j // 3) + 1 + j % 3`` and has the id
         ``FIRST_AUCTION_ID + j``."""
-        n = n or self.cfg.chunk_capacity
         j = np.arange(self.auctions_so_far, self.auctions_so_far + n,
                       dtype=np.int64)
         self.auctions_so_far += n
@@ -189,15 +189,16 @@ class NexmarkGenerator:
         seller = FIRST_PERSON_ID + np.where(hot, hot_seller, cold_seller)
         category = FIRST_CATEGORY_ID + self.rng.integers(0, NUM_CATEGORIES, n)
         extra = np.full(n, self._empty, np.int32)
-        return self._chunk(
-            AUCTION_SCHEMA,
-            [ids, item, desc, initial, reserve, ts, expires, seller, category, extra],
-            n)
+        return [ids, item, desc, initial, reserve, ts, expires, seller,
+                category, extra]
 
     def next_person_chunk(self, n: Optional[int] = None) -> StreamChunk:
+        n = n or self.cfg.chunk_capacity
+        return self._chunk(PERSON_SCHEMA, self.person_columns(n), n)
+
+    def person_columns(self, n: int) -> list[np.ndarray]:
         """The next ``n`` persons of the same sequence: the k-th person is
         event ``50*k`` and has the id ``FIRST_PERSON_ID + k``."""
-        n = n or self.cfg.chunk_capacity
         k = np.arange(self.persons_so_far, self.persons_so_far + n,
                       dtype=np.int64)
         self.persons_so_far += n
@@ -209,8 +210,7 @@ class NexmarkGenerator:
         city = self._city_ids[self.rng.integers(0, len(self._city_ids), n)]
         state = self._state_ids[self.rng.integers(0, len(self._state_ids), n)]
         extra = np.full(n, self._empty, np.int32)
-        return self._chunk(
-            PERSON_SCHEMA, [ids, name, email, card, city, state, ts, extra], n)
+        return [ids, name, email, card, city, state, ts, extra]
 
 
 class DeviceBidGenerator:

@@ -23,11 +23,12 @@ import threading
 from typing import Any, Callable, Optional, Sequence
 
 from ..common.chunk import (
-    OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, StreamChunk,
-    chunk_to_rows, make_chunk,
+    OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, HostChunk,
+    StagedCounts, StreamChunk, chunk_to_rows, make_chunk,
 )
 from ..common.config import MeshUnavailableError
 from ..common.types import Field, Schema
+from ..connector.base import feed_chunks
 from ..connector.nexmark import (
     AUCTION_SCHEMA, BID_SCHEMA, PERSON_SCHEMA, NexmarkConfig, NexmarkGenerator,
 )
@@ -157,7 +158,8 @@ class _SourceFeed:
     recovery seeks the reader before the first tick."""
 
     queue: QueueSource
-    generator: Callable[[], Optional[StreamChunk]]
+    #: one chunk's host columns a call; ``tick`` stages a barrier's together
+    generator: Callable[[], Optional[HostChunk]]
     reader: Optional[Any] = None
     state_table: Optional[StateTable] = None
     offsets_at_epoch: dict = dataclasses.field(default_factory=dict)
@@ -2750,7 +2752,7 @@ class Session:
                         # materialized state otherwise)
                         start_seq = reader.rows_emitted()
                 self.feeds.append(_SourceFeed(
-                    q, reader.next_chunk, reader=reader, state_table=st))
+                    q, reader.next_host_chunk, reader=reader, state_table=st))
             ex: Executor = _RowIdAppendSource(q, leaf.schema)
             ex = RowIdGenExecutor(ex, row_id_index=leaf.row_id_index,
                                   shard_id=self._alloc_shard(),
@@ -3132,19 +3134,20 @@ class Session:
             with tracing.span("source.feed", epoch=epoch,
                               stage="source_feed", cat=tracing.CAT_EPOCH,
                               tid="conductor") as feed_span:
+                staged = StagedCounts()
                 fed = fed_rows = 0
                 for feed in self.feeds:
                     if feed.job in self._dead_jobs:
                         # a dead job consumes nothing: advancing its reader
                         # would move offsets past rows it never processed
                         continue
-                    for _ in range(self.chunks_per_tick):
-                        chunk = feed.generator()
-                        if chunk is not None:
-                            feed.queue.push(chunk)
-                            fed += 1
-                            fed_rows += chunk.capacity
-                feed_span.set(chunks=fed, capacity_rows=fed_rows)
+                    for chunk in feed_chunks(
+                            feed.generator, self.chunks_per_tick,
+                            feed.queue.push, staged):
+                        fed += 1
+                        fed_rows += chunk.capacity
+                feed_span.set(chunks=fed, capacity_rows=fed_rows,
+                              **dataclasses.asdict(staged))
         if self._fused.engines:
             # fused jobs: one dispatch per group covers every member
             # MV's epoch; flush chunks land on the job queues BEFORE the

@@ -35,6 +35,7 @@ from typing import AsyncIterator, Optional
 from ..common.chunk import StreamChunk
 from ..common.row import encode_value_row
 from ..common.types import Field, INT64, Schema, VARCHAR
+from ..connector.base import feed_chunks
 from ..frontend.build import BuildConfig, BuildContext, build_plan
 from ..frontend.catalog import Catalog
 from ..frontend.plan_json import defs_from_json, plan_from_json
@@ -472,10 +473,8 @@ class WorkerHost:
             for feed in self.feeds:
                 if feed.job not in scope:
                     continue
-                for _ in range(self.chunks_per_tick):
-                    chunk = feed.reader.next_chunk()
-                    if chunk is not None:
-                        feed.queue.push(chunk)
+                feed_chunks(feed.reader.next_host_chunk,
+                            self.chunks_per_tick, feed.queue.push)
         for feed in self.feeds:
             if feed.job in scope:
                 feed.offsets_at_epoch[epoch] = feed.reader.offsets

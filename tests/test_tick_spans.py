@@ -563,7 +563,7 @@ def test_jitted_epoch_programs_keep_their_module_names(tmp_path):
         nos = jnp.asarray(group.batch_nos, jnp.int64)
         packed, ranks = group._probe(group.stacked)
         agg = agg_engine(execp, "exec")
-        chunk = execp.feeds[0].generator()
+        chunk = execp.feeds[0].reader.next_chunk()
         _, rank = agg._probe(agg.state)
         lowered = {
             "jit_coscheduled_epoch": group._epoch.lower(
@@ -608,7 +608,7 @@ def test_epoch_programs_carry_named_scopes(path, scopes, tmp_path):
                      "flush_finish": group._finish.lower(group.stacked)}
         else:
             agg = agg_engine(s, path)
-            low = agg._apply.lower(agg.state, s.feeds[0].generator(),
+            low = agg._apply.lower(agg.state, s.feeds[0].reader.next_chunk(),
                                    agg._str_ranks(), agg._lru())
             flush = {"flush_probe": agg._probe.lower(agg.state),
                      "flush_finish": agg._finish.lower(agg.state)}
@@ -761,6 +761,77 @@ def test_join_spans_and_their_args_on_both_kinds_of_barrier(q8_run, kind):
                 for d in by_epoch[e] if d["name"] == "join.state_delta"
                 and d["args"]["side"] == "left"]
         assert left == [3 * Q8_CHUNKS * 64] * len(left)
+
+
+@pytest.mark.parametrize("path", ["exec", "q8"])
+def test_source_feed_counts_what_it_staged(path, tmp_path, q8_run):
+    """``source.feed`` (ISSUE 30): a feed's chunks of a barrier are staged
+    together — one transfer per dtype of its schema and one unpack
+    dispatch — and the args add up over the feeds: 16 bid chunks are 2 + 1
+    where they were 272 copies."""
+    if path == "exec":
+        s, by_epoch = run("exec", tmp_path, ticks=3)
+        feeds, chunks, cap_rows = 1, CHUNKS, CHUNKS * CAP
+        # int64 x 4 + int32 x 3 a bid
+        nbytes = CHUNKS * CAP * (4 * 8 + 3 * 4)
+    else:
+        s, by_epoch, _history = q8_run
+        feeds, chunks, cap_rows = 2, 2 * Q8_CHUNKS, Q8_CHUNKS * (64 + 192)
+        # person int64 x 2 + int32 x 6, auction int64 x 7 + int32 x 3
+        nbytes = Q8_CHUNKS * (64 * (2 * 8 + 6 * 4) + 192 * (7 * 8 + 3 * 4))
+    try:
+        for spans in by_epoch.values():
+            (feed,) = [d for d in spans if d["name"] == "source.feed"]
+            assert feed["args"] == {
+                "chunks": chunks, "capacity_rows": cap_rows,
+                "transfers": 2 * feeds, "dispatches": feeds,
+                "bytes_staged": nbytes}
+    finally:
+        if path == "exec":
+            s.close()
+
+
+def test_a_failed_draw_loses_none_of_the_chunks_drawn_before_it(tmp_path):
+    """A draw advances the reader's offsets. Where a later draw of the same
+    barrier raises (a broker fetch out of retries, a file read error), what
+    was drawn is staged and queued all the same: the retried tick goes on
+    from there, and the checkpoint's offsets cover only rows the MV holds —
+    in this session and in the one recovered from it."""
+    data_dir = str(tmp_path / "flaky")
+    s = open_session("exec", data_dir)
+    try:
+        s.tick()
+        feed = s.feeds[0]
+        real, draws = feed.generator, []
+
+        def flaky():
+            draws.append(None)
+            if len(draws) == 3:
+                raise OSError("fetch failed, out of retries")
+            return real()
+        feed.generator = flaky
+        with pytest.raises(OSError):
+            s.tick()
+        assert feed.reader.offsets == {"0": CHUNKS + 2}
+        ticks = 2                           # the ticker retries
+        s.tick()
+        while s.epoch % 3:
+            s.tick()                        # ... through a checkpoint
+            ticks += 1
+        fed = feed.reader.offsets["0"]
+        assert fed == ticks * CHUNKS + 2
+        assert sum(r[2] for r in s.mv_rows("q5")) == fed * CAP
+    finally:
+        s.close()
+    # recovered: catalog, split offsets and MV from the last checkpoint
+    s = Session(config=BuildConfig(agg_table_capacity=1 << 16),
+                source_chunk_capacity=CAP, chunks_per_tick=CHUNKS,
+                checkpoint_frequency=3, data_dir=data_dir)
+    try:
+        assert s.feeds[0].reader.offsets == {"0": fed}
+        assert sum(r[2] for r in s.mv_rows("q5")) == fed * CAP
+    finally:
+        s.close()
 
 
 def test_join_programs_carry_their_names_and_scopes(q8_run):
