@@ -35,8 +35,10 @@ Phases (no arguments, one chip):
 
 ``--chips 4`` runs the mesh phase and what it is compared with, and no
 other phase: the same q5 and q8 MVs at the same size through
-``[streaming] mesh_shape = 4`` (sharded fused epoch with ``coschedule``
-on; sharded executors and the rank kernel under shard_map for the join)
+``[streaming] mesh_shape = 4`` (q5 twice: the sharded fused epoch with
+``coschedule`` on, and the default path with it off — the sharded hash
+agg executor the benchmark's ``q5core_exec_mesh4_catchup`` guards;
+sharded executors and the rank kernel under shard_map for the join)
 against the same MVs on one chip of the same host and the numpy
 recomputation. (NEXmark q7 is not the join MV: its join keeps every bid
 keyed by price and the arena is rectangular — 2 M log-uniform prices put
@@ -707,6 +709,26 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
             check(s.metrics()["coschedule"]["jobs"] == 1,
                   "one-chip session did not take the fused epoch")
         s.close()
+        # q5 again at DEFAULT settings (coschedule off): host bid source →
+        # project → hash agg executor — on a mesh the sharded executor of
+        # parallel/ (chunk split, vnode all-to-all, per-shard upsert), the
+        # path the benchmark's q5core_exec_mesh4_catchup guards
+        s = open_session(os.path.join(root, f"q5x_{tag}"), sz, seed,
+                         coschedule=False, mesh=mesh)
+        s.run_sql(source_ddl(chunk, tables=("bid",)))
+        s.run_sql(Q5_SQL)
+        out["q5x_ticks"] = timed_ticks(s, ticks)
+        out["q5x"] = s.run_sql(Q5_SELECT)
+        check(not s._fused.engines,
+              "default-path q5 session took a fused epoch")
+        aggs = [type(node).__name__ for node in pipeline_nodes(s.jobs["q5"])
+                if type(node).__name__.endswith("HashAggExecutor")]
+        check(aggs == (["ShardedHashAggExecutor"] if mesh
+                       else ["HashAggExecutor"]),
+              f"default-path q5 runs {aggs}")
+        spread_over(mesh or 1, "q5x",
+                    executor_state_leaves(s.jobs["q5"]), out)
+        s.close()
         # q8 LEFT OUTER: the join MV through the executors — the
         # mesh-sharded executors of parallel/ (two sharded aggs feeding
         # the sharded join, rank kernel under shard_map) when mesh_shape
@@ -730,12 +752,19 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
         check(got5.shape == exp5.shape and bool(np.array_equal(got5, exp5)),
               f"q5 on {n} chips differs from q5 on one chip "
               f"({got5.shape[0]} vs {exp5.shape[0]} groups)")
+        got5x, exp5x = q5_rows_array(many["q5x"]), q5_rows_array(one["q5x"])
+        check(got5x.shape == exp5x.shape
+              and bool(np.array_equal(got5x, exp5x)),
+              f"default-path q5 on {n} chips differs from one chip's "
+              f"({got5x.shape[0]} vs {exp5x.shape[0]} groups)")
         check(many["q8"] == one["q8"],
               f"q8 on {n} chips differs from q8 on one chip "
               f"({len(many['q8'])} vs {len(one['q8'])} rows)")
         # and both against the plain host recomputation
         auction, ts = device_bid_stream(seed, chunk, k, ticks)
         q5 = check_q5("mesh q5", many["q5"], auction, ts)
+        q5x = check_q5("mesh default-path q5", many["q5x"],
+                       *host_bid_stream(seed, chunk, ticks * k))
         exp8 = ref_q8(*side_stream_rows(seed, chunk, ticks * k))
         check(many["q8"] == exp8,
               f"q8 differs from the host recomputation "
@@ -749,11 +778,11 @@ def phase_mesh(sz: dict, seed: int, root: str, n: int) -> None:
                         "bytes_in_use": stats.get("bytes_in_use"),
                         "peak_bytes_in_use": stats.get(
                             "peak_bytes_in_use")})
-        rows = ("q5", "q8")
+        rows = ("q5", "q5x", "q8")
         ph.info.update(
             chips=n, barriers=ticks, chunk_rows=chunk, chunks_per_barrier=k,
             checkpoint_frequency=CHECKPOINT_FREQUENCY,
-            q5=q5, q5_bid_events=ticks * k * chunk,
+            q5=q5, q5_default_path=q5x, q5_bid_events=ticks * k * chunk,
             q8_join_rows=len(exp8), q8_matched_rows=matched,
             person_auction_rows_per_chunk=list(side_rows(chunk)),
             equal_rows_mesh_vs_one_chip=True,

@@ -224,6 +224,13 @@ def _trace_annotation():
     return _annotation
 
 
+def current_span() -> Optional["span"]:
+    """The innermost open span of the running task (None outside any): an
+    operator inside its ``<identity>.barrier`` adds the counts it learns
+    there with ``current_span().set(...)``."""
+    return _CURRENT.get()
+
+
 def set_conductor_epoch(epoch: Optional[int]) -> None:
     global _conductor_epoch
     _conductor_epoch = epoch

@@ -74,6 +74,21 @@ def _keys_equal_at(table: DeviceHashTable, cand: jax.Array,
     return eq
 
 
+def _home_slot(key_cols: Sequence[Column], cap: int) -> jax.Array:
+    """A key's first probe position: the HIGH word of its 64-bit hash.
+
+    The low bits belong to the routing: ``common/hashing.vnode_of`` is the
+    same hash modulo 256, and a mesh shard (or a fragment actor) holds only
+    the keys of its contiguous vnode range. Indexing with the low bits gave
+    such a shard 64 home slots in every 256 — its keys piled up in runs, a
+    quarter-full table probed 79 slots deep and a 40 % full one ran past
+    ``MAX_PROBE_ROUNDS`` into overflow (ISSUE 31, seen on the four-chip
+    cell as barriers slowing 1.6 x inside one window). The high word shares
+    no bit with the vnode, so a shard's table fills like any other."""
+    h = hash_columns(key_cols) >> jnp.uint64(32)
+    return (h & jnp.uint64(cap - 1)).astype(jnp.int32)
+
+
 @jax.named_scope("table_probe")
 def ht_lookup_or_insert(
     table: DeviceHashTable, key_cols: Sequence[Column], valid: jax.Array
@@ -91,7 +106,7 @@ def ht_lookup_or_insert(
     datas = [c.data for c in key_cols]
     masks = [c.mask for c in key_cols]
     n = valid.shape[0]
-    h = (hash_columns(key_cols) & jnp.uint64(cap - 1)).astype(jnp.int32)
+    h = _home_slot(key_cols, cap)
 
     def cond(state):
         _, _, _, done, _, _, it = state
@@ -158,7 +173,7 @@ def ht_lookup(table: DeviceHashTable, key_cols: Sequence[Column], valid: jax.Arr
     datas = [c.data for c in key_cols]
     masks = [c.mask for c in key_cols]
     n = valid.shape[0]
-    h = (hash_columns(key_cols) & jnp.uint64(cap - 1)).astype(jnp.int32)
+    h = _home_slot(key_cols, cap)
 
     def cond(state):
         done, _, _, it = state

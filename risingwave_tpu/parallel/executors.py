@@ -13,9 +13,22 @@ the exchange layer has no host-visible existence at all.
 An input chunk of capacity C is split into n local chunks of capacity C/n
 (leading [n] axis sharded over the mesh); the vnode shuffle inside the step
 re-routes rows to their owner shard, so the host-side split is free-form.
-Emission gathers per-shard output windows back to the driving device —
-correctness-first for now; a sharded MaterializeExecutor keeps egress
-device-resident later.
+The agg packs a chunk's leaves by dtype first (``pack_chunk``: one dispatch
+and one transfer a dtype); the join still splits leaf by leaf
+(``split_chunk``). Emission flattens the shards' output windows into one
+wide chunk that the ordinary MaterializeExecutor fetches.
+
+What the chip showed (four v5e chips, the benchmark's
+``q5core_exec_mesh4_catchup``, PERF.md PR 31): the HOST bounds the path, as
+on one chip. The leaf-by-leaf split was the largest piece of a barrier (141
+of 257 ms for 16 chunks of 6 leaves: a reshape dispatch and a four-device
+``device_put`` a leaf), which is why the agg packs. The step costs about 7 ms
+of device time a 4,096-row chunk on EVERY chip — each probes a full
+4,096-slot receive buffer for its quarter of the table, so four chips do
+not divide the one-chip step's work, they repeat it — and the checkpoint
+pulls the whole sharded state to the host (``_checkpoint_to_state_table``,
+0.56 s for 120 MB and 32 K dirty groups; ROADMAP A2d). A device-resident
+egress was not what the cell asked for: Materialize is 5 ms of the barrier.
 
 Durability mirrors the single-chip executors: dirty deltas flush to host
 StateTables on checkpoint barriers; recovery re-routes committed rows by
@@ -35,6 +48,10 @@ from ..common.chunk import (
     Column, DEFAULT_CHUNK_CAPACITY, StreamChunk, count_units,
     gather_units_window, pad_chunk, physical_chunk,
 )
+from ..common.fetch import fetch
+from ..common.tracing import (
+    CAT_STORAGE, current_span, now_ns, record_span, span,
+)
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall
 from ..ops.hash_table import ht_lookup_or_insert
@@ -44,7 +61,7 @@ from ..stream.barrier_align import barrier_align
 from ..stream.executor import Executor, SingleInputExecutor
 from ..stream.hash_join import _clear_ckpt_marks
 from ..stream.message import Barrier
-from .sharded_agg import ShardedHashAgg
+from .sharded_agg import ShardedHashAgg, build_sharded_agg_step
 from .sharded_join import ShardedHashJoin
 
 
@@ -57,6 +74,74 @@ def split_chunk(chunk: StreamChunk, n: int, sharding) -> StreamChunk:
         lambda x: x.reshape((n, -1) + x.shape[1:]), chunk)
     return jax.device_put(
         stacked, jax.tree_util.tree_map(lambda _: sharding, stacked))
+
+
+def _stack_key(x) -> tuple:
+    """Leaves that share a stack: same trailing shape, same dtype (the
+    bools — ``vis``, null masks — ride as int8 beside ``ops``)."""
+    dtype = jnp.int8 if x.dtype == jnp.bool_ else x.dtype
+    return jnp.dtype(dtype), x.shape[1:]
+
+
+def pack_chunk(chunk: StreamChunk, n: int) -> tuple:
+    """A chunk as a few arrays ready for the mesh: padded to a multiple of
+    ``n`` rows, every leaf reshaped ``[n, C/n, ...]`` and the leaves of one
+    ``_stack_key`` stacked ``[n, k, C/n, ...]`` — q5's six leaves become one
+    int64 and one int8 array. Jitted by the caller, it is ONE dispatch a
+    chunk, and what crosses to the other chips is one transfer a stack
+    where ``split_chunk`` pays a reshape and a transfer a leaf (0.5 + 1.3
+    ms a leaf on a v5e host, PERF.md PR 31)."""
+    chunk = pad_chunk(chunk, -(-chunk.capacity // n) * n)
+    stacks: dict = {}
+    for x in jax.tree_util.tree_leaves(chunk):
+        dtype, trailing = key = _stack_key(x)
+        stacks.setdefault(key, []).append(
+            x.astype(dtype).reshape((n, -1) + trailing))
+    return tuple(jnp.stack(xs, axis=1) for xs in stacks.values())
+
+
+def unpack_like(chunk: StreamChunk):
+    """The inverse of ``pack_chunk`` for chunks shaped like ``chunk``, for
+    use INSIDE a ``shard_map`` body: local stacks ``[1, k, C/n, ...]`` →
+    the local ``[C/n, ...]`` chunk."""
+    leaves, treedef = jax.tree_util.tree_flatten(chunk)
+    filled: dict = {}       # stack key → leaves placed so far, in stack order
+    where = []
+    for x in leaves:
+        key = _stack_key(x)
+        row = filled.get(key, 0)
+        filled[key] = row + 1
+        where.append((list(filled).index(key), row, x.dtype))
+
+    def unpack(stacks) -> StreamChunk:
+        return jax.tree_util.tree_unflatten(
+            treedef, [stacks[g][0, j].astype(dtype)
+                      for g, j, dtype in where])
+    return unpack
+
+
+class _SplitClock:
+    """An epoch's chunks packed and put on the mesh, rolled up into ONE
+    ``shard.split`` span at the barrier (a span a chunk would flood the
+    ring; ``stream/metrics.ChunkClock`` does the same for ``.chunks``,
+    inside whose time these pieces lie)."""
+
+    __slots__ = ("first_ns", "busy_ns", "chunks", "transfers")
+
+    def __init__(self):
+        self.first_ns = self.busy_ns = self.chunks = self.transfers = 0
+
+    def add(self, t0: int, transfers: int) -> None:
+        self.first_ns = self.first_ns or t0
+        self.busy_ns += now_ns() - t0
+        self.chunks += 1
+        self.transfers += transfers
+
+    def emit(self, identity: str, epoch: int) -> None:
+        record_span("shard.split", self.first_ns or now_ns(), self.busy_ns,
+                    epoch=epoch, parent="barrier.collect", tid=identity,
+                    chunks=self.chunks, transfers=self.transfers)
+        self.__init__()
 
 
 class ShardedHashAggExecutor(SingleInputExecutor):
@@ -94,18 +179,48 @@ class ShardedHashAggExecutor(SingleInputExecutor):
         self._flatten = jax.jit(flatten_shards)
         self._rank = jax.jit(jax.vmap(core.flush_rank))
         self._finish = jax.jit(jax.vmap(core.finish_flush))
+        self._pack = jax.jit(pack_chunk, static_argnums=(1,))
+        # the sharded step over packed chunks, one per chunk signature
+        # (an executor's input has one; built at its first chunk)
+        self._steps: dict = {}
+        self._split = _SplitClock()
+        # per-shard rows routed up to the last barrier (the step's running
+        # count is never reset on the device)
+        self._routed_seen = np.zeros(self.n, np.int64)
         if self.state_table is not None:
             self._load_from_state_table()
 
+    def _packed_step(self, chunk: StreamChunk):
+        signature = tuple((x.shape, x.dtype)
+                          for x in jax.tree_util.tree_leaves(chunk))
+        step = self._steps.get(signature)
+        if step is None:
+            step = self._steps[signature] = build_sharded_agg_step(
+                self.agg.core, self.agg.mesh, unpack_like(chunk))
+        return step
+
     async def map_chunk(self, chunk: StreamChunk):
-        self.agg.step(split_chunk(chunk, self.n, self.agg._sharding))
+        t0 = now_ns()
+        stacks = jax.device_put(self._pack(chunk, self.n),
+                                self.agg._sharding)
+        self._split.add(t0, len(stacks))
+        self.agg.step(stacks, self._packed_step(chunk))
         if False:
             yield
 
     async def on_barrier(self, barrier: Barrier):
+        epoch = barrier.epoch.curr
+        self._split.emit(self.identity, epoch)
         st = self.agg.state
         rank = self._rank(st)
-        counts, overflow = jax.device_get((rank[:, -1], st.overflow))
+        with span("agg.flush_wait", epoch=epoch, wait="device",
+                  tid=self.identity):
+            counts, overflow, routed = fetch(
+                (rank[:, -1], st.overflow, self.agg.routed))
+        epoch_routed = routed - self._routed_seen
+        self._routed_seen = routed
+        current_span().set(rows_routed=int(epoch_routed.sum()),
+                           rows_routed_max=int(epoch_routed.max()))
         if bool(np.any(overflow)):
             raise RuntimeError(
                 f"{self.identity}: group table overflow (per-shard capacity "
@@ -120,13 +235,24 @@ class ShardedHashAggExecutor(SingleInputExecutor):
             yield self._flatten(batch)
             lo += G
         if barrier.checkpoint and self.state_table is not None:
-            self._checkpoint_to_state_table(barrier.epoch.curr)
+            with span("agg.state_delta", epoch=epoch, stage="state_delta",
+                      cat=CAT_STORAGE, tid=self.identity,
+                      shards=self.n) as delta:
+                self._checkpoint_to_state_table(epoch, delta)
         self.agg.state = self._finish(self.agg.state)
 
     # -- persistence ----------------------------------------------------------
 
-    def _checkpoint_to_state_table(self, epoch: int) -> None:
+    def _checkpoint_to_state_table(self, epoch: int, delta) -> None:
+        """The WHOLE sharded state crosses to the host (``bytes_fetched``
+        follows capacity x shards, not the delta) and the dirty groups
+        are staged row by row as Python tuples, encoded at the table's
+        commit (``bytes_staged`` 0, as on the one-chip path without the
+        native codec): the design PR 26 replaced on one chip."""
         st = jax.device_get(self.agg.state)
+        delta.set(dirty_groups=int(np.count_nonzero(st.ckpt_dirty)),
+                  bytes_staged=0, bytes_fetched=sum(
+                      x.nbytes for x in jax.tree_util.tree_leaves(st)))
         wrote = False
         for s in range(self.n):
             idx = np.nonzero(np.asarray(st.ckpt_dirty[s]))[0]

@@ -101,12 +101,54 @@ def shuffle_chunk_local(chunk: StreamChunk, n_shards: int,
     return jax.tree_util.tree_map(a2a, send)
 
 
+def _squeeze_shard_axis(chunk: StreamChunk) -> StreamChunk:
+    return jax.tree_util.tree_map(lambda x: x[0], chunk)
+
+
+def build_sharded_agg_step(core: AggCore, mesh: Mesh,
+                           unpack=_squeeze_shard_axis):
+    """The jitted per-chunk program of the sharded agg over ``mesh``:
+    ``(state, routed, chunk) -> (state, routed, rows_in)``, every array
+    with a leading [n_shards] axis sharded over the mesh. ``unpack`` turns
+    a shard's local view of the third argument into its local chunk: a
+    stacked StreamChunk by default, the executor's packed stacks
+    (``parallel/executors.unpack_like``) on the SQL path. A function of its
+    own so that it can be compiled for a described mesh with no device
+    attached (tests/test_pallas_compile.py)."""
+    n = mesh.devices.size
+    gk = tuple(core.group_keys)
+
+    def sharded_agg_step(state: AggState, routed, chunk):
+        # shard_map keeps the sharded leading axis as size-1; work on the
+        # squeezed local view and restore the axis on the way out
+        state = jax.tree_util.tree_map(lambda x: x[0], state)
+        chunk = unpack(chunk)
+        with jax.named_scope("shard_shuffle"):
+            owned = shuffle_chunk_local(chunk, n, gk)
+        new_state = core.apply_chunk(state, owned)
+        routed = routed + jnp.sum(owned.vis, dtype=routed.dtype)
+        rows_in = jax.lax.psum(jnp.sum(chunk.vis.astype(jnp.int32)),
+                               SHARD_AXIS)
+        new_state = jax.tree_util.tree_map(lambda x: x[None], new_state)
+        return new_state, routed, rows_in
+
+    return jax.jit(shard_map_compat(
+        sharded_agg_step, mesh=mesh,
+        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
+        out_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P())))
+
+
 class ShardedHashAgg:
     """Data-parallel grouped agg over a device mesh.
 
     State arrays have shape [n_shards, ...] sharded on the leading axis; the
     jitted ``step`` does shuffle + upsert in one XLA program per chunk batch
-    (one local chunk per shard per step)."""
+    (one local chunk per shard per step; a device trace shows it as
+    ``jit_sharded_agg_step``, the exchange under the scope
+    ``shard_shuffle``). ``routed`` ([n_shards] int64, sharded like the
+    state) is each shard's running count of the rows the exchange handed
+    it, added up inside the step: how evenly the vnode map spreads the
+    stream, read at the barrier with the flush's own fetch."""
 
     def __init__(self, mesh: Mesh, key_types, group_keys: Sequence[int],
                  agg_calls: Sequence[AggCall], table_capacity: int = 1 << 14,
@@ -124,35 +166,18 @@ class ShardedHashAgg:
         init = jax.vmap(lambda _: local_init())(jnp.arange(self.n))
         self.state = jax.device_put(
             init, jax.tree_util.tree_map(lambda _: self._sharding, init))
+        self.routed = jax.device_put(jnp.zeros(self.n, jnp.int64),
+                                     self._sharding)
 
-        core = self.core
-        n = self.n
-        gk = tuple(group_keys)
+        self._step = build_sharded_agg_step(self.core, mesh)
 
-        def local_step(state: AggState, chunk: StreamChunk):
-            # shard_map keeps the sharded leading axis as size-1; work on the
-            # squeezed local view and restore the axis on the way out
-            state = jax.tree_util.tree_map(lambda x: x[0], state)
-            chunk = jax.tree_util.tree_map(lambda x: x[0], chunk)
-            owned = shuffle_chunk_local(chunk, n, gk)
-            new_state = core.apply_chunk(state, owned)
-            rows_in = jax.lax.psum(jnp.sum(chunk.vis.astype(jnp.int32)),
-                                   SHARD_AXIS)
-            new_state = jax.tree_util.tree_map(lambda x: x[None], new_state)
-            return new_state, rows_in
-
-        self._step = jax.jit(
-            shard_map_compat(
-                local_step, mesh=mesh,
-                in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-                out_specs=(P(SHARD_AXIS), P()),
-            )
-        )
-
-    def step(self, chunk_batch: StreamChunk):
+    def step(self, chunk_batch, program=None):
         """``chunk_batch``: arrays with leading [n_shards] axis (one local
-        chunk per shard)."""
-        self.state, rows = self._step(self.state, chunk_batch)
+        chunk per shard) — a stacked StreamChunk, or whatever form the
+        ``program`` (a ``build_sharded_agg_step`` with an ``unpack`` of its
+        own) takes."""
+        self.state, self.routed, rows = (program or self._step)(
+            self.state, self.routed, chunk_batch)
         return rows
 
     # -- host-side helpers ----------------------------------------------------

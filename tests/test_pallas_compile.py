@@ -147,6 +147,52 @@ def test_ckpt_delta_window_compiles_for_v5e_without_a_scatter(one_chip):
     assert " gather(" in text and " scatter(" not in text
 
 
+def test_sharded_agg_step_compiles_for_the_four_chip_mesh(topo):
+    """The mesh cell's per-chunk program (``q5core_exec_mesh4_catchup``:
+    2^19 slots a shard, a 4,096-row chunk packed [4, k, 1024] by dtype)
+    through XLA:TPU for the described 2x2 mesh: the vnode exchange is in
+    it as all-to-alls, and a shard's state fits a chip many times over."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from risingwave_tpu.common import INT64
+    from risingwave_tpu.common.chunk import Column, StreamChunk
+    from risingwave_tpu.expr.agg import count_star
+    from risingwave_tpu.ops.grouped_agg import AggCore
+    from risingwave_tpu.parallel.executors import pack_chunk, unpack_like
+    from risingwave_tpu.parallel.sharded_agg import (
+        SHARD_AXIS, build_sharded_agg_step,
+    )
+
+    n, rows = 4, 4096
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    assert mesh.devices.size == n
+    sharded = NamedSharding(mesh, P(SHARD_AXIS))
+
+    def on_mesh(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
+
+    core = AggCore((INT64, INT64), (0, 1), [count_star()],
+                   table_capacity=1 << 19, out_capacity=rows)
+    state = jax.tree_util.tree_map(on_mesh, jax.eval_shape(
+        lambda: jax.vmap(lambda _: core.init_state())(jnp.arange(n))))
+
+    def col(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype)
+    chunk = StreamChunk(col(jnp.int8), col(jnp.bool_),
+                        (Column(col(jnp.int64), col(jnp.bool_)),) * 2)
+    stacks = jax.eval_shape(lambda c: pack_chunk(c, n), chunk)
+    assert sorted((x.shape, str(x.dtype)) for x in stacks) == [
+        ((4, 2, 1024), "int64"), ((4, 4, 1024), "int8")]
+    step = build_sharded_agg_step(core, mesh, unpack_like(chunk))
+    compiled = _compile_for(
+        step, state, on_mesh(jax.ShapeDtypeStruct((n,), jnp.int64)),
+        tuple(on_mesh(x) for x in stacks))
+    assert "all-to-all" in compiled.as_text()
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    assert 30e6 < per_chip < 45e6          # 72 B a slot x 2^19
+
+
 # ---------------------------------------------------------------------------
 # Tier 2: lower for platform "tpu" (StableHLO + embedded Mosaic payload)
 # ---------------------------------------------------------------------------
