@@ -20,6 +20,7 @@ from ..common.chunk import (
     StreamChunk,
 )
 from ..expr.agg import AggCall
+from .ckpt_delta import delta_window, dirty_slots
 from .hash_table import DeviceHashTable, ht_lookup_or_insert, ht_new, scatter_reduce
 
 
@@ -158,7 +159,7 @@ class AggCore:
         per update, and the old scatter-from-[capacity] form cost ~1 s per
         window at multi-million-row capacity."""
         G = self.groups_per_chunk
-        slot, valid = _dirty_slots(rank, lo, G)
+        slot, valid = dirty_slots(rank, lo, G)
 
         def interleave(a, b):
             return jnp.stack([a, b], axis=-1).reshape(2 * G)
@@ -197,17 +198,14 @@ class AggCore:
     def ckpt_delta_window(self, state: AggState, lo: jax.Array, G: int):
         """The checkpoint delta's rows for dirty ranks [lo, lo+G), in
         ascending slot order: ``(n_dirty, valid[G], key_data, key_mask,
-        lanes)``, each column gathered to ``G`` rows. Same contract as
-        ``gather_flush_chunk`` — gathers only, nothing scattered into a
-        capacity-sized array — so only the dirty rows need cross to the
-        host. The capacity is the state's own: the tick compiler hands
-        padded states."""
-        rank = jnp.cumsum(state.ckpt_dirty.astype(jnp.int32))
-        slot, valid = _dirty_slots(rank, lo, G)
-        return (rank[-1], valid,
-                tuple(kd[slot] for kd in state.table.key_data),
-                tuple(km[slot] for km in state.table.key_mask),
-                tuple(l[slot] for l in state.lanes))
+        lanes)``, each column gathered to ``G`` rows
+        (``ckpt_delta.delta_window``: gathers only, so only the dirty rows
+        need cross to the host). The capacity is the state's own: the tick
+        compiler hands padded states."""
+        n_dirty, valid, cols = delta_window(
+            state.ckpt_dirty,
+            (state.table.key_data, state.table.key_mask, state.lanes), lo, G)
+        return (n_dirty, valid, *cols)
 
     @jax.named_scope("flush_finish")
     def finish_flush(self, state: AggState) -> AggState:
@@ -354,17 +352,6 @@ class AggCore:
             table=table, lanes=tuple(lanes), prev_lanes=tuple(prev),
             dirty=dirty, ckpt_dirty=ckpt_dirty,
             overflow=state.overflow | ovf)
-
-
-def _dirty_slots(rank: jax.Array, lo: jax.Array, G: int):
-    """``(slot[G], valid[G])`` of the dirty groups with rank in [lo, lo+G),
-    by binary search over ``rank``, the inclusive prefix count of a dirty
-    mask: log2(capacity) gather passes over ``G`` indices, no scatter.
-    Invalid rows (past the last dirty group) read slot 0."""
-    ks = lo.astype(jnp.int32) + jnp.arange(G, dtype=jnp.int32)
-    pos = jnp.searchsorted(rank, ks + 1, side="left").astype(jnp.int32)
-    valid = ks < rank[-1]
-    return jnp.where(valid, pos, 0), valid
 
 
 def load_rows_into_state(core: AggCore, state: AggState, rows) -> AggState:

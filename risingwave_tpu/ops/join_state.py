@@ -48,6 +48,7 @@ from ..common.chunk import (
     StreamChunk,
 )
 from ..common.types import Schema
+from .ckpt_delta import delta_window
 from .hash_table import DeviceHashTable, ht_lookup, ht_lookup_or_insert, ht_new
 
 
@@ -490,6 +491,19 @@ def clean_side_below(st: JoinSideState, col_idx: int, threshold) -> JoinSideStat
         tomb=st.tomb | cleaned,
         ckpt_dirty=st.ckpt_dirty | cleaned,
     )
+
+
+@jax.named_scope("ckpt_delta")
+def join_ckpt_delta_window(st: JoinSideState, lo: jax.Array, G: int):
+    """One side's checkpoint delta for dirty ranks [lo, lo+G) of its
+    ``[capacity, W]`` arena read row-major (slot, then lane): ``(n_dirty,
+    valid[G], occupied, tomb, row_data, row_mask)``, each column gathered
+    to ``G`` rows (``ckpt_delta.delta_window``). Degrees, LRU stamps and
+    the key table are not persisted: recovery rebuilds them."""
+    n_dirty, valid, cols = delta_window(
+        st.ckpt_dirty, (st.occupied, st.tomb, st.row_data, st.row_mask),
+        lo, G)
+    return (n_dirty, valid, *cols)
 
 
 def compact_side(core: "JoinCore", old: JoinSideState, schema: Schema,

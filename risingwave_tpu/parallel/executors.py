@@ -25,9 +25,10 @@ of 257 ms for 16 chunks of 6 leaves: a reshape dispatch and a four-device
 ``device_put`` a leaf), which is why the agg packs. The step costs about 7 ms
 of device time a 4,096-row chunk on EVERY chip — each probes a full
 4,096-slot receive buffer for its quarter of the table, so four chips do
-not divide the one-chip step's work, they repeat it — and the checkpoint
-pulls the whole sharded state to the host (``_checkpoint_to_state_table``,
-0.56 s for 120 MB and 32 K dirty groups; ROADMAP A2d). A device-resident
+not divide the one-chip step's work, they repeat it. The checkpoint pulled
+the whole sharded state to the host (0.55 s for 120 MB and 32 K dirty
+groups) until PR 32; now every shard gathers its dirty groups where it
+lives (``_checkpoint_to_state_table``: 42 ms, 1.1 MB). A device-resident
 egress was not what the cell asked for: Materialize is 5 ms of the barrier.
 
 Durability mirrors the single-chip executors: dirty deltas flush to host
@@ -59,6 +60,7 @@ from ..ops.join_state import JoinType
 from ..storage.state_table import StateTable
 from ..stream.barrier_align import barrier_align
 from ..stream.executor import Executor, SingleInputExecutor
+from ..stream.hash_agg import stage_agg_delta
 from ..stream.hash_join import _clear_ckpt_marks
 from ..stream.message import Barrier
 from .sharded_agg import ShardedHashAgg, build_sharded_agg_step
@@ -179,6 +181,11 @@ class ShardedHashAggExecutor(SingleInputExecutor):
         self._flatten = jax.jit(flatten_shards)
         self._rank = jax.jit(jax.vmap(core.flush_rank))
         self._finish = jax.jit(jax.vmap(core.finish_flush))
+        # the state stays where it is sharded; a device trace shows the
+        # program as jit_ckpt_delta_window, as on one chip
+        self._delta_window = jax.jit(
+            jax.vmap(core.ckpt_delta_window, in_axes=(0, None, None)),
+            static_argnums=(2,))
         self._pack = jax.jit(pack_chunk, static_argnums=(1,))
         # the sharded step over packed chunks, one per chunk signature
         # (an executor's input has one; built at its first chunk)
@@ -244,39 +251,16 @@ class ShardedHashAggExecutor(SingleInputExecutor):
     # -- persistence ----------------------------------------------------------
 
     def _checkpoint_to_state_table(self, epoch: int, delta) -> None:
-        """The WHOLE sharded state crosses to the host (``bytes_fetched``
-        follows capacity x shards, not the delta) and the dirty groups
-        are staged row by row as Python tuples, encoded at the table's
-        commit (``bytes_staged`` 0, as on the one-chip path without the
-        native codec): the design PR 26 replaced on one chip."""
-        st = jax.device_get(self.agg.state)
-        delta.set(dirty_groups=int(np.count_nonzero(st.ckpt_dirty)),
-                  bytes_staged=0, bytes_fetched=sum(
-                      x.nbytes for x in jax.tree_util.tree_leaves(st)))
-        wrote = False
-        for s in range(self.n):
-            idx = np.nonzero(np.asarray(st.ckpt_dirty[s]))[0]
-            if not len(idx):
-                continue
-            wrote = True
-            keys_d = [np.asarray(kd[s])[idx] for kd in st.table.key_data]
-            keys_m = [np.asarray(km[s])[idx] for km in st.table.key_mask]
-            lanes = [np.asarray(l[s])[idx] for l in st.lanes]
-            for r in range(len(idx)):
-                key_vals = [
-                    keys_d[c][r].item() if keys_m[c][r] else None
-                    for c in range(len(keys_d))
-                ]
-                lane_vals = [lanes[j][r].item() for j in range(len(lanes))]
-                row = tuple(key_vals) + tuple(lane_vals)
-                if lanes[0][r] > 0:
-                    self.state_table.insert(row)
-                else:
-                    self.state_table.delete(row)
-        if wrote:
-            self.state_table.commit(epoch)
-        self.agg.state = self.agg.state.replace(
-            ckpt_dirty=jnp.zeros_like(self.agg.state.ckpt_dirty))
+        """Every shard's dirty groups, selected and gathered where the
+        shard lives (the one-chip window under ``vmap`` over the shard
+        axis: window 0 of all shards is one dispatch and one fetch),
+        staged shard after shard in ONE batch and one commit."""
+        st = self.agg.state
+        stage_agg_delta(self.state_table, epoch, delta,
+                        lambda lo, G: self._delta_window(st, lo, G),
+                        self.agg.core.capacity)
+        self.agg.state = st.replace(
+            ckpt_dirty=jnp.zeros_like(st.ckpt_dirty))
 
     def _load_from_state_table(self) -> None:
         """Recovery: route committed groups to their owner shard (same vnode

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.chunk import DEFAULT_CHUNK_CAPACITY, Column, StreamChunk
-from ..common.fetch import async_fetch, fetch
+from ..common.fetch import fetch
 from ..common.tracing import CAT_STORAGE, span
 from ..common.types import INT64, Field, Schema
 from ..expr.agg import AggCall
@@ -42,13 +42,7 @@ from ..ops.grouped_agg import AggCore, AggState, load_rows_into_state
 from ..storage.state_table import StateTable
 from .executor import Executor, SingleInputExecutor
 from .message import Barrier
-
-
-#: rows of one checkpoint-delta window (``AggCore.ckpt_delta_window``); a
-#: smaller table is its own window. The window's device time is linear in
-#: its rows, dirty or not (0.24-0.29 us a row on a v5e, PERF.md PR 26), so
-#: a window far above the delta gathers and moves rows nobody reads.
-_DELTA_WINDOW_ROWS = 1 << 13
+from .state_delta import fetch_delta, stage_delta
 
 
 class HashAggExecutor(SingleInputExecutor):
@@ -345,64 +339,14 @@ class HashAggExecutor(SingleInputExecutor):
         The span lives HERE so that both callers have it: ``on_barrier``
         and the co-scheduled tick, which borrows this executor as its
         persistence engine."""
+        st = self.state
         with span("agg.state_delta", epoch=epoch, stage="state_delta",
                   cat=CAT_STORAGE, tid=self.identity) as delta:
-            self._stage_state_delta(epoch, delta)
-
-    def _stage_state_delta(self, epoch: int, delta) -> None:
-        """Select and gather the dirty groups ON THE DEVICE
-        (``AggCore.ckpt_delta_window``) and fetch only those rows: what
-        crosses to the host follows the delta, not the table's capacity.
-        Window 0 answers ``n_dirty``; a delta larger than one window
-        costs ``ceil(n_dirty / G)`` of them."""
-        st = self.state
-        G = min(st.ckpt_dirty.shape[0], _DELTA_WINDOW_ROWS)
-        wins = [fetch(self._delta_window(st, np.int32(0), G))]
-        n_dirty = int(wins[0][0])
-        more = [async_fetch(self._delta_window(st, np.int32(lo), G))
-                for lo in range(G, n_dirty, G)]
-        wins += [f.result() for f in more]
-        # every window but the last is full and the valid rows lead, so the
-        # first n_dirty rows of the concatenation are the delta, in
-        # ascending slot order
-        keys_d, keys_m, lanes = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate(xs)[:n_dirty], *(w[2:] for w in wins))
-        delta.set(dirty_groups=n_dirty, bytes_staged=0, windows=len(wins),
-                  bytes_fetched=sum(
-                      x.nbytes for x in jax.tree_util.tree_leaves(wins)))
-        if n_dirty:
-            idx = np.arange(n_dirty)
-            live = lanes[0] > 0
-            from ..native import codec as _native_codec
-            codec = _native_codec()
-            if codec is not None:
-                datas = keys_d + lanes
-                masks = keys_m + (np.ones(n_dirty, bool),) * len(lanes)
-                types = self.state_table.schema.types
-                nk = len(keys_d)
-                ins_idx, del_idx = idx[live], idx[~live]
-                pk_t = list(types[:nk])
-                puts = dict(zip(
-                    codec.encode_keys(keys_d, keys_m, pk_t, ins_idx),
-                    codec.encode_value_rows(datas, masks, types, ins_idx)))
-                dels = codec.encode_keys(keys_d, keys_m, pk_t, del_idx)
-                self.state_table.stage_encoded(puts, dels)
-                delta.set(bytes_staged=sum(map(len, puts))
-                          + sum(map(len, puts.values()))
-                          + sum(map(len, dels)))
-            else:
-                for r in idx:
-                    key_vals = [
-                        keys_d[c][r].item() if keys_m[c][r] else None
-                        for c in range(len(keys_d))
-                    ]
-                    row = tuple(key_vals) + tuple(l[r].item() for l in lanes)
-                    if live[r]:
-                        self.state_table.insert(row)
-                    else:
-                        self.state_table.delete(row)
-            self.state_table.commit(epoch)
-        self.state = st.replace(ckpt_dirty=jnp.zeros_like(st.ckpt_dirty))
+            stage_agg_delta(self.state_table, epoch, delta,
+                            lambda lo, G: self._delta_window(st, lo, G),
+                            st.ckpt_dirty.shape[0])
+            self.state = st.replace(
+                ckpt_dirty=jnp.zeros_like(st.ckpt_dirty))
 
     def _filter_shard(self, rows: list) -> list:
         """Keep rows whose group key hashes to this actor's shard — the
@@ -447,6 +391,23 @@ class HashAggExecutor(SingleInputExecutor):
         # prev must match what was already emitted before the failure: the
         # recovered snapshot is the new baseline
         self.state = self.state.rebaselined()
+
+
+def stage_agg_delta(table: StateTable, epoch: int, delta, window,
+                    capacity: int) -> None:
+    """Select and gather the groups dirtied since the last checkpoint ON
+    THE DEVICE (``window`` dispatches ``AggCore.ckpt_delta_window``, alone
+    or under ``vmap`` over a mesh's shards) and fetch only those rows: what
+    crosses to the host follows the delta, not the table's capacity. A
+    group whose row count is back at 0 is a delete. ``delta`` is the
+    ``agg.state_delta`` span, whose counters this sets."""
+    n_dirty, (keys_d, keys_m, lanes), fetched = fetch_delta(window, capacity)
+    delta.set(dirty_groups=n_dirty, bytes_staged=0, **fetched)
+    if n_dirty:
+        live = lanes[0] > 0
+        delta.set(bytes_staged=stage_delta(
+            table, epoch, keys_d + lanes,
+            keys_m + (np.ones(n_dirty, bool),) * len(lanes), live, ~live))
 
 
 def agg_state_schema(key_fields: Sequence[Field], agg_calls: Sequence[AggCall]) -> Schema:

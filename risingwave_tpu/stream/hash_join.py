@@ -33,12 +33,14 @@ from ..common.fetch import fetch
 from ..common.tracing import CAT_STORAGE, conductor_epoch, span
 from ..ops.join_state import (
     JoinCore, JoinSideState, JoinState, JoinType, apply_evict_side,
-    clean_side_below, compact_side, import_state, join_evict_plan,
+    clean_side_below, compact_side, import_state, join_ckpt_delta_window,
+    join_evict_plan,
 )
 from ..storage.state_table import StateTable
 from .barrier_align import barrier_align
 from .executor import Executor
 from .message import Barrier
+from .state_delta import fetch_delta, stage_delta
 
 
 class HashJoinExecutor(Executor):
@@ -200,6 +202,10 @@ class HashJoinExecutor(Executor):
 
         self._pack_stats = jax.jit(join_pack_stats)
         self._clear_ckpt = jax.jit(_clear_ckpt_marks)
+        # reads a side and leaves it in place (not donated); a device
+        # trace shows it as jit_join_ckpt_delta_window
+        self._delta_window = jax.jit(join_ckpt_delta_window,
+                                     static_argnums=(2,))
         self._clean_side = jax.jit(clean_side_below, static_argnums=(1,))
 
         def _compact(state: JoinState) -> JoinState:
@@ -600,70 +606,19 @@ class HashJoinExecutor(Executor):
         self.state = self._clear_ckpt(self.state)
 
     def _stage_state_delta(self, side: str, epoch: int, delta) -> None:
-        """Stage the rows of one side dirtied since the last checkpoint:
-        the dirty marks cross to the host first and, where any is set,
-        every ``[capacity, W]`` column of the side after them
-        (``bytes_fetched`` follows the arena's capacity, not the delta)."""
-        table = self.state_tables[side]
+        """Stage the rows of one side dirtied since the last checkpoint,
+        selected and gathered on the device (``join_ckpt_delta_window``):
+        what crosses to the host follows the delta, not the arena's
+        capacity. A dirty row is a put where it is occupied, a delete
+        where it is a tombstone that was not filled again."""
         st: JoinSideState = getattr(self.state, side)
-        dirty = np.asarray(st.ckpt_dirty)
-        slots, lanes = np.nonzero(dirty)
-        delta.set(dirty_rows=len(slots), bytes_fetched=dirty.nbytes,
-                  bytes_staged=0)
-        if not len(slots):
-            return
-        occ = np.asarray(st.occupied)
-        tomb = np.asarray(st.tomb)
-        datas = [np.asarray(d) for d in st.row_data]
-        masks = [np.asarray(m) for m in st.row_mask]
-        delta.set(bytes_fetched=dirty.nbytes + sum(
-            a.nbytes for a in [occ, tomb, *datas, *masks]))
-        from ..native import codec as _native_codec
-        codec = _native_codec()
-        if codec is not None:
-            # batch path: flatten (slot, lane) → row index and encode the
-            # whole dirty delta in one native call; stage_encoded applies
-            # deletes before inserts, the same-pk update ordering rule below
-            width = occ.shape[1]
-            flat = slots * width + lanes
-            fdatas = [d.reshape(-1) for d in datas]
-            fmasks = [m.reshape(-1) for m in masks]
-            occ_f = occ.reshape(-1)
-            tomb_f = tomb.reshape(-1)
-            del_idx = flat[tomb_f[flat] & ~occ_f[flat]]
-            ins_idx = flat[occ_f[flat]]
-            types = table.schema.types
-            pk = table.pk_indices
-            pk_d = [fdatas[i] for i in pk]
-            pk_m = [fmasks[i] for i in pk]
-            pk_t = [types[i] for i in pk]
-            puts = dict(zip(
-                codec.encode_keys(pk_d, pk_m, pk_t, ins_idx),
-                codec.encode_value_rows(fdatas, fmasks, types, ins_idx)))
-            dels = codec.encode_keys(pk_d, pk_m, pk_t, del_idx)
-            table.stage_encoded(puts, dels)
-            delta.set(bytes_staged=sum(map(len, puts))
-                      + sum(map(len, puts.values()))
-                      + sum(map(len, dels)))
-            table.commit(epoch)
-            return
-
-        def row_at(s, l):
-            return tuple(
-                datas[c][s, l].item() if masks[c][s, l] else None
-                for c in range(len(datas))
-            )
-
-        # deletes strictly before inserts: a same-pk update lands in two
-        # different lanes and scan order must not let the delete clobber
-        # the freshly upserted row
-        for s, l in zip(slots, lanes):
-            if tomb[s, l] and not occ[s, l]:
-                table.delete(row_at(s, l))
-        for s, l in zip(slots, lanes):
-            if occ[s, l]:
-                table.insert(row_at(s, l))
-        table.commit(epoch)
+        n_dirty, (occ, tomb, datas, masks), fetched = fetch_delta(
+            lambda lo, G: self._delta_window(st, lo, G), st.ckpt_dirty.size)
+        delta.set(dirty_rows=n_dirty, bytes_staged=0, **fetched)
+        if n_dirty:
+            delta.set(bytes_staged=stage_delta(
+                self.state_tables[side], epoch, datas, masks, occ,
+                tomb & ~occ))
 
     def _load_from_state_tables(self) -> None:
         """Recovery: replay both sides' committed rows through the insert

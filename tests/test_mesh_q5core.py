@@ -24,7 +24,9 @@ import pytest
 from risingwave_tpu.common import tracing
 from risingwave_tpu.common.config import load_config
 from risingwave_tpu.frontend import Session
+from risingwave_tpu.native import codec as native_codec
 from risingwave_tpu.parallel.executors import ShardedHashAggExecutor
+from risingwave_tpu.stream import state_delta
 from risingwave_tpu.stream.metrics import iter_executors
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -76,7 +78,11 @@ def mesh_run(tmp_path_factory):
     rows, ledger, the spans of every barrier, and what the shards hold."""
     config = tiny_config()
     tracing.GLOBAL_TRACE.clear()
-    sut = drive(config, str(tmp_path_factory.mktemp("mesh4")), 30)
+    # the deployment's checkpoint window is 8,192 rows of a 2^19-slot
+    # shard; the rehearsal's 2,048-slot shard would be its own window
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(state_delta, "DELTA_WINDOW_ROWS", 256)
+        sut = drive(config, str(tmp_path_factory.mktemp("mesh4")), 30)
     (ex,) = sharded_aggs(sut.session)
     out = {"config": config, "rows": sut.read_back(),
            "history": sut.barrier_history(),
@@ -166,6 +172,8 @@ def test_sharded_executor_spans_on_every_barrier(mesh_run):
     k = config["chunks_per_tick"]
     per_barrier = k * config["rows_per_chunk"]["bid"]
     seen, hot_share = 0, []
+    state_bytes = sum(x.nbytes for x in
+                      jax.tree_util.tree_leaves(mesh_run["state"]))
     for h, spans in window_spans(mesh_run):
         ids = {s["id"]: s for s in spans}
         collect = only(spans, "barrier.collect")
@@ -190,8 +198,13 @@ def test_sharded_executor_spans_on_every_barrier(mesh_run):
             assert ids[delta["parent"]] is barrier
             assert delta["args"]["shards"] == 4
             assert delta["args"]["dirty_groups"] > 0
-            assert delta["args"]["bytes_fetched"] > 0
-            assert delta["args"]["bytes_staged"] == 0
+            assert delta["args"]["windows"] >= 1
+            # the delta's rows, not the state: 4 shards x 72 B a slot
+            assert 0 < delta["args"]["bytes_fetched"] < state_bytes // 8
+            if native_codec() is None:
+                assert delta["args"]["bytes_staged"] == 0
+            else:
+                assert delta["args"]["bytes_staged"] > 0
             seen += 1
         else:
             assert not deltas

@@ -132,7 +132,7 @@ def test_ckpt_delta_window_compiles_for_v5e_without_a_scatter(one_chip):
     from risingwave_tpu.common import INT64
     from risingwave_tpu.expr.agg import count_star
     from risingwave_tpu.ops.grouped_agg import AggCore
-    from risingwave_tpu.stream.hash_agg import _DELTA_WINDOW_ROWS
+    from risingwave_tpu.stream.state_delta import DELTA_WINDOW_ROWS
 
     core = AggCore((INT64, INT64), (0, 1), [count_star()],
                    table_capacity=1 << 21, out_capacity=4096)
@@ -142,9 +142,78 @@ def test_ckpt_delta_window_compiles_for_v5e_without_a_scatter(one_chip):
     lo = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = _compile_for(
         jax.jit(core.ckpt_delta_window, static_argnums=(2,)),
-        state, lo, _DELTA_WINDOW_ROWS)
+        state, lo, DELTA_WINDOW_ROWS)
     text = compiled.as_text()
     assert " gather(" in text and " scatter(" not in text
+
+
+def test_join_ckpt_delta_window_compiles_for_v5e_without_a_scatter(one_chip):
+    """The join's window at ``q8_catchup``'s size: the person side's
+    ``[2^20, 1]`` arena (four columns with their null masks, occupancy,
+    tombstones) read as one flat axis, 8,192 rows a window."""
+    from risingwave_tpu.common import INT64, TIMESTAMP, VARCHAR, Schema
+    from risingwave_tpu.ops.join_state import (
+        JoinCore, JoinType, join_ckpt_delta_window,
+    )
+    from risingwave_tpu.stream.state_delta import DELTA_WINDOW_ROWS
+
+    person = Schema.of(("id", INT64), ("name", VARCHAR),
+                       ("starttime", TIMESTAMP), ("endtime", TIMESTAMP))
+    auction = Schema.of(("seller", INT64), ("starttime", TIMESTAMP),
+                        ("endtime", TIMESTAMP))
+    core = JoinCore(person, auction, [0, 2, 3], [0, 1, 2], JoinType.INNER,
+                    key_capacity=1 << 20, bucket_width=1)
+    side = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(core.init_state).left)
+    assert side.ckpt_dirty.shape == (1 << 20, 1)
+    lo = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile_for(
+        jax.jit(join_ckpt_delta_window, static_argnums=(2,)),
+        side, lo, DELTA_WINDOW_ROWS)
+    text = compiled.as_text()
+    assert " gather(" in text and " scatter(" not in text
+    out = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda s, l: join_ckpt_delta_window(s, l, DELTA_WINDOW_ROWS),
+        side, lo))
+    assert sorted({x.shape for x in out}) == [(), (DELTA_WINDOW_ROWS,)]
+
+
+def test_sharded_ckpt_delta_window_stays_on_its_shards(topo):
+    """The mesh cell's checkpoint window (``q5core_exec_mesh4_catchup``:
+    state ``[4, 2^19]`` sharded on the leading axis) through XLA:TPU for
+    the described 2x2 mesh: the one-chip window under ``vmap`` over the
+    shard axis, every shard searching and gathering its own rows — no
+    collective, no scatter, outputs ``[4, G]`` left where they were made."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from risingwave_tpu.common import INT64
+    from risingwave_tpu.expr.agg import count_star
+    from risingwave_tpu.ops.grouped_agg import AggCore
+    from risingwave_tpu.parallel.sharded_agg import SHARD_AXIS
+    from risingwave_tpu.stream.state_delta import DELTA_WINDOW_ROWS
+
+    n = 4
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    sharded = NamedSharding(mesh, P(SHARD_AXIS))
+    core = AggCore((INT64, INT64), (0, 1), [count_star()],
+                   table_capacity=1 << 19, out_capacity=4096)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded),
+        jax.eval_shape(
+            lambda: jax.vmap(lambda _: core.init_state())(jnp.arange(n))))
+    window = jax.jit(
+        jax.vmap(core.ckpt_delta_window, in_axes=(0, None, None)),
+        static_argnums=(2,))
+    compiled = _compile_for(window, state, np.int32(0), DELTA_WINDOW_ROWS)
+    text = compiled.as_text()
+    assert " gather(" in text and " scatter(" not in text
+    for collective in ("all-gather", "all-to-all", "all-reduce",
+                       "collective-permute"):
+        assert collective not in text
+    assert all(s.spec == P(SHARD_AXIS) for s in
+               jax.tree_util.tree_leaves(compiled.output_shardings))
 
 
 def test_sharded_agg_step_compiles_for_the_four_chip_mesh(topo):

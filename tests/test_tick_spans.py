@@ -686,10 +686,10 @@ Q8_MV = """CREATE MATERIALIZED VIEW q8 AS
 Q8_CHUNKS = 2
 
 
-def open_q8(data_dir) -> Session:
+def open_q8(data_dir, join_key_capacity=1 << 12) -> Session:
     s = Session(config=BuildConfig(chunk_capacity=128,
                                    agg_table_capacity=1 << 12,
-                                   join_key_capacity=1 << 12,
+                                   join_key_capacity=join_key_capacity,
                                    join_bucket_width=1),
                 chunks_per_tick=Q8_CHUNKS, checkpoint_frequency=3,
                 data_dir=data_dir)
@@ -752,7 +752,9 @@ def test_join_spans_and_their_args_on_both_kinds_of_barrier(q8_run, kind):
         assert {parent_of(d) for d in deltas} == {"HashJoin.barrier"}
         for d in deltas:
             assert d["args"]["dirty_rows"] > 0
-            assert d["args"]["bytes_fetched"] > d["args"]["bytes_staged"] > 0
+            assert d["args"]["windows"] >= 1
+            assert d["args"]["bytes_fetched"] > 0
+            assert d["args"]["bytes_staged"] > 0
         assert history[epoch]["stages"]["state_delta"] > 0
     # the left side's dirty rows of a checkpoint are the persons since the
     # one before it
@@ -761,6 +763,31 @@ def test_join_spans_and_their_args_on_both_kinds_of_barrier(q8_run, kind):
                 for d in by_epoch[e] if d["name"] == "join.state_delta"
                 and d["args"]["side"] == "left"]
         assert left == [3 * Q8_CHUNKS * 64] * len(left)
+
+
+def test_join_state_delta_fetches_the_dirty_rows_not_the_arena(tmp_path):
+    """The join's twin of the agg's test above: the same q8 traffic into
+    arenas of 2^13 and 2^16 keys moves the same bytes across the link."""
+    fetched = {}
+    for capacity in (1 << 13, 1 << 16):
+        s = open_q8(str(tmp_path / f"q8_{capacity}"),
+                    join_key_capacity=capacity)
+        try:
+            GLOBAL_TRACE.clear()
+            for _ in range(6):
+                s.tick()
+            fetched[capacity] = [
+                (d["args"]["side"], d["args"]["dirty_rows"],
+                 d["args"]["windows"], d["args"]["bytes_fetched"])
+                for spans in tracing.epoch_spans().values() for d in spans
+                if d["name"] == "join.state_delta"]
+        finally:
+            s.close()
+    small, large = fetched.values()
+    assert [side for side, *_ in small] == ["left", "right"] * 2
+    assert small == large
+    assert all(0 < dirty < 1 << 13 and windows == 1
+               for _side, dirty, windows, _bytes in small)
 
 
 @pytest.mark.parametrize("path", ["exec", "q8"])
