@@ -52,6 +52,12 @@ from .ckpt_delta import delta_window
 from .hash_table import DeviceHashTable, ht_lookup, ht_lookup_or_insert, ht_new
 
 
+#: graveyard slots a side (a smaller arena is its own bound): refills of a
+#: tombstoned lane by another state-table key between two checkpoints;
+#: past it the insert reports ``lane_overflow`` and the bucket width grows
+GRAVE_ROWS = 1 << 13
+
+
 class JoinType(enum.Enum):
     """reference: JoinTypePrimitive consts, src/stream/src/executor/hash_join.rs:83-100."""
 
@@ -94,6 +100,12 @@ class JoinSideState:
     tomb: jax.Array                     # bool[cap, W] — deleted since last ckpt
     degree: jax.Array                   # int32[cap, W] — opposite-side matches
     ckpt_dirty: jax.Array               # bool[cap, W] — changed since last ckpt
+    # rows whose tombstoned lane was refilled by a row of ANOTHER
+    # state-table key since the last checkpoint: the durable tier still owes
+    # their delete (``grave_rows`` slots, the first ``grave_n`` in use)
+    grave_data: tuple[jax.Array, ...]   # per column: dtype[grave_rows]
+    grave_mask: tuple[jax.Array, ...]   # per column: bool[grave_rows]
+    grave_n: jax.Array                  # int32 scalar
     lru: jax.Array                      # int32[cap] — key's last-touch step
     ht_overflow: jax.Array              # bool scalar, sticky: key table full
     lane_overflow: jax.Array            # bool scalar, sticky: bucket width full
@@ -121,7 +133,15 @@ class JoinCore:
         condition=None,
         key_capacity: int = 1 << 13,
         bucket_width: int = 16,
+        state_pks: tuple = (None, None),
     ):
+        """``state_pks``: per side, the columns of the durable state
+        table's key (``()``: the side has no durable tier). An insert may
+        take a lane tombstoned since the last checkpoint; where the old
+        row's state-table key differs from the new row's, the old row is
+        kept in the side's graveyard for the checkpoint to delete. ``None``
+        (the key is not known here) buries every such row: a delete too
+        many is harmless, deletes are staged before puts."""
         self.left_schema = left_schema
         self.right_schema = right_schema
         self.left_keys = tuple(left_keys)
@@ -130,6 +150,8 @@ class JoinCore:
         self.condition = condition
         self.capacity = key_capacity
         self.W = bucket_width
+        self.grave_rows = min(key_capacity * bucket_width, GRAVE_ROWS)
+        self.state_pks = {"left": state_pks[0], "right": state_pks[1]}
         lkt = tuple(left_schema[i].type for i in self.left_keys)
         rkt = tuple(right_schema[i].type for i in self.right_keys)
         assert tuple(t.dtype for t in lkt) == tuple(t.dtype for t in rkt), (
@@ -156,6 +178,11 @@ class JoinCore:
             tomb=jnp.zeros((cap, W), jnp.bool_),
             degree=jnp.zeros((cap, W), jnp.int32),
             ckpt_dirty=jnp.zeros((cap, W), jnp.bool_),
+            grave_data=tuple(jnp.zeros(self.grave_rows, f.type.dtype)
+                             for f in schema),
+            grave_mask=tuple(jnp.zeros(self.grave_rows, jnp.bool_)
+                             for _ in schema),
+            grave_n=jnp.zeros((), jnp.int32),
             lru=jnp.zeros(cap, jnp.int32),
             ht_overflow=jnp.zeros((), jnp.bool_),
             lane_overflow=jnp.zeros((), jnp.bool_),
@@ -206,6 +233,21 @@ class JoinCore:
         )
         return state, StreamChunk(ops, vis, cols)
 
+    def emit_counts(self, big: StreamChunk) -> tuple:
+        """``(rows_out, null_padded_out, transitions)`` of one step's
+        emission grid, as int64 scalars: the visible rows; those on the
+        ``pself`` lane (lane 2W: the input row NULL-padded, or a semi /
+        anti join's own row); and the degree transitions 0 -> 1 / 1 -> 0
+        of the opposite side that the step emitted — the second rows of
+        an outer join's update pairs (lanes 2w+1), a semi / anti join's
+        opposite rows (lanes 2w; its own-side passes leave them empty)."""
+        W = self.W
+        vis = big.vis.reshape(-1, 2 * W + 1)
+        first = 1 if self.join_type.semi_anti_side is None else 0
+        return (jnp.sum(vis, dtype=jnp.int64),
+                jnp.sum(vis[:, 2 * W], dtype=jnp.int64),
+                jnp.sum(vis[:, first:2 * W:2], dtype=jnp.int64))
+
     # -- internals -------------------------------------------------------------
 
     def _empty_out(self, N: int):
@@ -255,7 +297,7 @@ class JoinCore:
                                     is_insert, side, step)
         with jax.named_scope("join_insert" if is_insert else "join_delete"):
             A = self._update_own(A, chunk, a_key_cols, sel, is_insert,
-                                 probed[1], step)
+                                 probed[1], step, self.state_pks[side])
         state = (state.replace(left=A, right=B) if side == "left"
                  else state.replace(left=B, right=A))
         with jax.named_scope("join_emit"):
@@ -302,10 +344,15 @@ class JoinCore:
         return B, (matches, c_cnt, r, t, d0, b_datas, b_masks)
 
     def _update_own(self, A: JoinSideState, chunk: StreamChunk, a_key_cols,
-                    sel, is_insert: bool, c_cnt, step) -> JoinSideState:
+                    sel, is_insert: bool, c_cnt, step,
+                    state_pk=None) -> JoinSideState:
         """The input side's arena: place the inserted rows (each with its
         degree ``c_cnt``, the matches the probe found), or tombstone the
-        deleted ones."""
+        deleted ones. An insert takes a free lane of its key's bucket
+        and, when those run out, a lane tombstoned since the last
+        checkpoint (the U+ of an update pair lands where its U- was): the
+        lane is then BOTH occupied and tombstoned, which the checkpoint
+        reads as a put."""
         cap, W = self.capacity, self.W
         N = chunk.capacity
         idx = jnp.arange(N)
@@ -317,12 +364,20 @@ class JoinCore:
             alower = ((aident[:, None] == aident[None, :])
                       & (aident >= 0)[:, None] & (idx[None, :] < idx[:, None]))
             a_rank = jnp.sum(alower, axis=1).astype(jnp.int32)
-            free = ~(A.occupied | A.tomb)[as_]                         # [N, W]
-            cs = jnp.cumsum(free, axis=1)
-            hit = (cs == (a_rank + 1)[:, None]) & free
+            # one gather for both marks: bit 0 occupied, bit 1 tombstoned
+            marks = (A.occupied.astype(jnp.int8)
+                     | (A.tomb.astype(jnp.int8) << 1))[as_]            # [N, W]
+            dead = marks == 2
+            free = marks == 0
+            want = (a_rank + 1)[:, None]
+            n_free = jnp.sum(free, axis=1, dtype=jnp.int32)[:, None]
+            hit_dead = (jnp.cumsum(dead, axis=1) == want - n_free) & dead
+            hit = ((jnp.cumsum(free, axis=1) == want) & free) | hit_dead
             lane = jnp.argmax(hit, axis=1).astype(jnp.int32)
             lane_ok = jnp.any(hit, axis=1) & a_ok
             f = jnp.where(lane_ok, as_ * W + lane, cap * W)
+            A, grave_full = self._bury_refilled(
+                A, chunk, f, lane_ok & jnp.any(hit_dead, axis=1), state_pk)
             A = A.replace(
                 ht=a_ht,
                 occupied=A.occupied.reshape(-1).at[f].set(True, mode="drop")
@@ -339,7 +394,8 @@ class JoinCore:
                             .reshape(cap, W),
                 ht_overflow=A.ht_overflow | ht_ovf
                             | jnp.any(sel & (a_slot >= cap)),
-                lane_overflow=A.lane_overflow | jnp.any(a_ok & ~lane_ok),
+                lane_overflow=A.lane_overflow | jnp.any(a_ok & ~lane_ok)
+                              | grave_full,
             )
             if step is not None:
                 A = A.replace(lru=A.lru.at[jnp.where(a_ok, a_slot, cap)]
@@ -380,6 +436,51 @@ class JoinCore:
                 A = A.replace(lru=A.lru.at[jnp.where(a_found, a_slot, cap)]
                               .max(step, mode="drop"))
         return A
+
+    def _bury_refilled(self, A: JoinSideState, chunk: StreamChunk, f,
+                       refill, state_pk):
+        """Before the rows of ``chunk`` overwrite the tombstoned lanes
+        ``f[refill]``: copy those lanes' old rows into the graveyard where
+        the durable tier would otherwise keep them — where their
+        state-table key is not the new row's. Returns the side and whether
+        the graveyard ran out of slots (the caller's ``lane_overflow``).
+        A chunk that refills nothing (every insert-only stream) pays one
+        ``any`` and a skipped branch."""
+        if state_pk is not None and not len(state_pk):
+            return A, jnp.zeros((), jnp.bool_)     # no durable tier
+        G = self.grave_rows
+
+        def bury(grave):
+            at = jnp.where(refill, f, 0)
+            same_pk = jnp.full(refill.shape, state_pk is not None)
+            for i in (state_pk or ()):
+                c = chunk.columns[i]
+                old_d = A.row_data[i].reshape(-1)[at]
+                old_m = A.row_mask[i].reshape(-1)[at]
+                same_pk = same_pk & ((old_m & c.mask & (old_d == c.data))
+                                     | (~old_m & ~c.mask))
+            owed = refill & ~same_pk
+            pos = A.grave_n + jnp.cumsum(owed, dtype=jnp.int32) - 1
+            g = jnp.where(owed & (pos < G), pos, G)
+
+            def write(grave):
+                gd, gm = grave
+                return (tuple(d.at[g].set(rd.reshape(-1)[at], mode="drop")
+                              for d, rd in zip(gd, A.row_data)),
+                        tuple(m.at[g].set(rm.reshape(-1)[at], mode="drop")
+                              for m, rm in zip(gm, A.row_mask)))
+
+            n_owed = jnp.sum(owed, dtype=jnp.int32)
+            return (*jax.lax.cond(n_owed > 0, write, lambda gr: gr, grave),
+                    n_owed)
+
+        gd, gm, n_owed = jax.lax.cond(
+            jnp.any(refill), bury,
+            lambda grave: (*grave, jnp.zeros((), jnp.int32)),
+            (A.grave_data, A.grave_mask))
+        return (A.replace(grave_data=gd, grave_mask=gm,
+                          grave_n=jnp.minimum(A.grave_n + n_owed, G)),
+                A.grave_n + n_owed > G)
 
     def _emit(self, chunk, sel, is_insert: bool, side: str, matches, c_cnt,
               r, t, d0, b_datas, b_masks):
@@ -495,15 +596,24 @@ def clean_side_below(st: JoinSideState, col_idx: int, threshold) -> JoinSideStat
 
 @jax.named_scope("ckpt_delta")
 def join_ckpt_delta_window(st: JoinSideState, lo: jax.Array, G: int):
-    """One side's checkpoint delta for dirty ranks [lo, lo+G) of its
-    ``[capacity, W]`` arena read row-major (slot, then lane): ``(n_dirty,
+    """One side's checkpoint delta for dirty ranks [lo, lo+G): ``(n_dirty,
     valid[G], occupied, tomb, row_data, row_mask)``, each column gathered
-    to ``G`` rows (``ckpt_delta.delta_window``). Degrees, LRU stamps and
-    the key table are not persisted: recovery rebuilds them."""
-    n_dirty, valid, cols = delta_window(
+    to ``G`` rows. The dirty lanes of the ``[capacity, W]`` arena come
+    first, read row-major (slot, then lane; ``ckpt_delta.delta_window``);
+    the graveyard's rows follow them as tombstones. Degrees, LRU stamps
+    and the key table are not persisted: recovery rebuilds them."""
+    n_arena, valid, (occ, tomb, datas, masks) = delta_window(
         st.ckpt_dirty, (st.occupied, st.tomb, st.row_data, st.row_mask),
         lo, G)
-    return (n_dirty, valid, *cols)
+    k = lo.astype(jnp.int32) + jnp.arange(G, dtype=jnp.int32) - n_arena
+    buried = (k >= 0) & (k < st.grave_n)
+    at = jnp.clip(k, 0, st.grave_mask[0].shape[0] - 1)
+    return (n_arena + st.grave_n, valid | buried, occ & ~buried,
+            tomb | buried,
+            tuple(jnp.where(buried, g[at], d)
+                  for g, d in zip(st.grave_data, datas)),
+            tuple(jnp.where(buried, g[at], m)
+                  for g, m in zip(st.grave_mask, masks)))
 
 
 def compact_side(core: "JoinCore", old: JoinSideState, schema: Schema,
@@ -534,6 +644,8 @@ def compact_side(core: "JoinCore", old: JoinSideState, schema: Schema,
         tomb=move(old.tomb, False),
         degree=move(old.degree, 0),
         ckpt_dirty=move(old.ckpt_dirty, False),
+        grave_data=old.grave_data, grave_mask=old.grave_mask,
+        grave_n=old.grave_n,
         lru=jnp.zeros(cap, jnp.int32).at[dst].set(old.lru, mode="drop"),
         # a key that exhausts probing during rebuild would silently drop its
         # whole bucket via mode="drop" — surface it
@@ -641,12 +753,22 @@ def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
     degree = pad(old.degree, 0)
     ckpt_dirty = pad(old.ckpt_dirty)
 
+    # the graveyard is no arena: it keeps its rows, in a buffer of the new
+    # geometry's size
+    def longer(a):
+        return jnp.zeros(core.grave_rows, a.dtype).at[:a.shape[0]].set(a)
+
+    grave = dict(grave_data=tuple(longer(g) for g in old.grave_data),
+                 grave_mask=tuple(longer(g) for g in old.grave_mask),
+                 grave_n=old.grave_n)
+
     key_types = tuple(schema[i].type for i in key_idx)
     if cap == old_cap:
         ht = old.ht
         new = JoinSideState(
             ht=ht, row_data=row_data, row_mask=row_mask, occupied=occupied,
             tomb=tomb, degree=degree, ckpt_dirty=ckpt_dirty, lru=old.lru,
+            **grave,
             ht_overflow=jnp.zeros((), jnp.bool_),
             lane_overflow=jnp.zeros((), jnp.bool_),
             inconsistent=old.inconsistent,
@@ -674,6 +796,7 @@ def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
         tomb=move(tomb, False),
         degree=move(degree, 0),
         ckpt_dirty=move(ckpt_dirty, False),
+        **grave,
         lru=jnp.zeros(cap, jnp.int32).at[dst].set(old.lru, mode="drop"),
         ht_overflow=jnp.zeros((), jnp.bool_),
         lane_overflow=jnp.zeros((), jnp.bool_),

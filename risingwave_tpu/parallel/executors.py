@@ -500,6 +500,13 @@ class ShardedHashJoinExecutor(Executor):
             # clobber the new-shard upsert (StateTable.delete is pk-keyed)
             deletes, inserts = [], []
             for sh in range(self.n):
+                # rows a refilled lane overwrote (JoinCore's graveyard)
+                buried = int(side_st.grave_n[sh])
+                deletes.extend(
+                    tuple(d[sh, i].item() if m[sh, i] else None
+                          for d, m in zip(side_st.grave_data,
+                                          side_st.grave_mask))
+                    for i in range(buried))
                 dirty = np.asarray(side_st.ckpt_dirty[sh])
                 slots, lanes = np.nonzero(dirty)
                 if not len(slots):

@@ -101,17 +101,22 @@ class HashJoinExecutor(Executor):
             join_type == JoinType.LEFT_ANTI
         from .metrics import ExecutorStats
         self.stats = ExecutorStats()
-        self._join_args = dict(join_type=join_type, condition=condition)
+        # an insert may refill a lane tombstoned since the last checkpoint;
+        # the core compares state-table keys to know whether the old row's
+        # delete is still owed (no table: nothing durable to delete)
+        self._join_args = dict(
+            join_type=join_type, condition=condition,
+            state_pks=tuple(() if t is None else tuple(t.pk_indices)
+                            for t in (left_state_table, right_state_table)))
         self._key_args = (left_keys, right_keys)
         self.interval_clean = tuple(interval_clean)
         self._pending_clean: dict[tuple[str, int], int] = {}
         # max threshold ever applied per (side, col) — the fault-in filter
         self._applied_clean: dict[tuple[str, int], int] = {}
         self.core = JoinCore(
-            left.schema, right.schema, left_keys, right_keys, join_type,
-            condition=condition, key_capacity=key_capacity,
-            bucket_width=bucket_width,
-        )
+            left.schema, right.schema, left_keys, right_keys,
+            key_capacity=key_capacity, bucket_width=bucket_width,
+            **self._join_args)
         self.schema = self.core.out_schema
         self.out_capacity = out_capacity
         # chunks applied per host sync (optimistic batched emission)
@@ -139,7 +144,8 @@ class HashJoinExecutor(Executor):
         self.state = self.core.init_state()
         # what HashJoin.chunks reports for the epoch, reset at its barrier
         self._epoch_counts = {"rows_in_left": 0, "rows_in_right": 0,
-                              "rewinds": 0, "grows": 0}
+                              "rows_out": 0, "null_padded_out": 0,
+                              "transitions": 0, "rewinds": 0, "grows": 0}
         self._make_jits()
         if any(self.state_tables.values()):
             self._load_from_state_tables()
@@ -170,7 +176,7 @@ class HashJoinExecutor(Executor):
             def body(st, x):
                 ch, step = x if steps is not None else (x, None)
                 st, big = core.apply_chunk(st, ch, side=side, step=step)
-                return st, (join_pack_stats(st, big, ch), big)
+                return st, (pack_stats(core, _flags(st), big, ch), big)
 
             xs = (batched_chunk, steps) if steps is not None \
                 else batched_chunk
@@ -198,9 +204,12 @@ class HashJoinExecutor(Executor):
             return gather_units_window(ch, lo, self.out_capacity)
 
         self._gather = jax.jit(join_gather)
-        self._count_units = jax.jit(count_units)
+
+        def join_pack_stats(flags, big, ch):
+            return pack_stats(core, flags, big, ch)
 
         self._pack_stats = jax.jit(join_pack_stats)
+        self._no_stats = jnp.zeros(9, jnp.int64)      # pads a stats fetch
         self._clear_ckpt = jax.jit(_clear_ckpt_marks)
         # reads a side and leaves it in place (not donated); a device
         # trace shows it as jit_join_ckpt_delta_window
@@ -279,29 +288,47 @@ class HashJoinExecutor(Executor):
                   parent="barrier.collect", tid=self.identity):
             return np.asarray(packed)
 
+    def _count(self, side: str, rows: np.ndarray) -> None:
+        """Add the packed stats of applied chunks (``[k, 9]``) to what
+        ``HashJoin.chunks`` reports for the epoch."""
+        counts = self._epoch_counts
+        counts[f"rows_in_{side}"] += int(rows[:, 5].sum())
+        for i, name in enumerate(
+                ("rows_out", "null_padded_out", "transitions"), 6):
+            counts[name] += int(rows[:, i].sum())
+
+    def _gather_units(self, big, n_units: int):
+        for lo in range(0, n_units, self.out_capacity // 2):
+            self.stats.chunks_out += 1
+            yield self._gather(big, jnp.int64(lo))
+
+    def _replay_growing(self, side: str, chunk: StreamChunk):
+        """One chunk of a rewound batch again, through the growing path."""
+        big = self._apply_growing(side, chunk)
+        row = np.asarray(self._pack_stats(_flags(self.state), big, chunk))
+        self._count(side, row[None])
+        yield from self._gather_units(big, int(row[4]))
+
     def _flush_pending(self):
         if not self._pending:
             return
-        stats = self.stats
-        packed = self._fetch_stats(jnp.stack([p[2] for p in self._pending]))
-        for (side, _, _, _), row in zip(self._pending, packed):
-            self._epoch_counts[f"rows_in_{side}"] += int(row[5])
+        # always ``emit_batch`` vectors, so that ONE stack program serves
+        # every count of pending chunks (a flush of one chunk more than
+        # any barrier before it compiled inside that barrier)
+        k = len(self._pending)
+        packed = self._fetch_stats(jnp.stack(
+            [p[2] for p in self._pending]
+            + [self._no_stats] * (self.emit_batch - k)))[:k]
         if not packed[:, :4].any():
-            for (side, chunk, _, big), row in zip(self._pending, packed):
-                n_units = int(row[4])
-                for lo in range(0, n_units, self.out_capacity // 2):
-                    stats.chunks_out += 1
-                    yield self._gather(big, jnp.int64(lo))
+            for (side, _, _, big), row in zip(self._pending, packed):
+                self._count(side, row[None])
+                yield from self._gather_units(big, int(row[4]))
         else:
             # overflow inside the batch: rewind and replay with growth
             self.state = self._rewind_state
             self._epoch_counts["rewinds"] += 1
             for side, chunk, _, _ in self._pending:
-                big = self._apply_growing(side, chunk)
-                n_units = int(self._count_units(big))
-                for lo in range(0, n_units, self.out_capacity // 2):
-                    stats.chunks_out += 1
-                    yield self._gather(big, jnp.int64(lo))
+                yield from self._replay_growing(side, chunk)
         self._pending.clear()
         self._rewind_state = None
 
@@ -332,8 +359,8 @@ class HashJoinExecutor(Executor):
             self.state, sub_chunk, steps)
         self.state = new_state
         rows = self._fetch_stats(packed)      # ONE transfer for k chunks
-        self._epoch_counts[f"rows_in_{side}"] += int(rows[:, 5].sum())
         if not rows[:, :4].any():
+            self._count(side, rows)
             for kk in range(k):
                 n_units = int(rows[kk, 4])
                 for lo in range(0, n_units, self.out_capacity // 2):
@@ -348,11 +375,7 @@ class HashJoinExecutor(Executor):
             self._epoch_counts["rewinds"] += 1
             for kk in range(k):
                 ch = jax.tree_util.tree_map(lambda x: x[kk], sub_chunk)
-                big = self._apply_growing(side, ch)
-                n_units = int(self._count_units(big))
-                for lo in range(0, n_units, self.out_capacity // 2):
-                    stats.chunks_out += 1
-                    yield self._gather(big, jnp.int64(lo))
+                yield from self._replay_growing(side, ch)
 
     async def execute(self):
         from ..common.tracing import now_ns
@@ -400,8 +423,8 @@ class HashJoinExecutor(Executor):
                                                    self._lru())
                 self.state = new_state
                 self._pending.append(
-                    (side, chunk, self._pack_stats(new_state, big, chunk),
-                     big))
+                    (side, chunk,
+                     self._pack_stats(_flags(new_state), big, chunk), big))
                 clock.add(t_chunk)
                 if len(self._pending) >= self.emit_batch:
                     for out in clock.timed(self._flush_pending()):
@@ -412,7 +435,7 @@ class HashJoinExecutor(Executor):
                     yield out
                 clock.emit(self.identity, barrier.epoch.curr,
                            chunks_out=stats.chunks_out - chunks_out,
-                           **self._epoch_counts)
+                           bucket_width=self.core.W, **self._epoch_counts)
                 chunks_out = stats.chunks_out
                 self._epoch_counts = dict.fromkeys(self._epoch_counts, 0)
                 with barrier_timer(stats, self.identity, barrier.epoch.curr):
@@ -705,16 +728,24 @@ class HashJoinExecutor(Executor):
         return out
 
 
-def join_pack_stats(state: JoinState, big, chunk) -> jax.Array:
+def _flags(state: JoinState) -> tuple:
+    """The four overflow flags ``pack_stats`` reads: handing the stats
+    program these and not the whole state keeps its dispatch at a few
+    arguments (a state is some sixty device buffers)."""
+    return (state.left.lane_overflow, state.left.ht_overflow,
+            state.right.lane_overflow, state.right.ht_overflow)
+
+
+def pack_stats(core: JoinCore, flags: tuple, big, chunk) -> jax.Array:
     """Every host-read scalar of one applied chunk in ONE vector:
-    [l.lane_ovf, l.ht_ovf, r.lane_ovf, r.ht_ovf, n_units, rows_in]."""
+    [l.lane_ovf, l.ht_ovf, r.lane_ovf, r.ht_ovf, n_units, rows_in,
+    rows_out, null_padded_out, transitions] (the last three:
+    ``JoinCore.emit_counts``)."""
     return jnp.stack([
-        state.left.lane_overflow.astype(jnp.int64),
-        state.left.ht_overflow.astype(jnp.int64),
-        state.right.lane_overflow.astype(jnp.int64),
-        state.right.ht_overflow.astype(jnp.int64),
+        *(f.astype(jnp.int64) for f in flags),
         count_units(big),
         jnp.sum(chunk.vis, dtype=jnp.int64),
+        *core.emit_counts(big),
     ])
 
 
@@ -723,6 +754,7 @@ def _clear_ckpt_marks(state: JoinState) -> JoinState:
         return st.replace(
             ckpt_dirty=jnp.zeros_like(st.ckpt_dirty),
             tomb=jnp.zeros_like(st.tomb),
+            grave_n=jnp.zeros_like(st.grave_n),
         )
     return state.replace(left=clear(state.left), right=clear(state.right))
 

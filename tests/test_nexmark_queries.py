@@ -219,3 +219,60 @@ def test_q8_new_users():
         {(pid, name, ws) for (pid, name, ws) in p_windows
          if (pid, ws) in a_windows})
     assert got == exp and len(got) > 0
+
+
+def test_q101_highest_bid_outer():
+    """RisingWave's own nexmark q101 (e2e_test/streaming/nexmark/views/
+    q101.slt.part): every auction with its current highest bid, NULL where
+    it has none. Checked after EVERY barrier across a checkpoint: the
+    outer join has to retract a NULL-padded row when the first bid on its
+    auction arrives, and replace a maximum a later barrier raises."""
+    bid_rows, auction_rows, ticks = 400, 24, 14     # 8 auction epochs a chunk
+    s = Session(chunks_per_tick=1, checkpoint_frequency=5)
+    s.run_sql(f"""
+        CREATE SOURCE bid (auction BIGINT, bidder BIGINT, price BIGINT,
+          channel VARCHAR, url VARCHAR, date_time TIMESTAMP, extra VARCHAR)
+        WITH (connector = 'nexmark', nexmark_table = 'bid',
+              rows_per_chunk = {bid_rows});
+        CREATE SOURCE auction (id BIGINT, item_name VARCHAR,
+          description VARCHAR, initial_bid BIGINT, reserve BIGINT,
+          date_time TIMESTAMP, expires TIMESTAMP, seller BIGINT,
+          category BIGINT, extra VARCHAR)
+        WITH (connector = 'nexmark', nexmark_table = 'auction',
+              rows_per_chunk = {auction_rows})""")
+    s.run_sql("""CREATE MATERIALIZED VIEW nexmark_q101 AS
+        SELECT a.id AS auction_id, a.item_name AS auction_item_name,
+               b.max_price AS current_highest_bid
+        FROM auction a
+        LEFT OUTER JOIN (
+            SELECT b1.auction, MAX(b1.price) max_price
+            FROM bid b1 GROUP BY b1.auction
+        ) b ON a.id = b.auction""")
+
+    def streams(table, rows, schema):
+        gen = NexmarkGenerator(NexmarkConfig(chunk_capacity=rows), seed=42)
+        fn = gen.next_bid_chunk if table == "bid" else gen.next_auction_chunk
+        return [chunk_to_rows(fn(), schema) for _ in range(ticks)]
+
+    bids = streams("bid", bid_rows, BID_SCHEMA)
+    auctions = streams("auction", auction_rows, AUCTION_SCHEMA)
+    items, best = {}, {}
+    went_from_null = replaced = 0
+    for t in range(ticks):
+        s.tick()
+        before, padded = dict(best), set(items)   # padded: in the MV already
+        for a in auctions[t]:
+            items[a[0]] = a[1]
+        for b in bids[t]:
+            best[b[0]] = max(best.get(b[0], 0), b[2])
+        went_from_null += sum(1 for a in best
+                              if a not in before and a in padded)
+        replaced += sum(1 for a, p in before.items() if best[a] > p)
+        exp = sorted((a, item, best.get(a)) for a, item in items.items())
+        got = sorted(s.mv_rows("nexmark_q101"),
+                     key=lambda r: r[0])
+        assert got == exp, f"barrier {t + 1}"
+    assert s.epoch > 2 * s.checkpoint_frequency
+    assert 0 < sum(r[2] is None for r in got) < len(got)
+    assert went_from_null > 0        # a NULL-padded row was retracted
+    assert replaced > 0              # an update pair reached the join

@@ -70,10 +70,12 @@ def _compile_for(fn, *shapes):
         return fn.lower(*shapes).compile()
 
 
-@pytest.mark.parametrize("n,w", [(4096, 128), (1024, 16)])
+@pytest.mark.parametrize("n,w", [(4096, 128), (1024, 16), (1024, 1), (768, 1)])
 def test_rank_kernel_compiles_for_v5e(one_chip, n, w):
-    """Mosaic accepts the rank kernel at the bench shape and at the SQL
-    defaults (chunk_capacity 1024 × join_bucket_width 16)."""
+    """Mosaic accepts the rank kernel at the bench shape, at the SQL
+    defaults (chunk_capacity 1024 × join_bucket_width 16) and at the two
+    join inputs of the benchmark's nexmark-q101 (1,024-row flush chunks
+    and 768-row auction chunks at join_bucket_width 1)."""
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     compiled = _compile_for(

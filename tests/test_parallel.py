@@ -225,3 +225,60 @@ def test_sharded_mv_checkpoint_recovery(mesh):
     got = {k[0]: (v[1], v[2])
            for k, v in ex2.agg.merged_group_values().items()}
     assert got == expected
+
+
+def test_sharded_join_update_pairs_refill_at_width_one(mesh):
+    """The mesh join shares ``JoinCore``'s lane refill (ISSUE 33) and does
+    not know its state tables' keys, so every refilled tombstone is
+    buried: its checkpoint has to stage the graveyard's deletes before
+    the puts. Update pairs on a side unique on the join key, bucket width
+    1, the state table keyed ``(k, v)``: no growth, and the durable rows
+    are the live rows at each checkpoint."""
+    import asyncio
+
+    from risingwave_tpu.common.chunk import OP_UPDATE_DELETE, OP_UPDATE_INSERT
+    from risingwave_tpu.parallel.executors import ShardedHashJoinExecutor
+    from risingwave_tpu.storage.state_store import MemoryStateStore
+    from risingwave_tpu.storage.state_table import StateTable
+    from risingwave_tpu.stream.message import Barrier, Mutation, MutationKind
+    from risingwave_tpu.stream.source import MockSource
+
+    keys = list(range(1, 25))
+    U_, UP = OP_UPDATE_DELETE, OP_UPDATE_INSERT
+
+    def pairs(old, new):
+        rows, ops = [], []
+        for k in keys[::2]:
+            rows += [(k, old + k), (k, new + k)]
+            ops += [U_, UP]
+        return make_chunk(SCHEMA2, rows, ops=ops, capacity=64)
+
+    left = [Barrier.new(1), make_chunk(SCHEMA2, [(k, k) for k in keys],
+                                       capacity=64)]
+    right = [Barrier.new(1), make_chunk(SCHEMA2, [(k, 100 + k) for k in keys],
+                                        capacity=64)]
+    stop = Mutation(MutationKind.STOP)
+    for e, chunk in ((2, pairs(100, 200)), (3, pairs(200, 300)),
+                     (4, pairs(300, 400))):
+        right.append(chunk)
+        for side in (left, right):
+            side.append(Barrier.new(e, checkpoint=e != 3,
+                                    mutation=stop if e == 4 else None))
+    store = MemoryStateStore()
+    lt = StateTable(store, 1, SCHEMA2, [0, 1])
+    rt = StateTable(store, 2, SCHEMA2, [0, 1])
+    ex = ShardedHashJoinExecutor(
+        MockSource(SCHEMA2, left), MockSource(SCHEMA2, right), mesh, [0], [0],
+        JoinType.LEFT_OUTER, left_state_table=lt, right_state_table=rt,
+        key_capacity=64, bucket_width=1, out_capacity=64)
+
+    async def drain():
+        async for _ in ex.execute():
+            pass
+
+    asyncio.run(drain())
+    store.commit(4)
+    assert ex.join.core.W == 1, "the join grew"
+    want = sorted((k, (400 if k in keys[::2] else 100) + k) for k in keys)
+    assert sorted(rt.scan_all()) == want
+    assert sorted(lt.scan_all()) == [(k, k) for k in keys]
