@@ -13,8 +13,10 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import operator
 import os
 import subprocess
+from itertools import compress, repeat
 from typing import Optional, Sequence
 
 import numpy as np
@@ -24,6 +26,9 @@ _SRC = os.path.join(_DIR, "rowcodec.cpp")
 
 _lib = None
 _tried = False
+
+_LL_P = ctypes.POINTER(ctypes.c_longlong)
+_UB_P = ctypes.POINTER(ctypes.c_ubyte)
 
 # DataType.kind -> native type code (rowcodec.cpp header comment)
 _CODE_BY_KIND = {
@@ -63,7 +68,12 @@ def _build() -> Optional[ctypes.CDLL]:
         ctypes.c_longlong,
         ctypes.POINTER(ctypes.c_longlong),
     ]
-    if lib.rw_abi_version() != 1:
+    lib.rw_encode_segment_table.restype = ctypes.c_longlong
+    lib.rw_encode_segment_table.argtypes = [
+        ctypes.c_char_p, _LL_P, ctypes.c_char_p, _LL_P,
+        _UB_P, ctypes.c_longlong, _UB_P, ctypes.c_longlong,
+    ]
+    if lib.rw_abi_version() != 2:
         return None
     return lib
 
@@ -190,3 +200,43 @@ class RowCodec:
         """Columnar buffers -> memcomparable key bytes per selected row
         (byte-identical to common/row.py encode_key)."""
         return self._encode(1, datas, masks, types, indices)
+
+    def encode_segment_table(self, buf: dict) -> Optional[np.ndarray]:
+        """One table's delta ``{key: value | None}`` -> its rows as a
+        checkpoint segment lays them out (byte-identical to the row loop of
+        storage/checkpoint.py ``_encode_segment_py``: ordered by key,
+        ``<H klen> key 0x00`` for a tombstone, ``<H klen> key 0x01 <I vlen>
+        value`` for a put), or None where a length does not fit the layout.
+        The dict is taken apart with C-speed calls only: nothing here runs
+        once a row in Python."""
+        n = len(buf)
+        if n == 0:
+            return np.empty(0, np.uint8)
+        keys = list(buf)
+        vals = list(buf.values())
+        klens = np.fromiter(map(len, keys), np.int64, count=n)
+        n_live = n - vals.count(None)
+        if n_live == n:
+            live = np.ones(n, np.uint8)
+            vlens = np.fromiter(map(len, vals), np.int64, count=n)
+        else:
+            live = np.fromiter(map(operator.is_not, vals, repeat(None)),
+                               np.uint8, count=n)
+            vals = list(compress(vals, live.tolist()))
+            vlens = np.zeros(n, np.int64)
+            vlens[live.view(np.bool_)] = np.fromiter(
+                map(len, vals), np.int64, count=n_live)
+        if klens.max() > 0xFFFF or vlens.max() > 0xFFFFFFFF:
+            return None
+        size = 3 * n + int(klens.sum()) + 4 * n_live + int(vlens.sum())
+        out = np.empty(size, np.uint8)
+        key_blob, val_blob = b"".join(keys), b"".join(vals)
+        written = self.lib.rw_encode_segment_table(
+            key_blob, klens.ctypes.data_as(_LL_P),
+            val_blob, vlens.ctypes.data_as(_LL_P),
+            live.ctypes.data_as(_UB_P), n,
+            out.ctypes.data_as(_UB_P), size)
+        if written != size:
+            raise RuntimeError(
+                f"native segment encode: wrote {written} of {size} bytes")
+        return out

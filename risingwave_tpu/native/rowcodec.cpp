@@ -20,9 +20,16 @@
 // Type codes: 0=bool(u8), 1=int16, 2=int32, 3=int64, 4=float32,
 //             5=float64, 6=string (data = int64 uniq index per row;
 //             blob/offsets give the uniq string table).
+//
+// Segment table block (storage/checkpoint.py _encode_segment): a table's
+// rows ordered by key as Python's bytes compare (memcmp, the shorter first
+// on a tie), each  u16 LE klen + key + 0x00  (tombstone)  or
+// u16 LE klen + key + 0x01 + u32 LE vlen + value  (put).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <vector>
 
 namespace {
 
@@ -194,6 +201,74 @@ inline long long encode_rows(bool key_mode, int ncols, const ColView* cols,
     return pos;
 }
 
+// A key of a segment table, with its first 8 bytes as a big-endian word
+// (zero-padded) so that most comparisons never touch the key's memory.
+struct SegKey {
+    uint64_t prefix;
+    const unsigned char* p;
+    uint32_t len;
+    uint32_t row;
+};
+
+inline bool seg_key_less(const SegKey& a, const SegKey& b) {
+    if (a.prefix != b.prefix) return a.prefix < b.prefix;
+    uint32_t m = a.len < b.len ? a.len : b.len;
+    int c = m ? std::memcmp(a.p, b.p, m) : 0;
+    if (c != 0) return c < 0;
+    return a.len < b.len;
+}
+
+inline long long encode_segment_table(
+        const unsigned char* keys, const long long* key_lens,
+        const unsigned char* vals, const long long* val_lens,
+        const unsigned char* live, long long n,
+        unsigned char* out, long long out_cap) {
+    if (n > 0xFFFFFFFFLL) return -2;
+    std::vector<SegKey> order((size_t)n);
+    std::vector<long long> val_off((size_t)n);
+    long long kpos = 0, vpos = 0, need = 0;
+    for (long long r = 0; r < n; ++r) {
+        long long kl = key_lens[r];
+        long long vl = live[r] ? val_lens[r] : 0;
+        if (kl < 0 || kl > 0xFFFF || vl < 0 || vl > 0xFFFFFFFFLL) return -2;
+        SegKey& k = order[(size_t)r];
+        k.p = keys + kpos;
+        k.len = (uint32_t)kl;
+        k.row = (uint32_t)r;
+        uint64_t prefix = 0;
+        for (int i = 0; i < 8; ++i) {
+            prefix = (prefix << 8) | (i < kl ? k.p[i] : 0);
+        }
+        k.prefix = prefix;
+        val_off[(size_t)r] = vpos;
+        kpos += kl;
+        vpos += vl;
+        need += 2 + kl + 1 + (live[r] ? 4 + vl : 0);
+    }
+    if (need > out_cap) return -1;
+    std::sort(order.begin(), order.end(), seg_key_less);
+    long long pos = 0;
+    for (const SegKey& k : order) {
+        out[pos++] = (unsigned char)(k.len & 0xff);
+        out[pos++] = (unsigned char)(k.len >> 8);
+        std::memcpy(out + pos, k.p, k.len);
+        pos += k.len;
+        if (!live[k.row]) {
+            out[pos++] = 0x00;
+            continue;
+        }
+        out[pos++] = 0x01;
+        uint32_t vl = (uint32_t)val_lens[k.row];
+        out[pos++] = (unsigned char)(vl & 0xff);
+        out[pos++] = (unsigned char)((vl >> 8) & 0xff);
+        out[pos++] = (unsigned char)((vl >> 16) & 0xff);
+        out[pos++] = (unsigned char)(vl >> 24);
+        std::memcpy(out + pos, vals + val_off[k.row], vl);
+        pos += vl;
+    }
+    return pos;
+}
+
 }  // namespace
 
 extern "C" {
@@ -221,6 +296,25 @@ long long rw_encode(int key_mode, int ncols, const int* typecodes,
                        out_offsets);
 }
 
-int rw_abi_version() { return 1; }
+// One table's rows of a checkpoint segment. keys / vals are the rows' keys
+// and values back to back in the caller's order, key_lens / val_lens their
+// lengths (a tombstone's value length is not read), live[r] == 0 marks a
+// tombstone. Returns the bytes written; -1 if out_cap is too small; -2 if
+// a length does not fit the layout (key > 65,535 bytes, value > 4 GiB - 1);
+// -3 if memory ran out.
+long long rw_encode_segment_table(
+        const unsigned char* keys, const long long* key_lens,
+        const unsigned char* vals, const long long* val_lens,
+        const unsigned char* live, long long n,
+        unsigned char* out, long long out_cap) {
+    try {
+        return encode_segment_table(keys, key_lens, vals, val_lens, live, n,
+                                    out, out_cap);
+    } catch (...) {
+        return -3;
+    }
+}
+
+int rw_abi_version() { return 2; }
 
 }  // extern "C"

@@ -51,6 +51,14 @@ _MANIFEST = "manifest.json"
 PLAN_FORMAT_VERSION = 3
 
 
+def _segment_counts(rows: int = 0, nbytes: int = 0,
+                    native: bool = False) -> dict:
+    """The args a storage span carries of the segment it wrote: its rows,
+    its length and whether the native codec laid it out (0 / 0 / 0 for an
+    epoch that wrote none)."""
+    return {"rows": rows, "bytes": nbytes, "native": int(native)}
+
+
 class CheckpointLog:
     def __init__(self, data_dir: Optional[str] = None,
                  object_store: Optional[ObjectStore] = None,
@@ -119,8 +127,11 @@ class CheckpointLog:
     # -- segments -------------------------------------------------------------
 
     @staticmethod
-    def _encode_segment(
+    def _encode_segment_py(
             deltas: dict[int, dict[bytes, Optional[bytes]]]) -> bytes:
+        """The segment format, row by row in Python: what runs where the
+        native codec is absent or a key does not fit its ``<H`` length
+        (``struct.error``, as ever)."""
         parts = [struct.pack("<I", len(deltas))]
         for table_id, buf in sorted(deltas.items()):
             parts.append(struct.pack("<II", table_id, len(buf)))
@@ -135,11 +146,44 @@ class CheckpointLog:
                     parts.append(v)
         return b"".join(parts)
 
+    @staticmethod
+    def _encode_segment_native(
+            deltas: dict[int, dict[bytes, Optional[bytes]]]
+    ) -> Optional[bytes]:
+        """The same bytes with each table's rows sorted and laid out by the
+        native codec (native/rowcodec.cpp ``rw_encode_segment_table``);
+        None where the codec is absent or refuses a table."""
+        from ..native import codec
+        native = codec()
+        if native is None:
+            return None
+        parts: list = [struct.pack("<I", len(deltas))]
+        for table_id, buf in sorted(deltas.items()):
+            block = native.encode_segment_table(buf)
+            if block is None:
+                return None
+            parts.append(struct.pack("<II", table_id, len(buf)))
+            parts.append(block)
+        return b"".join(parts)
+
+    @staticmethod
+    def _encode_segment(
+            deltas: dict[int, dict[bytes, Optional[bytes]]]) -> bytes:
+        # a segment is never empty (its table count), so None alone is falsy
+        return (CheckpointLog._encode_segment_native(deltas)
+                or CheckpointLog._encode_segment_py(deltas))
+
     def _write_segment(self, name: str,
-                       deltas: dict[int, dict[bytes, Optional[bytes]]]) -> None:
+                       deltas: dict[int, dict[bytes, Optional[bytes]]]
+                       ) -> dict:
+        """Encode and durably put one segment; returns what the caller's
+        span reports of it (``_segment_counts``)."""
         from ..common.failpoint import fail_point
         fail_point("checkpoint.segment.write")
-        payload = self._encode_segment(deltas)
+        payload = self._encode_segment_native(deltas)
+        native = payload is not None
+        if not native:
+            payload = self._encode_segment_py(deltas)
         try:
             # simulates a torn segment (crash mid-write): a truncated
             # object lands on disk. Safe because the manifest that would
@@ -150,6 +194,8 @@ class CheckpointLog:
             self.store.put(name, payload[:4])
             raise
         self.store.put(name, payload)
+        return _segment_counts(sum(map(len, deltas.values())),
+                               len(payload), native)
 
     def _read_segment(self, name: str) -> dict[int, dict[bytes, Optional[bytes]]]:
         data = self.store.get(name)
@@ -191,12 +237,13 @@ class CheckpointLog:
     COMPACT_AFTER = 64
 
     def append_epoch(self, epoch: int,
-                     deltas: dict[int, dict[bytes, Optional[bytes]]]) -> None:
+                     deltas: dict[int, dict[bytes, Optional[bytes]]]) -> dict:
         from ..common.failpoint import fail_point
         fail_point("checkpoint.commit")
+        counts = _segment_counts()
         if deltas:
             name = f"epoch_{epoch:012d}.seg"
-            self._write_segment(name, deltas)
+            counts = self._write_segment(name, deltas)
         with self._mlock:
             manifest = self._read_manifest()
             if deltas:
@@ -208,6 +255,7 @@ class CheckpointLog:
             n_segments = len(manifest["segments"])
         if n_segments > self.COMPACT_AFTER:
             self._spawn_compact()
+        return counts
 
     # -- two-phase epochs (spanning jobs) -------------------------------------
     # A job whose fragment graph spans worker processes needs the cluster
@@ -224,18 +272,20 @@ class CheckpointLog:
     # src/meta/src/hummock/manager/ commit_epoch).
 
     def prepare_epoch(self, epoch: int,
-                      deltas: dict[int, dict[bytes, Optional[bytes]]]) -> None:
+                      deltas: dict[int, dict[bytes, Optional[bytes]]]) -> dict:
         """Phase 1: durably stage an epoch's deltas without committing."""
         from ..common.failpoint import fail_point
         fail_point("checkpoint.prepare")
         name = None
+        counts = _segment_counts()
         if deltas:
             name = f"epoch_{epoch:012d}.prepared.seg"
-            self._write_segment(name, deltas)
+            counts = self._write_segment(name, deltas)
         with self._mlock:
             manifest = self._read_manifest()
             manifest["prepared"][str(epoch)] = name
             self._write_manifest(manifest)
+        return counts
 
     def prepared_epochs(self) -> list[int]:
         with self._mlock:
@@ -493,8 +543,8 @@ class DurableStateStore(MemoryStateStore):
         deltas = self._pending_deltas(epoch)
         with span("DurableStateStore.prepare", epoch=epoch,
                   stage="storage_prepare", cat=CAT_STORAGE, tid="storage",
-                  tables=len(deltas)):
-            self.log.prepare_epoch(epoch, deltas)
+                  tables=len(deltas)) as sp:
+            sp.set(**self.log.prepare_epoch(epoch, deltas))
         self._prepared_epochs.add(epoch)
 
     def commit_async(self, epoch: int) -> None:
@@ -526,8 +576,8 @@ class DurableStateStore(MemoryStateStore):
                 with span("DurableStateStore.commit_async", epoch=epoch,
                           stage="storage_commit", parent="checkpoint.commit",
                           cat=CAT_STORAGE, tid="storage",
-                          tables=len(deltas)):
-                    self.log.append_epoch(epoch, deltas)
+                          tables=len(deltas)) as sp:
+                    sp.set(**self.log.append_epoch(epoch, deltas))
             except BaseException as e:  # noqa: BLE001 - surfaced at join
                 self._commit_error = e
 
@@ -567,8 +617,8 @@ class DurableStateStore(MemoryStateStore):
             deltas = self._pending_deltas(epoch)
             with span("DurableStateStore.commit", epoch=epoch,
                       stage="storage_commit", cat=CAT_STORAGE,
-                      tid="storage", tables=len(deltas)):
-                self.log.append_epoch(epoch, deltas)
+                      tid="storage", tables=len(deltas)) as sp:
+                sp.set(**self.log.append_epoch(epoch, deltas))
         super().commit(epoch)
 
     def import_tables(self, deltas: dict[int, dict[bytes, bytes]],

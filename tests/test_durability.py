@@ -221,3 +221,93 @@ def test_load_tables_retries_when_compactor_deletes_segment(tmp_path):
     epoch, tables = reader.load_tables()
     assert epoch == 2
     assert tables[7] == {b"a": b"1", b"b": b"2"}
+
+
+# -- segments laid out by the native codec (native/rowcodec.cpp) -------------
+
+def _native_or_skip():
+    from risingwave_tpu.native import codec
+    if codec() is None:
+        pytest.skip("native toolchain unavailable")
+
+
+def _drive_epochs(st, model, epochs, seed):
+    """Ingest and commit ``epochs`` of random puts and deletes over three
+    tables; ``model`` follows as plain dicts."""
+    import random
+    rng = random.Random(seed)
+    for e in epochs:
+        for tid in (4, 2, 9):
+            tbl = model.setdefault(tid, {})
+            puts = {b"%d-%04d" % (tid, rng.randrange(400)):
+                    rng.randbytes(rng.randrange(0, 24)) for _ in range(60)}
+            live = sorted(set(tbl) - set(puts))
+            dels = set(rng.sample(live, min(len(live), 15)))
+            st.ingest(tid, e, puts, dels)
+            tbl.update(puts)
+            for k in dels:
+                del tbl[k]
+        st.commit(e)
+
+
+def _tables(st):
+    return {tid: dict(st.iter_table(tid)) for tid in (4, 2, 9)}
+
+
+@pytest.mark.parametrize("how", ["straight", "folded", "torn"])
+def test_native_segments_recover(tmp_path, how):
+    """Written through the native encoder, read back by a fresh store:
+    straight, after a fold, and with a torn segment left unreferenced."""
+    from risingwave_tpu.common.failpoint import failpoints
+    _native_or_skip()
+    d = str(tmp_path)
+    st, model = DurableStateStore(d, compact_after=1000), {}
+    _drive_epochs(st, model, range(1, 7), seed=34)
+    if how == "folded":
+        st.log.compact()
+        assert len(st.log._read_manifest()["segments"]) == 1
+    if how == "torn":
+        committed = {tid: dict(t) for tid, t in model.items()}
+        st.ingest(4, 7, {b"4-torn": b"x"}, set())
+        with failpoints(**{"checkpoint.segment.write.partial": OSError}):
+            with pytest.raises(OSError):
+                st.commit(7)
+        torn = "epoch_000000000007.seg"
+        assert len(st.log.store.get(torn)) == 4
+        assert torn not in st.log._read_manifest()["segments"]
+        st2 = DurableStateStore(d)
+        assert st2.committed_epoch == 6 and _tables(st2) == committed
+        st.commit(7)                      # the retry overwrites the torn one
+        model[4][b"4-torn"] = b"x"
+    st3 = DurableStateStore(d)
+    assert st3.committed_epoch == (7 if how == "torn" else 6)
+    assert _tables(st3) == model
+
+
+@pytest.mark.parametrize("first", ["python", "native"])
+def test_segments_of_either_encoder_reopen_under_the_other(
+        tmp_path, monkeypatch, first):
+    """An older data_dir (segments of the Python loop) reopens under a store
+    that writes natively, keeps growing, and the other way round."""
+    _native_or_skip()
+    d = str(tmp_path)
+    native = CheckpointLog._encode_segment_native
+
+    def use(encoder):
+        monkeypatch.setattr(
+            CheckpointLog, "_encode_segment_native",
+            staticmethod(native if encoder == "native"
+                         else (lambda deltas: None)))
+
+    second = "native" if first == "python" else "python"
+    model = {}
+    use(first)
+    _drive_epochs(DurableStateStore(d), model, range(1, 4), seed=1)
+    use(second)
+    st = DurableStateStore(d)
+    assert st.committed_epoch == 3 and _tables(st) == model
+    _drive_epochs(st, model, range(4, 7), seed=2)
+    st.log.compact()
+    use(first)
+    st2 = DurableStateStore(d)
+    assert st2.committed_epoch == 6 and _tables(st2) == model

@@ -118,3 +118,128 @@ class TestCheckpointPath:
         python_kv = run(True)
         assert native_kv == python_kv
         assert len(native_kv) == 3
+
+
+# -- checkpoint segment: a table's block laid out by the native codec --------
+
+def _random_table(rng, n, tombstones=0.2):
+    buf = {}
+    while len(buf) < n:
+        k = rng.randbytes(rng.choice((1, 4, 9, 20, 21, 40)))
+        buf[k] = (None if rng.random() < tombstones
+                  else rng.randbytes(rng.randrange(0, 64)))
+    return buf
+
+
+def _random_10k():
+    import random
+    rng = random.Random(34)
+    return {3: _random_table(rng, 6000), 11: _random_table(rng, 4000, 0.0)}
+
+
+SEGMENT_CASES = {
+    "no_tables": lambda: {},
+    "empty_table": lambda: {5: {}},
+    "puts_only": lambda: {1: {b"b": b"2", b"a": b"1", b"c": b"333"}},
+    "tombstones_only": lambda: {1: {b"z": None, b"y": None, b"x": None}},
+    "mixed": lambda: {1: {b"k2": None, b"k1": b"v1", b"k3": b"v3",
+                          b"k0": None}},
+    # the order on a tie: a key sorts before every key it is a prefix of,
+    # a trailing zero byte included (the 8-byte compare prefix pads with 0)
+    "prefix_keys": lambda: {1: {b"ab": b"1", b"abc": None, b"a": b"2",
+                                b"": b"e", b"ab\x00": b"z", b"b": b"3",
+                                b"ab\x00\x00": b"y",
+                                b"abcdefgh": b"8", b"abcdefghi": b"9",
+                                b"abcdefgh\x00": None, b"abcdefg": b"7"}},
+    # live with vlen 0 — not a tombstone
+    "empty_value": lambda: {1: {b"k": b"", b"j": None, b"l": b"x"}},
+    "key_65535": lambda: {1: {b"k" * 65535: b"v", b"a": None}},
+    "key_65536": lambda: {1: {b"a": b"1"}, 2: {b"k" * 65536: b"v"}},
+    "tables_unordered": lambda: {9: {b"n": b"9"}, 2: {b"t": None},
+                                 5: {}, 7: {b"b": b"", b"a": b"7"}},
+    "random_10k": _random_10k,
+}
+
+
+@pytest.mark.parametrize("case", list(SEGMENT_CASES))
+def test_segment_native_equals_python(case):
+    """Byte for byte the segment the Python row loop writes; where that
+    loop raises (a key past the ``<H`` length) the native path stands
+    aside and the same error surfaces."""
+    import struct
+    from risingwave_tpu.storage.checkpoint import CheckpointLog
+    deltas = SEGMENT_CASES[case]()
+    got = CheckpointLog._encode_segment_native(deltas)
+    if case == "key_65536":
+        assert got is None
+        with pytest.raises(struct.error):
+            CheckpointLog._encode_segment(deltas)
+        return
+    expect = CheckpointLog._encode_segment_py(deltas)
+    assert got == expect
+    assert CheckpointLog._encode_segment(deltas) == expect
+    assert CheckpointLog._decode_segment(got) == deltas
+
+
+def _commit_span(epoch):
+    from risingwave_tpu.common import tracing
+    (sp,) = [s for s in tracing.GLOBAL_TRACE.snapshot(epoch)
+             if s.name == "DurableStateStore.commit"]
+    return sp.args
+
+
+def test_commit_of_n_rows_runs_no_python_row_loop(tmp_path, monkeypatch):
+    """Counts, no timing: with the codec present a commit never enters the
+    per-row Python body, and its span says what was written and how."""
+    from risingwave_tpu.common import tracing
+    from risingwave_tpu.storage.checkpoint import (
+        CheckpointLog, DurableStateStore,
+    )
+    calls = []
+    py = CheckpointLog._encode_segment_py
+    monkeypatch.setattr(
+        CheckpointLog, "_encode_segment_py",
+        staticmethod(lambda deltas: calls.append(1) or py(deltas)))
+    tracing.GLOBAL_TRACE.clear()
+    st = DurableStateStore(str(tmp_path))
+    puts = {b"k%05d" % i: b"v%d" % i for i in range(1000)}
+    st.ingest(7, 1, puts, set())
+    st.ingest(9, 1, {b"x": b"1"}, {b"gone-a", b"gone-b"})
+    st.commit(1)
+    assert calls == []
+    segment = st.log.store.get("epoch_000000000001.seg")
+    assert _commit_span(1) == {"tables": 2, "rows": 1003,
+                               "bytes": len(segment), "native": 1}
+    assert segment == py(st.log._decode_segment(segment))
+    # an epoch with nothing to write appends no segment
+    st.commit(2)
+    assert _commit_span(2) == {"tables": 0, "rows": 0, "bytes": 0,
+                               "native": 0}
+
+
+def test_segment_fallback_under_disable_env(tmp_path, monkeypatch):
+    """RW_TPU_DISABLE_NATIVE=1: the Python loop writes the segment, the
+    span reads native = 0, the bytes are the same."""
+    import risingwave_tpu.native as native_mod
+    from risingwave_tpu.common import tracing
+    from risingwave_tpu.storage.checkpoint import (
+        CheckpointLog, DurableStateStore,
+    )
+    deltas = SEGMENT_CASES["mixed"]()
+    native_bytes = CheckpointLog._encode_segment_native(deltas)
+    monkeypatch.setenv("RW_TPU_DISABLE_NATIVE", "1")
+    monkeypatch.setattr(native_mod, "_lib", None)
+    monkeypatch.setattr(native_mod, "_tried", False)
+    assert native_mod.codec() is None
+    assert CheckpointLog._encode_segment_native(deltas) is None
+    tracing.GLOBAL_TRACE.clear()
+    st = DurableStateStore(str(tmp_path))
+    st.ingest(1, 1, {k: v for k, v in deltas[1].items() if v is not None},
+              {k for k, v in deltas[1].items() if v is None})
+    st.commit(1)
+    segment = st.log.store.get("epoch_000000000001.seg")
+    assert segment == native_bytes
+    assert _commit_span(1) == {"tables": 1, "rows": 4,
+                               "bytes": len(segment), "native": 0}
+    st2 = DurableStateStore(str(tmp_path))
+    assert dict(st2.iter_table(1)) == {b"k1": b"v1", b"k3": b"v3"}
