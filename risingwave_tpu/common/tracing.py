@@ -12,12 +12,17 @@ barrier's ``epoch`` id —
       cosched.dispatch / flush_begin / epoch_wait / flush_decode / restack
       barrier.inject               queue pushes + remote inject
       barrier.collect              awaiting every actor's ack
+        actor.run                  one job task's time in the epoch
         <Executor>.chunks          roll-up: host time inside map_chunk
         <Executor>.barrier         each executor's on_barrier work
           agg.flush_wait           the flush's one device fetch
           agg.state_delta          the checkpoint's state-table delta
+            delta.fetch_wait / delta.encode / delta.stage
       checkpoint.commit            store + worker phase-2 commit
+        commit.pending             the epoch's staged deltas merged
         DurableStateStore.commit   segment append inside the store
+          segment.encode / segment.put / manifest.write
+        store.apply                the in-memory apply
 
 — and every span is recorded through ``span(...)`` below, the only place
 that reads a clock for a span:
@@ -61,7 +66,6 @@ from .barrier_ledger import record_stage
 CAT_EPOCH = "epoch"          # whole-epoch + inject/collect conductor spans
 CAT_BARRIER = "barrier"      # per-executor on_barrier work
 CAT_STORAGE = "storage"      # state-table / store commit work
-CAT_EXCHANGE = "exchange"    # cross-process data movement
 CAT_DISPATCH = "dispatch"    # jitted-epoch dispatches (common/profiling.py)
 
 #: the one clock of every span: monotonic integer nanoseconds
@@ -260,11 +264,24 @@ def record_span(name: str, start_ns: int, dur_ns: int, *,
     """Record a span whose interval is already known (a roll-up of many
     short pieces, an interval that began in another call, a duration a
     listener is handed): same ring, same ledger fold as ``span`` — but no
-    annotation, there is no body to run inside one."""
+    annotation, there is no body to run inside one (the pieces of a
+    roll-up get theirs from ``annotation``)."""
     _emit(Span(name, cat, int(start_ns), int(dur_ns), epoch=epoch, tid=tid,
                id=next(_IDS),
                parent=_resolve_parent(parent, epoch, _CURRENT.get()),
                wait=wait, args=args), stage)
+
+
+def annotation(name: str, epoch: Optional[int] = None, **stats):
+    """A profiler annotation WITHOUT a ring record — the counterpart of
+    ``record_span``, for the pieces a roll-up sums: each short step runs
+    inside ``with annotation("<identity>.chunks", epoch)`` and lands in a
+    profiler's trace under the name the ring knows its roll-up by; with no
+    profiler session it is a no-op of about half a microsecond. The body
+    must not suspend its task (another task's work would fall inside)."""
+    if epoch is not None:
+        stats["epoch"] = epoch
+    return _trace_annotation()(name, **stats)
 
 
 def _emit(done: Span, stage: Optional[str], min_ns: float = 0) -> None:

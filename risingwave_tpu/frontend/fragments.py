@@ -32,7 +32,21 @@ from ..stream.dispatch import (
 )
 from ..stream.hash_agg import HashAggExecutor, agg_state_schema
 from ..stream.hash_join import HashJoinExecutor
+from ..stream.message import Barrier
+from ..stream.metrics import task_barrier_passed
 from ..storage.state_table import StateTable
+
+
+def _actor(executor, dispatcher):
+    """The coroutine factory of one fragment actor: drain ``executor``
+    into ``dispatcher``; a barrier handed on closes the epoch of the
+    actor task's clock (``actor.run``)."""
+    async def run():
+        async for msg in executor.execute():
+            await dispatcher.dispatch(msg)
+            if isinstance(msg, Barrier):
+                task_barrier_passed(msg.epoch.curr)
+    return run
 
 
 def build_fragmented_agg(plan, ctx):
@@ -69,20 +83,9 @@ def build_fragmented_agg(plan, ctx):
             out_capacity=cfg.chunk_capacity, load_shard=(i, n),
             hbm_group_budget=cfg.agg_hbm_budget))
 
-    async def run_upstream():
-        async for msg in upstream.execute():
-            await dispatcher.dispatch(msg)
-
-    def agg_actor(i: int):
-        async def run():
-            out = SimpleDispatcher(out_chans[i])
-            async for msg in aggs[i].execute():
-                await out.dispatch(msg)
-        return run
-
-    ctx.actors.append(run_upstream)
+    ctx.actors.append(_actor(upstream, dispatcher))
     for i in range(n):
-        ctx.actors.append(agg_actor(i))
+        ctx.actors.append(_actor(aggs[i], SimpleDispatcher(out_chans[i])))
     return MergeExecutor(out_chans, aggs[0].schema)
 
 
@@ -136,21 +139,8 @@ def build_fragmented_join(plan, ctx, join_types):
             out_capacity=cfg.chunk_capacity, load_shard=(i, n),
             hbm_key_budget=cfg.join_hbm_budget))
 
-    def upstream_actor(up, disp):
-        async def run():
-            async for msg in up.execute():
-                await disp.dispatch(msg)
-        return run
-
-    def join_actor(i: int):
-        async def run():
-            out = SimpleDispatcher(out_chans[i])
-            async for msg in joins[i].execute():
-                await out.dispatch(msg)
-        return run
-
-    ctx.actors.append(upstream_actor(left_up, l_disp))
-    ctx.actors.append(upstream_actor(right_up, r_disp))
+    ctx.actors.append(_actor(left_up, l_disp))
+    ctx.actors.append(_actor(right_up, r_disp))
     for i in range(n):
-        ctx.actors.append(join_actor(i))
+        ctx.actors.append(_actor(joins[i], SimpleDispatcher(out_chans[i])))
     return MergeExecutor(out_chans, joins[0].schema)

@@ -28,6 +28,9 @@ from ..stream.dispatch import MsgQueue
 from ..stream.executor import Executor
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Watermark
+from ..stream.metrics import (
+    number_executors, start_task_clock, task_barrier_passed,
+)
 
 
 class QueueSource(Executor):
@@ -87,14 +90,17 @@ class StreamJob:
         self._task: Optional[asyncio.Task] = None
         self._actor_tasks: list[asyncio.Task] = []
         self._failure: Optional[BaseException] = None
+        number_executors(pipeline)
 
     def start(self, loop: Optional[asyncio.AbstractEventLoop] = None) -> None:
-        for factory in self.actors:
-            self._actor_tasks.append(
-                asyncio.ensure_future(self._run_actor(factory), loop=loop))
+        for i, factory in enumerate(self.actors):
+            self._actor_tasks.append(asyncio.ensure_future(
+                self._run_actor(factory, i + 1), loop=loop))
         self._task = asyncio.ensure_future(self._run(), loop=loop)
 
-    async def _run_actor(self, factory) -> None:
+    async def _run_actor(self, factory, task: int) -> None:
+        # the actor passes its barriers on itself (frontend/fragments.py)
+        start_task_clock(self.name, task)
         try:
             await factory()
         except asyncio.CancelledError:
@@ -106,6 +112,7 @@ class StreamJob:
             raise
 
     async def _run(self) -> None:
+        start_task_clock(self.name)
         try:
             async for msg in self.pipeline.execute():
                 self.bus.publish(msg)
@@ -113,6 +120,7 @@ class StreamJob:
                     ev = self._barrier_events.setdefault(
                         msg.epoch.curr, asyncio.Event())
                     ev.set()
+                    task_barrier_passed(msg.epoch.curr)
         except BaseException as e:   # noqa: BLE001 - surfaced on next await
             self._failure = e
             for ev in self._barrier_events.values():

@@ -39,7 +39,7 @@ from ..stream.executor import Executor
 from ..stream.fused_jobs import MARKER_PREFIXES, FusedJobs
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Mutation, MutationKind
-from ..stream.row_id_gen import RowIdGenExecutor
+from ..stream.row_id_gen import RowIdAppendExecutor, RowIdGenExecutor
 from ..stream.source import MockSource
 from . import sqlast as A
 from .binder import BindError, ExprBinder, Scope
@@ -164,30 +164,6 @@ class _SourceFeed:
     state_table: Optional[StateTable] = None
     offsets_at_epoch: dict = dataclasses.field(default_factory=dict)
     job: str = ""          # owning stream job; feed dies with it on DROP
-
-
-class _RowIdAppendSource(Executor):
-    """Wraps a queue of connector chunks, appending the hidden _row_id
-    column (reference: source executors append the row-id column before
-    RowIdGen fills it)."""
-
-    def __init__(self, inner: QueueSource, out_schema: Schema):
-        self.inner = inner
-        self.schema = out_schema
-
-    async def execute(self):
-        import jax.numpy as jnp
-        from ..common.chunk import Column
-        async for msg in self.inner.execute():
-            if isinstance(msg, StreamChunk):
-                cap = msg.capacity
-                rid = Column(jnp.zeros(cap, jnp.int64),
-                             jnp.ones(cap, jnp.bool_))
-                yield msg.append_columns((rid,))
-            else:
-                yield msg
-            if isinstance(msg, Barrier) and msg.is_stop():
-                return
 
 
 def _split_sql(sql: str) -> list[str]:
@@ -1355,7 +1331,7 @@ class Session:
                 seqs = [r[len(fields)] & ((1 << 48) - 1)
                         for r in recovered.scan_all()]
                 start_seq = max(seqs) + 1 if seqs else 0
-            src = _RowIdAppendSource(q, schema)
+            src = RowIdAppendExecutor(q, schema)
             src = RowIdGenExecutor(src, row_id_index=len(fields),
                                    shard_id=self._alloc_shard(),
                                    start_seq=start_seq)
@@ -2753,7 +2729,7 @@ class Session:
                         start_seq = reader.rows_emitted()
                 self.feeds.append(_SourceFeed(
                     q, reader.next_host_chunk, reader=reader, state_table=st))
-            ex: Executor = _RowIdAppendSource(q, leaf.schema)
+            ex: Executor = RowIdAppendExecutor(q, leaf.schema)
             ex = RowIdGenExecutor(ex, row_id_index=leaf.row_id_index,
                                   shard_id=self._alloc_shard(),
                                   start_seq=start_seq)
@@ -3161,8 +3137,7 @@ class Session:
         self._barrier_ledger.begin(epoch, checkpoint, _time.time(),
                                    tracing.now_ns())
         with tracing.span("barrier.inject", epoch=epoch, stage="inject",
-                          cat=tracing.CAT_EPOCH, tid="conductor",
-                          checkpoint=checkpoint):
+                          cat=tracing.CAT_EPOCH, tid="conductor"):
             self.dml.drain_into_epoch()
             for feed in self.feeds:
                 if feed.reader is not None:

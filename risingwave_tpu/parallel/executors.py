@@ -51,7 +51,8 @@ from ..common.chunk import (
 )
 from ..common.fetch import fetch
 from ..common.tracing import (
-    CAT_STORAGE, current_span, now_ns, record_span, span,
+    CAT_STORAGE, annotation, conductor_epoch, current_span, now_ns,
+    record_span, span,
 )
 from ..common.types import Field, Schema
 from ..expr.agg import AggCall
@@ -126,7 +127,8 @@ class _SplitClock:
     """An epoch's chunks packed and put on the mesh, rolled up into ONE
     ``shard.split`` span at the barrier (a span a chunk would flood the
     ring; ``stream/metrics.ChunkClock`` does the same for ``.chunks``,
-    inside whose time these pieces lie)."""
+    inside whose time these pieces lie). Each piece runs inside a
+    profiler annotation of the same name."""
 
     __slots__ = ("first_ns", "busy_ns", "chunks", "transfers")
 
@@ -208,8 +210,9 @@ class ShardedHashAggExecutor(SingleInputExecutor):
 
     async def map_chunk(self, chunk: StreamChunk):
         t0 = now_ns()
-        stacks = jax.device_put(self._pack(chunk, self.n),
-                                self.agg._sharding)
+        with annotation("shard.split", conductor_epoch()):
+            stacks = jax.device_put(self._pack(chunk, self.n),
+                                    self.agg._sharding)
         self._split.add(t0, len(stacks))
         self.agg.step(stacks, self._packed_step(chunk))
         if False:
@@ -451,7 +454,8 @@ class ShardedHashJoinExecutor(Executor):
                 self._run_pending_inputs()
                 for out in self._flush_pending():
                     yield out
-                with barrier_timer(stats, self.identity, barrier.epoch.curr):
+                with barrier_timer(stats, self.identity, barrier.epoch.curr,
+                                   self.node):
                     self._check_flags()
                     if barrier.checkpoint:
                         self._checkpoint(barrier.epoch.curr)

@@ -35,6 +35,7 @@ import struct
 import threading
 from typing import Optional
 
+from ..common.tracing import CAT_STORAGE, span
 from .object_store import ObjectStore, open_object_store, wrap_object_store
 from .state_store import MemoryStateStore
 
@@ -57,6 +58,15 @@ def _segment_counts(rows: int = 0, nbytes: int = 0,
     its length and whether the native codec laid it out (0 / 0 / 0 for an
     epoch that wrote none)."""
     return {"rows": rows, "bytes": nbytes, "native": int(native)}
+
+
+def _part(name: str, **args):
+    """One kind of work inside a store writer's span (``DurableStateStore
+    .commit`` / ``.prepare`` / ``.commit_async``), of the enclosing span's
+    epoch — a fold's thread has none: its parts carry no epoch and
+    ``epoch_spans()`` skips them. No ledger stage: the writer's span
+    holds their time."""
+    return span(name, epoch=None, cat=CAT_STORAGE, tid="storage", **args)
 
 
 class CheckpointLog:
@@ -180,10 +190,14 @@ class CheckpointLog:
         span reports of it (``_segment_counts``)."""
         from ..common.failpoint import fail_point
         fail_point("checkpoint.segment.write")
-        payload = self._encode_segment_native(deltas)
-        native = payload is not None
-        if not native:
-            payload = self._encode_segment_py(deltas)
+        with _part("segment.encode") as encode:
+            payload = self._encode_segment_native(deltas)
+            native = payload is not None
+            if not native:
+                payload = self._encode_segment_py(deltas)
+            counts = _segment_counts(sum(map(len, deltas.values())),
+                                     len(payload), native)
+            encode.set(**counts)
         try:
             # simulates a torn segment (crash mid-write): a truncated
             # object lands on disk. Safe because the manifest that would
@@ -193,9 +207,9 @@ class CheckpointLog:
         except BaseException:
             self.store.put(name, payload[:4])
             raise
-        self.store.put(name, payload)
-        return _segment_counts(sum(map(len, deltas.values())),
-                               len(payload), native)
+        with _part("segment.put", bytes=len(payload)):
+            self.store.put(name, payload)
+        return counts
 
     def _read_segment(self, name: str) -> dict[int, dict[bytes, Optional[bytes]]]:
         data = self.store.get(name)
@@ -244,7 +258,7 @@ class CheckpointLog:
         if deltas:
             name = f"epoch_{epoch:012d}.seg"
             counts = self._write_segment(name, deltas)
-        with self._mlock:
+        with _part("manifest.write") as write, self._mlock:
             manifest = self._read_manifest()
             if deltas:
                 manifest["segments"].append(name)
@@ -253,6 +267,7 @@ class CheckpointLog:
             manifest["committed_epoch"] = epoch
             self._write_manifest(manifest)
             n_segments = len(manifest["segments"])
+            write.set(segments=n_segments)
         if n_segments > self.COMPACT_AFTER:
             self._spawn_compact()
         return counts
@@ -281,10 +296,11 @@ class CheckpointLog:
         if deltas:
             name = f"epoch_{epoch:012d}.prepared.seg"
             counts = self._write_segment(name, deltas)
-        with self._mlock:
+        with _part("manifest.write") as write, self._mlock:
             manifest = self._read_manifest()
             manifest["prepared"][str(epoch)] = name
             self._write_manifest(manifest)
+            write.set(segments=len(manifest["segments"]))
         return counts
 
     def prepared_epochs(self) -> list[int]:
@@ -526,10 +542,13 @@ class DurableStateStore(MemoryStateStore):
             self.committed_epoch = epoch
 
     def _pending_deltas(self, epoch: int) -> dict:
-        deltas: dict[int, dict[bytes, Optional[bytes]]] = {}
-        for e in sorted(k for k in self._pending if k <= epoch):
-            for table_id, buf in self._pending[e].items():
-                deltas.setdefault(table_id, {}).update(buf)
+        with span("commit.pending", epoch=epoch, cat=CAT_STORAGE,
+                  tid="storage") as pending:
+            deltas: dict[int, dict[bytes, Optional[bytes]]] = {}
+            for e in sorted(k for k in self._pending if k <= epoch):
+                for table_id, buf in self._pending[e].items():
+                    deltas.setdefault(table_id, {}).update(buf)
+            pending.set(rows=sum(map(len, deltas.values())))
         return deltas
 
     def prepare(self, epoch: int) -> None:
@@ -539,7 +558,6 @@ class DurableStateStore(MemoryStateStore):
         if epoch <= self.committed_epoch or epoch in self._prepared_epochs:
             return
         self.join_commits()          # manifest ops stay strictly ordered
-        from ..common.tracing import CAT_STORAGE, span
         deltas = self._pending_deltas(epoch)
         with span("DurableStateStore.prepare", epoch=epoch,
                   stage="storage_prepare", cat=CAT_STORAGE, tid="storage",
@@ -568,7 +586,6 @@ class DurableStateStore(MemoryStateStore):
             return
         deltas = self._pending_deltas(epoch)
         MemoryStateStore.commit(self, epoch)
-        from ..common.tracing import CAT_STORAGE, span
 
         def _encode_and_publish() -> None:
             # on its own thread: the conductor's commit span is named
@@ -602,7 +619,6 @@ class DurableStateStore(MemoryStateStore):
         if epoch <= self.committed_epoch:
             return
         self.join_commits()
-        from ..common.tracing import CAT_STORAGE, span
         prepared = {e for e in self._prepared_epochs if e <= epoch}
         if prepared:
             # phase 2: promote the durably staged segment(s); epochs

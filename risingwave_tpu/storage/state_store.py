@@ -20,6 +20,8 @@ from __future__ import annotations
 import copy
 from typing import Any, Iterator, Optional
 
+from ..common.tracing import CAT_STORAGE, span
+
 
 class MemoryStateStore:
     """Process-local multi-table KV store with epoch commit.
@@ -62,15 +64,20 @@ class MemoryStateStore:
         is likewise a single logical commit per epoch)."""
         if epoch <= self.committed_epoch:
             return
-        for e in sorted(k for k in self._pending if k <= epoch):
-            for table_id, buf in self._pending.pop(e).items():
-                tbl = self._committed.setdefault(table_id, {})
-                self._keys_dirty.add(table_id)
-                for k, v in buf.items():
-                    if v is None:
-                        tbl.pop(k, None)
-                    else:
-                        tbl[k] = v
+        with span("store.apply", epoch=epoch, cat=CAT_STORAGE,
+                  tid="storage") as apply:
+            rows = 0
+            for e in sorted(k for k in self._pending if k <= epoch):
+                for table_id, buf in self._pending.pop(e).items():
+                    tbl = self._committed.setdefault(table_id, {})
+                    self._keys_dirty.add(table_id)
+                    rows += len(buf)
+                    for k, v in buf.items():
+                        if v is None:
+                            tbl.pop(k, None)
+                        else:
+                            tbl[k] = v
+            apply.set(rows=rows)
         self.committed_epoch = epoch
 
     # -- async commit surface (pipelined tick, docs/performance.md) -----------

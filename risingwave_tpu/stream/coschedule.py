@@ -152,11 +152,8 @@ class JobAxisGroup:
                   wait="device", cat=CAT_EPOCH, tid="conductor"):
             packed_h = np.asarray(p.fetch.result())
         with span("cosched.flush_decode", epoch=None, stage="flush_decode",
-                  cat=CAT_EPOCH, tid="conductor") as decode:
-            out = self._decode_flush(p, packed_h)
-            decode.set(dirty_groups=int(packed_h[:, 0].sum()),
-                       chunks=sum(len(c) for c in out.values()))
-        return out
+                  cat=CAT_EPOCH, tid="conductor"):
+            return self._decode_flush(p, packed_h)
 
     def checkpoint(self, engines: dict, epoch: int) -> None:
         """Write every job's delta through its OWN HashAggExecutor

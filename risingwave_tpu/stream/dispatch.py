@@ -32,6 +32,7 @@ from ..common.hashing import vnode_of, vnode_to_shard
 from ..common.types import Schema
 from .executor import Executor
 from .message import Barrier, Message, Watermark
+from .metrics import task_resumed
 
 
 class MsgQueue:
@@ -69,6 +70,9 @@ class MsgQueue:
                 await self._waiter
             finally:
                 self._waiter = None
+        # the consuming task has a message in hand: the first one after a
+        # barrier starts its ``actor.run`` (stream/metrics.TaskClock)
+        task_resumed()
         return self._items.popleft()
 
     def qsize(self) -> int:

@@ -30,6 +30,9 @@ class Executor:
 
     schema: Schema
     identity: str = "Executor"
+    #: ordinal in its plan (``stream/metrics.number_executors``): the
+    #: ``node`` arg of the executor's ``.chunks`` / ``.barrier`` spans
+    node: Optional[int] = None
 
     def execute(self) -> AsyncIterator[Message]:
         raise NotImplementedError
@@ -73,7 +76,7 @@ class SingleInputExecutor(Executor):
     async def execute(self) -> AsyncIterator[Message]:
         from .metrics import ChunkClock, barrier_timer
         stats = self.stats
-        clock = ChunkClock(stats)
+        clock = ChunkClock(stats, self.identity)
         async for msg in self.input.execute():
             if isinstance(msg, StreamChunk):
                 stats.chunks_in += 1
@@ -89,10 +92,10 @@ class SingleInputExecutor(Executor):
                     stats.chunks_out += 1
                     yield out
             elif isinstance(msg, Barrier):
-                with barrier_timer(stats, self.identity, msg.epoch.curr):
+                with barrier_timer(stats, self.identity, msg.epoch.curr,
+                                   self.node):
                     outs = [out async for out in self.on_barrier(msg)]
-                clock.emit(self.identity, msg.epoch.curr,
-                           **self.epoch_counts())
+                clock.emit(msg.epoch.curr, self.node, **self.epoch_counts())
                 for out in outs:
                     stats.chunks_out += 1
                     yield out

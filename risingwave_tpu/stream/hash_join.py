@@ -378,10 +378,9 @@ class HashJoinExecutor(Executor):
                 yield from self._replay_growing(side, ch)
 
     async def execute(self):
-        from ..common.tracing import now_ns
         from .metrics import ChunkClock, barrier_timer
         stats = self.stats
-        clock = ChunkClock(stats)
+        clock = ChunkClock(stats, self.identity)
         self._pending: list = []
         self._rewind_state = None
         chunks_out = stats.chunks_out
@@ -403,7 +402,7 @@ class HashJoinExecutor(Executor):
                 _, side, chunk = ev
                 stats.chunks_in += 1
                 stats.capacity_rows_in += chunk.capacity
-                t_chunk = now_ns()
+                clock.begin()
                 if self.null_aware_anti and side == "right":
                     self._reject_null_build_keys(chunk)
                 if self._evicted:
@@ -412,10 +411,10 @@ class HashJoinExecutor(Executor):
                         # flush the optimistic batch FIRST: fault-in
                         # replays mutate state, and a later rewind of the
                         # batch must not lose them
-                        clock.add(t_chunk)
+                        clock.end()
                         for out in clock.timed(self._flush_pending()):
                             yield out
-                        t_chunk = now_ns()
+                        clock.begin()
                         self._fault_in(hits)
                 if self._rewind_state is None:
                     self._rewind_state = self.state
@@ -425,7 +424,7 @@ class HashJoinExecutor(Executor):
                 self._pending.append(
                     (side, chunk,
                      self._pack_stats(_flags(new_state), big, chunk), big))
-                clock.add(t_chunk)
+                clock.end()
                 if len(self._pending) >= self.emit_batch:
                     for out in clock.timed(self._flush_pending()):
                         yield out
@@ -433,12 +432,13 @@ class HashJoinExecutor(Executor):
                 barrier = ev[1]
                 for out in clock.timed(self._flush_pending()):
                     yield out
-                clock.emit(self.identity, barrier.epoch.curr,
+                clock.emit(barrier.epoch.curr, self.node,
                            chunks_out=stats.chunks_out - chunks_out,
                            bucket_width=self.core.W, **self._epoch_counts)
                 chunks_out = stats.chunks_out
                 self._epoch_counts = dict.fromkeys(self._epoch_counts, 0)
-                with barrier_timer(stats, self.identity, barrier.epoch.curr):
+                with barrier_timer(stats, self.identity, barrier.epoch.curr,
+                                   self.node):
                     self._check_flags()
                     if barrier.checkpoint:
                         cleaned = self._apply_pending_clean()

@@ -3,6 +3,14 @@
 into the state table. One routine each for the hash agg, the hash join
 and the sharded hash agg; what differs between them — which columns are
 gathered, and which dirty rows are deletes — stays with the caller.
+
+Whatever ``*.state_delta`` span the caller has open gets three children
+here, one a kind of work: ``delta.fetch_wait`` (the host blocked on the
+device's windows), ``delta.encode`` (dirty rows to key / value bytes) and
+``delta.stage`` (the bytes into the state table and its commit). What is
+left as the parent's self time is window 0's dispatch, the numpy cut, the
+caller's masks and its ``ckpt_dirty`` reset. None carries a ledger stage:
+the parent's ``state_delta`` stage already holds their time.
 """
 
 from __future__ import annotations
@@ -13,6 +21,7 @@ import jax
 import numpy as np
 
 from ..common.fetch import async_fetch, fetch
+from ..common.tracing import CAT_STORAGE, current_span, span
 from ..storage.state_table import StateTable
 
 #: rows of one delta window; a smaller state is its own window. A
@@ -34,11 +43,14 @@ def fetch_delta(window: Callable, capacity: int) -> tuple:
     rows, shard after shard in ascending flat-index order, and what the
     ``*.state_delta`` spans report (``windows``, ``bytes_fetched``)."""
     G = min(capacity, DELTA_WINDOW_ROWS)
-    wins = [fetch(window(np.int32(0), G))]
-    n_dirty = np.atleast_1d(wins[0][0])
-    more = [async_fetch(window(np.int32(lo), G))
-            for lo in range(G, int(n_dirty.max()), G)]
-    wins += [f.result() for f in more]
+    first = window(np.int32(0), G)
+    with _child("delta.fetch_wait", wait="device") as wait:
+        wins = [fetch(first)]
+        n_dirty = np.atleast_1d(wins[0][0])
+        more = [async_fetch(window(np.int32(lo), G))
+                for lo in range(G, int(n_dirty.max()), G)]
+        wins += [f.result() for f in more]
+        wait.set(windows=len(wins))
     # a shard's windows are full up to its last one and the valid rows
     # lead, so its first n_dirty rows across the windows are its delta
     keep = np.arange(len(wins) * G) < n_dirty[:, None]
@@ -48,6 +60,14 @@ def fetch_delta(window: Callable, capacity: int) -> tuple:
     counters = {"windows": len(wins), "bytes_fetched": sum(
         x.nbytes for x in jax.tree_util.tree_leaves(wins))}
     return int(n_dirty.sum()), columns, counters
+
+
+def _child(name: str, **kw) -> span:
+    """A span under the caller's open ``*.state_delta``, on its track and
+    of its epoch."""
+    parent = current_span()
+    return span(name, epoch=None, cat=CAT_STORAGE,
+                tid=parent.tid if parent is not None else "main", **kw)
 
 
 def stage_delta(table: StateTable, epoch: int, datas: Sequence[np.ndarray],
@@ -63,27 +83,35 @@ def stage_delta(table: StateTable, epoch: int, datas: Sequence[np.ndarray],
     put_idx, del_idx = np.flatnonzero(puts), np.flatnonzero(dels)
     types = table.schema.types
     codec = _native_codec()
+    native = codec is not None and codec.supports(types)
     staged = 0
-    if codec is not None and codec.supports(types):
-        pk = table.pk_indices
-        pk_d = [datas[i] for i in pk]
-        pk_m = [masks[i] for i in pk]
-        pk_t = [types[i] for i in pk]
-        rows = dict(zip(
-            codec.encode_keys(pk_d, pk_m, pk_t, put_idx),
-            codec.encode_value_rows(datas, masks, types, put_idx)))
-        keys = codec.encode_keys(pk_d, pk_m, pk_t, del_idx)
-        table.stage_encoded(rows, keys)
-        staged = (sum(map(len, rows)) + sum(map(len, rows.values()))
-                  + sum(map(len, keys)))
-    else:
-        def row_at(r):
-            return tuple(d[r].item() if m[r] else None
-                         for d, m in zip(datas, masks))
+    with _child("delta.encode", rows=len(put_idx) + len(del_idx),
+                native=int(native)) as encode:
+        if native:
+            pk = table.pk_indices
+            pk_d = [datas[i] for i in pk]
+            pk_m = [masks[i] for i in pk]
+            pk_t = [types[i] for i in pk]
+            put_keys = codec.encode_keys(pk_d, pk_m, pk_t, put_idx)
+            put_rows = codec.encode_value_rows(datas, masks, types, put_idx)
+            del_keys = codec.encode_keys(pk_d, pk_m, pk_t, del_idx)
+            staged = (sum(map(len, put_keys)) + sum(map(len, put_rows))
+                      + sum(map(len, del_keys)))
+        else:
+            def row_at(r):
+                return tuple(d[r].item() if m[r] else None
+                             for d, m in zip(datas, masks))
 
-        for r in del_idx:
-            table.delete(row_at(r))
-        for r in put_idx:
-            table.insert(row_at(r))
-    table.commit(epoch)
+            del_rows = [row_at(r) for r in del_idx]
+            put_rows = [row_at(r) for r in put_idx]
+        encode.set(bytes=staged)
+    with _child("delta.stage", puts=len(put_idx), deletes=len(del_idx)):
+        if native:
+            table.stage_encoded(dict(zip(put_keys, put_rows)), del_keys)
+        else:
+            for row in del_rows:
+                table.delete(row)
+            for row in put_rows:
+                table.insert(row)
+        table.commit(epoch)
     return staged

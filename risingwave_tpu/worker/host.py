@@ -48,7 +48,7 @@ from ..stream.eowc import WatermarkFilterExecutor
 from ..stream.executor import Executor
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Mutation, MutationKind
-from ..stream.row_id_gen import RowIdGenExecutor
+from ..stream.row_id_gen import RowIdAppendExecutor, RowIdGenExecutor
 
 
 class _Feed:
@@ -119,26 +119,6 @@ class _ChannelSource(Executor):
             yield msg
             if isinstance(msg, Barrier) and msg.is_stop():
                 return
-
-
-class _RowIdAppend(Executor):
-    """Append the hidden _row_id column slot to connector chunks (the
-    session's _RowIdAppendSource, worker-side)."""
-
-    def __init__(self, inner: QueueSource, out_schema: Schema):
-        self.inner = inner
-        self.schema = out_schema
-
-    async def execute(self) -> AsyncIterator[Message]:
-        import jax.numpy as jnp
-
-        from ..common.chunk import Column
-        async for msg in self.inner.execute():
-            if isinstance(msg, StreamChunk):
-                zero = Column(jnp.zeros(msg.capacity, jnp.int64),
-                              jnp.ones(msg.capacity, jnp.bool_))
-                msg = StreamChunk(msg.ops, msg.vis, msg.columns + (zero,))
-            yield msg
 
 
 class WorkerHost:
@@ -226,7 +206,7 @@ class WorkerHost:
                 reader.seek(offsets)
                 start_seq = reader.rows_emitted()
             self.feeds.append(_Feed(q, reader, st, job_name))
-        ex: Executor = _RowIdAppend(q, leaf.schema)
+        ex: Executor = RowIdAppendExecutor(q, leaf.schema)
         # span fragments pin their shard id from the session (stable
         # across drop-and-rebuild recovery, so replayed rows reproduce
         # their pre-crash row ids — the exactly-once upsert condition for

@@ -47,6 +47,7 @@ CONDUCTOR = {
     "source.feed": "session.tick",
     "barrier.inject": "session.tick",
     "barrier.collect": "session.tick",
+    "actor.run": "barrier.collect",
     "Materialize.chunks": "barrier.collect",
     "Materialize.barrier": "barrier.collect",
     "materialize.fetch_wait": "Materialize.barrier",
@@ -66,21 +67,28 @@ EVERY_BARRIER = {
     "shardfused": FUSED_DRIVER,
     "exec": {**CONDUCTOR,
              **{f"{ident}.{kind}": "barrier.collect"
-                for ident in ("RowIdGen", "Project", "HashAgg")
+                for ident in ("RowIdAppend", "RowIdGen", "Project",
+                              "HashAgg")
                 for kind in ("chunks", "barrier")},
              "agg.flush_wait": "HashAgg.barrier"},
 }
+#: the checkpoint by part (ISSUE 35): one leaf a kind of work, under
+#: whatever ``*.state_delta`` / store writer's span is open
+DELTA_PARTS = ("delta.fetch_wait", "delta.encode", "delta.stage")
+SEGMENT_PARTS = ("segment.encode", "segment.put", "manifest.write")
+COMMIT = {"checkpoint.commit": "session.tick",
+          "commit.pending": "checkpoint.commit",
+          "DurableStateStore.commit": "checkpoint.commit",
+          **dict.fromkeys(SEGMENT_PARTS, "DurableStateStore.commit"),
+          "store.apply": "checkpoint.commit",
+          **dict.fromkeys(DELTA_PARTS, "agg.state_delta")}
 FUSED_CHECKPOINT = {"agg.state_delta": "session.tick",
-                    "cosched.restack": "session.tick",
-                    "checkpoint.commit": "session.tick",
-                    "DurableStateStore.commit": "checkpoint.commit"}
+                    "cosched.restack": "session.tick", **COMMIT}
 CHECKPOINT_ONLY = {
     "fused": FUSED_CHECKPOINT,
     "hetero": FUSED_CHECKPOINT,
     "shardfused": FUSED_CHECKPOINT,
-    "exec": {"agg.state_delta": "HashAgg.barrier",
-             "checkpoint.commit": "session.tick",
-             "DurableStateStore.commit": "checkpoint.commit"},
+    "exec": {"agg.state_delta": "HashAgg.barrier", **COMMIT},
 }
 #: never owed to a reader: they occur only sometimes
 SOMETIMES = {"xla.compile", "cosched.resolve_deferred"}
@@ -344,10 +352,13 @@ def test_profiler_trace_holds_the_same_spans_on_a_shared_clock(
         notes = host_annotations(log_dir)
         # everything recorded around a body is annotated; roll-ups and the
         # latency summary have no body to annotate
+        # (a roll-up's STEPS are annotated under its name: the test of
+        # the steps in the trace, below)
+        notes = [n for n in notes if not n["name"].endswith(".chunks")]
         ring = [d for spans in tracing.epoch_spans().values() for d in spans
                 if not d["name"].endswith(".chunks")
                 and not d["name"].startswith("epoch ")
-                and d["name"] != "xla.compile"]
+                and d["name"] not in ("xla.compile", "actor.run")]
         assert len(ring) >= 3 * 8
         key = lambda d: (d["epoch"], d["name"])       # noqa: E731
         by_key: dict = {}
@@ -874,3 +885,275 @@ def test_join_programs_carry_their_names_and_scopes(q8_run):
     assert join._apply["right"].__wrapped__.__name__ == "join_step_right"
     assert join._gather.__wrapped__.__name__ == "join_gather"
     assert join._pack_stats.__wrapped__.__name__ == "join_pack_stats"
+
+
+# -- the closed span tree of the five cells' plans (ISSUE 35) -----------------
+# Every executor's steps in the profiler's trace under its roll-up's name,
+# a clock for the job's task (``actor.run``), and the checkpoint's delta
+# and commit by part. The plans are the benchmark's configurations at
+# their tiny sizes, through the benchmark's own ``System``.
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL_CONFIGS = {"fused": "nexmark-q5core-fused",
+                "exec": "nexmark-q5core-exec",
+                "q8": "nexmark-q8",
+                "q101": "nexmark-q101",
+                "mesh": "nexmark-q5core-exec-mesh4"}
+DEFAULT_PATH = ("exec", "q8", "q101", "mesh")
+#: the store writer's children (``commit.pending`` and ``store.apply`` sit
+#: beside it, under ``checkpoint.commit``)
+COMMIT_PARTS = ("commit.pending", "DurableStateStore.commit", "store.apply")
+CELL_TICKS = 7
+
+
+@pytest.fixture(scope="module", params=list(CELL_CONFIGS))
+def cell_run(request, tmp_path_factory):
+    """Seven barriers (two checkpoints) of one cell's plan under a
+    profiler session: ``(cell, session, {epoch: [span dict]}, ledger
+    records by epoch, the trace's directory)``."""
+    import json
+    import sys
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from benchmark import run as bench_run
+    from benchmark import system
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           f"{CELL_CONFIGS[request.param]}.json")) as f:
+        config = bench_run.tiny_sizes(json.load(f))
+    config["rw_toml"] = {**config["rw_toml"],
+                         "streaming.checkpoint_frequency": 3}
+    tmp = tmp_path_factory.mktemp(request.param)
+    sut = system.System(config, str(tmp / "data"), 3_500_000_017)
+    sut.create()
+    for _ in range(2):
+        sut.barrier()                     # compile outside the trace
+    GLOBAL_TRACE.clear()
+    first = sut.session.epoch + 1
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    log_dir = str(tmp / "trace")
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    try:
+        for _ in range(CELL_TICKS):
+            sut.barrier()
+    finally:
+        jax.profiler.stop_trace()
+    spans = {e: v for e, v in tracing.epoch_spans().items() if e >= first}
+    assert sorted(spans) == list(range(first, first + CELL_TICKS))
+    history = {r["epoch"]: r for r in sut.session._barrier_ledger.history()}
+    yield request.param, sut.session, spans, history, log_dir
+    sut.close()
+
+
+def children_of(spans: list, parent: dict) -> list:
+    return [d for d in spans if d["parent"] == parent["id"]]
+
+
+def test_every_executor_of_a_plan_has_a_clock_and_a_node(cell_run):
+    """(a) what ``iter_executors`` walks is what emits ``.chunks`` and
+    ``.barrier``, on every barrier, told apart by ``node``."""
+    from risingwave_tpu.stream.metrics import iter_executors
+    cell, s, by_epoch, _history, _log = cell_run
+    (job,) = s.jobs.values()
+    plan = list(iter_executors(job.pipeline))
+    assert [ex.node for ex in plan] == list(range(len(plan)))
+    # the queue under the fused job's Materialize stays bare: its own
+    # work is a ``queue.get()``
+    from risingwave_tpu.frontend.runtime import QueueSource
+    assert all(isinstance(ex, QueueSource) for ex in plan
+               if not hasattr(ex, "stats"))
+    want = sorted((ex.identity, ex.node) for ex in plan
+                  if hasattr(ex, "stats"))
+    if cell in DEFAULT_PATH:
+        idents = {ident for ident, _node in want}
+        assert {"RowIdAppend", "RowIdGen", "Project", "Materialize"} <= idents
+        # two executors of one identity in every default-path plan
+        assert len(idents) < len(want)
+    for epoch, spans in by_epoch.items():
+        for kind in (".chunks", ".barrier"):
+            got = sorted((d["name"][:-len(kind)], d["args"]["node"])
+                         for d in spans if d["name"].endswith(kind))
+            assert got == want, (epoch, kind)
+
+
+def test_actor_run_is_the_tasks_clock_under_collect(cell_run):
+    """(a) one ``actor.run`` a job task and epoch, inside
+    ``barrier.collect``, and no executor's time outside it."""
+    _cell, s, by_epoch, _history, _log = cell_run
+    (job,) = s.jobs.values()
+    for epoch, spans in by_epoch.items():
+        (collect,) = [d for d in spans if d["name"] == "barrier.collect"]
+        (actor,) = [d for d in spans if d["name"] == "actor.run"]
+        assert actor["parent"] == collect["id"]
+        assert actor["tid"] == job.name and actor["args"]["task"] == 0
+        # the feeds' chunks + the barrier, of every source
+        assert actor["args"]["messages"] >= len(job.sources)
+        assert collect["start_ns"] <= actor["start_ns"]
+        assert actor["start_ns"] + actor["dur_ns"] \
+            <= collect["start_ns"] + collect["dur_ns"]
+        own = [d for d in children_of(spans, collect)
+               if d["name"].endswith((".chunks", ".barrier"))]
+        for d in own:
+            assert actor["start_ns"] <= d["start_ns"], d["name"]
+        unowned_ns = actor["dur_ns"] - sum(d["dur_ns"] for d in own)
+        assert unowned_ns >= -0.5e6, epoch
+
+
+def test_executor_steps_are_in_the_profilers_trace(cell_run):
+    """(b) the steps a roll-up sums are annotations of the roll-up's name,
+    found by the names ``epoch_spans()`` hands out, as many as steps ran,
+    each inside a ``barrier.collect`` annotation."""
+    from benchmark import trace
+    cell, _s, by_epoch, _history, log_dir = cell_run
+    names = {d["name"] for spans in by_epoch.values() for d in spans}
+    raw = trace.extract(trace.find_xplane(log_dir), tuple(names))
+    events: dict = {}
+    for name, start, dur in raw["annotations"]:
+        events.setdefault(name, []).append((start, start + dur))
+    collects = events["barrier.collect"]
+    assert len(collects) == CELL_TICKS
+    rollups: dict = {}
+    for spans in by_epoch.values():
+        for d in spans:
+            if d["name"].endswith(".chunks") or d["name"] == "shard.split":
+                rollups[d["name"]] = (rollups.get(d["name"], 0)
+                                      + d["args"]["chunks"])
+    owed = {"fused": ("Materialize.chunks",),
+            "mesh": ("RowIdAppend.chunks", "RowIdGen.chunks",
+                     "ShardedHashAgg.chunks", "Materialize.chunks",
+                     "shard.split")}.get(
+        cell, ("RowIdAppend.chunks", "RowIdGen.chunks", "HashAgg.chunks",
+               "Materialize.chunks"))
+    assert set(owed) <= set(rollups)
+    for name, chunks in rollups.items():
+        steps = events.get(name, [])
+        if name == "HashJoin.chunks":
+            # a step a chunk and one a flush of pending output
+            assert len(steps) >= chunks > 0
+        elif name in ("RowIdAppend.chunks", "shard.split"):
+            assert len(steps) == chunks > 0, name
+        else:
+            # each chunk: a step an output and the closing StopIteration
+            assert chunks <= len(steps) <= 2 * chunks + CELL_TICKS, name
+        for lo, hi in steps:
+            assert any(c0 <= lo and hi <= c1 for c0, c1 in collects), name
+
+
+def test_checkpoint_by_part_under_every_delta_and_the_commit(cell_run):
+    """(c) each ``*.state_delta`` has its three children, the commit its
+    three and the store writer its three; children never sum to more than
+    their parent; an ordinary barrier has none of them."""
+    cell, _s, by_epoch, history, _log = cell_run
+    parts = set(DELTA_PARTS + SEGMENT_PARTS + COMMIT_PARTS)
+    deltas_a_checkpoint = {"fused": 1, "exec": 1, "mesh": 1, "q8": 4,
+                           "q101": 3}[cell]
+    checkpoints = 0
+    for epoch, spans in by_epoch.items():
+        if not history[epoch]["checkpoint"]:
+            assert not [d["name"] for d in spans if d["name"] in parts]
+            continue
+        checkpoints += 1
+        deltas = [d for d in spans if d["name"].endswith(".state_delta")]
+        assert len(deltas) == deltas_a_checkpoint
+        (commit,) = [d for d in spans if d["name"] == "checkpoint.commit"]
+        (writer,) = [d for d in spans
+                     if d["name"] == "DurableStateStore.commit"]
+        for parent, want in ([(d, DELTA_PARTS) for d in deltas]
+                             + [(commit, COMMIT_PARTS),
+                                (writer, SEGMENT_PARTS)]):
+            kids = children_of(spans, parent)
+            assert tuple(d["name"] for d in kids) == want, parent["name"]
+            assert sum(d["dur_ns"] for d in kids) <= parent["dur_ns"]
+            assert not any(d["dur_ns"] < 0 for d in kids)
+        for d in spans:
+            if d["name"] == "delta.fetch_wait":
+                assert d["wait"] == "device" and d["args"]["windows"] >= 1
+            elif d["name"] == "delta.encode":
+                assert d["args"]["rows"] > 0 and "native" in d["args"]
+                assert d["args"]["bytes"] > 0 or not d["args"]["native"]
+            elif d["name"] == "delta.stage":
+                stage = d["args"]
+        # the last delta's parts add up to its rows
+        assert stage["puts"] + stage["deletes"] > 0
+        encode = next(d for d in children_of(spans, writer)
+                      if d["name"] == "segment.encode")
+        assert {k: encode["args"][k] for k in ("rows", "bytes", "native")} \
+            == {k: writer["args"][k] for k in ("rows", "bytes", "native")}
+        put, manifest = [next(d for d in spans if d["name"] == n)
+                         for n in ("segment.put", "manifest.write")]
+        assert put["args"]["bytes"] == writer["args"]["bytes"]
+        assert manifest["args"]["segments"] >= 1
+        pending, apply = [next(d for d in spans if d["name"] == n)
+                          for n in ("commit.pending", "store.apply")]
+        assert pending["args"]["rows"] == writer["args"]["rows"]
+        assert apply["args"]["rows"] >= pending["args"]["rows"]
+    assert checkpoints == 2
+
+
+def test_no_new_span_folds_into_a_ledger_stage(cell_run):
+    """(d) a child span carries no ``stage``: the ledger's stages read
+    what the spans that carried them before read, and only those."""
+    _cell, _s, by_epoch, history, _log = cell_run
+    carried = {"collect": ("barrier.collect",),
+               "commit": ("checkpoint.commit",),
+               "storage_commit": ("DurableStateStore.commit",),
+               "inject": ("barrier.inject",),
+               "source_feed": ("source.feed",)}
+    for epoch, spans in by_epoch.items():
+        stages = history[epoch]["stages"]
+        for stage, names in carried.items():
+            ms = sum(d["dur_ns"] for d in spans if d["name"] in names) / 1e6
+            assert stages.get(stage, 0.0) == pytest.approx(ms, abs=1e-6), \
+                (epoch, stage)
+        ms = sum(d["dur_ns"] for d in spans
+                 if d["name"].endswith(".state_delta")
+                 or d["name"] == "cosched.restack") / 1e6
+        assert stages.get("state_delta", 0.0) == pytest.approx(ms, abs=1e-6)
+        waits = [d["name"] for d in spans if d["wait"] == "device"]
+        assert ("delta.fetch_wait" in waits) == history[epoch]["checkpoint"]
+
+
+@pytest.mark.parametrize("writer", ["prepare", "commit_async", "fold"])
+def test_segment_spans_from_every_writer_of_a_segment(writer, tmp_path):
+    """(e) 2PC's prepare, the deferred commit's thread and the background
+    fold write their segments through the same parts; a thread with no
+    span around it records them without an epoch."""
+    import threading
+    from risingwave_tpu.storage.checkpoint import DurableStateStore
+    store = DurableStateStore(str(tmp_path / "db"))
+    rows = {bytes([i]) * 4: bytes([i]) * 16 for i in range(32)}
+    GLOBAL_TRACE.clear()
+    if writer == "fold":
+        for epoch in (1, 2, 3):
+            store.ingest(7, epoch, rows, set())
+            store.commit(epoch)
+        GLOBAL_TRACE.clear()
+        t = threading.Thread(target=store.log.compact)
+        t.start()
+        t.join()
+        got = [s for s in GLOBAL_TRACE.snapshot()
+               if s.name in SEGMENT_PARTS]
+        assert [s.name for s in got] == ["segment.encode", "segment.put"]
+        assert all(s.epoch is None and s.parent is None for s in got)
+        assert got[0].args["rows"] == 32 and got[0].args["native"] in (0, 1)
+        assert tracing.epoch_spans() == {}
+        assert len(store.log._read_manifest()["segments"]) == 1
+        return
+    store.ingest(7, 1, rows, {b"gone"})
+    if writer == "prepare":
+        store.prepare(1)
+        store.commit(1)
+    else:
+        store.commit_async(1)
+        store.join_commits()
+    spans = tracing.epoch_spans()[1]
+    by_id = {d["id"]: d for d in spans}
+    parent = f"DurableStateStore.{writer}"
+    got = [d for d in spans if d["name"] in SEGMENT_PARTS]
+    assert [d["name"] for d in got] == list(SEGMENT_PARTS)
+    assert {by_id[d["parent"]]["name"] for d in got} == {parent}
+    assert sum(d["dur_ns"] for d in got) <= by_id[got[0]["parent"]]["dur_ns"]
+    (pending,) = [d for d in spans if d["name"] == "commit.pending"]
+    (apply,) = [d for d in spans if d["name"] == "store.apply"]
+    assert pending["args"]["rows"] == apply["args"]["rows"] == 33
+    assert store.committed_epoch == 1
