@@ -525,7 +525,7 @@ def pipeline_nodes(job):
     while stack:
         node = stack.pop()
         yield node
-        for attr in ("input", "inner", "left", "right", "agg", "join"):
+        for attr in ("input", "left", "right", "agg", "join"):
             child = getattr(node, attr, None)
             if hasattr(child, "__dict__"):
                 stack.append(child)

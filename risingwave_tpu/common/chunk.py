@@ -153,28 +153,83 @@ class StagedCounts:
     own object (``Session.tick`` passes one per ``source.feed`` span, whose
     args these are): ``transfers`` — host column buffers, one per dtype
     stack; ``bytes_staged`` — their bytes; ``dispatches`` — unpack programs
-    run. Each dispatch carries one more small argument that is counted with
-    it and not as a transfer: the int32 row count per chunk."""
+    run; ``row_ids`` — rows given a ``_row_id`` in those programs. Each
+    dispatch carries small arguments that are counted with it and not as
+    transfers: the int32 row count per chunk and, with a row-id sequence,
+    the int64 first id per chunk."""
 
     transfers: int = 0
     bytes_staged: int = 0
     dispatches: int = 0
+    row_ids: int = 0
 
 
-def unpack_chunk(layout, ns, *stacks) -> tuple:
+@dataclasses.dataclass
+class RowIdSequence:
+    """Where a source's hidden ``_row_id`` stands: a host counter owned by
+    whoever feeds the source. An id is ``shard_id << 48 | seq`` (reference:
+    src/common/src/util/row_id.rs — a prefix per parallel source so their
+    ids never collide, a serial number below it); ``next`` is the ``seq``
+    of the next row. Recovery restarts it above every id handed out before
+    the crash (``SplitReader.rows_emitted``)."""
+
+    SEQ_BITS = 48
+
+    shard_id: int = 0
+    next: int = 0
+
+    def take(self, n: int) -> int:
+        """The id of the next row; the ``n`` rows from it on are taken."""
+        first = (self.shard_id << self.SEQ_BITS) | self.next
+        self.next += n
+        return first
+
+    @classmethod
+    def seq_of(cls, row_id: int) -> int:
+        """The serial number under an id's shard prefix."""
+        return row_id & ((1 << cls.SEQ_BITS) - 1)
+
+
+def row_id_data(first, rank) -> jax.Array:
+    """THE ``_row_id`` arithmetic, for staged and for device chunks alike:
+    ``first`` is the id of the chunk's first visible row (``RowIdSequence.
+    take``), ``rank`` a row's position among the visible rows. Invisible
+    rows get ids too; nobody reads them. The column's mask is ``vis``."""
+    return first + rank.astype(jnp.int64)
+
+
+@jax.jit
+def append_row_ids(first: jax.Array, chunk: StreamChunk):
+    """``_row_id`` for a chunk that is ALREADY on the device (a test's push
+    into a reader-less source, a table's INSERT): the column appended and
+    filled in one dispatch. ``first`` rides as a device scalar because only
+    the device knows how many rows are visible. -> (the first id after
+    this chunk, the chunk with its ids)."""
+    vis = chunk.vis
+    seen = jnp.cumsum(vis)
+    return first + seen[-1], chunk.append_columns(
+        (Column(row_id_data(first, seen - vis), vis),))
+
+
+def unpack_chunk(layout, ns, firsts, *stacks) -> tuple:
     """Device side of ``stage_chunks``: slice the staged ``[K, rows, cap]``
     stacks back into K chunks' arrays — per chunk ``(ops, vis, datas,
-    masks)``, ``masks`` empty where every mask is ``vis``."""
-    stack_of, flags, cap, k = layout
+    masks)``; a column without a mask here has ``vis`` as its mask. With
+    ``with_ids`` in the layout ``firsts`` holds each chunk's first id, and
+    ``datas`` ends in the ``_row_id`` column (never a mask of its own)."""
+    stack_of, flags, cap, k, with_ids = layout
+    rows = jnp.arange(cap, dtype=jnp.int32)
     out = []
     for c in range(k):
-        live = jnp.arange(cap, dtype=jnp.int32) < ns[c]
+        live = rows < ns[c]
         datas = tuple(stacks[s][c, r] for s, r in stack_of)
+        if with_ids:
+            datas += (row_id_data(firsts[c], rows),)
         if flags:
             # the int8 stack: one row per column mask, then the ops
-            rows = stacks[-1][c]
-            masks = tuple(rows[i] != 0 for i in range(len(stack_of)))
-            ops = rows[len(stack_of)]
+            int8s = stacks[-1][c]
+            masks = tuple(int8s[i] != 0 for i in range(len(stack_of)))
+            ops = int8s[len(stack_of)]
         else:
             masks = ()
             ops = jnp.zeros(cap, jnp.int8)  # all Insert (append-only source)
@@ -185,8 +240,8 @@ def unpack_chunk(layout, ns, *stacks) -> tuple:
 _unpack = jax.jit(unpack_chunk, static_argnums=(0,))
 
 
-def _stage_run(run: Sequence[HostChunk],
-               counts: Optional[StagedCounts]) -> list:
+def _stage_run(run: Sequence[HostChunk], counts: Optional[StagedCounts],
+               row_ids: Optional[RowIdSequence]) -> list:
     """``stage_chunks`` for chunks of ONE dtype layout and capacity."""
     dtypes, cap, k = run[0].dtypes(), run[0].capacity, len(run)
     order = list(dict.fromkeys(dtypes))
@@ -209,21 +264,28 @@ def _stage_run(run: Sequence[HostChunk],
         if flags and h.ops is not None:
             bufs[-1][c, len(dtypes), :h.n] = h.ops[:h.n]
     ns = np.array([h.n for h in run], np.int32)
+    firsts = (None if row_ids is None else
+              np.array([row_ids.take(h.n) for h in run], np.int64))
     if counts is not None:
         counts.transfers += len(bufs)
         counts.bytes_staged += sum(b.nbytes for b in bufs)
         counts.dispatches += 1
+        counts.row_ids += 0 if firsts is None else int(ns.sum())
     # an output array costs the host about as much as a small transfer (55 us
-    # each on a v5e, PERF.md §6 PR 30): a chunk without nulls gets ONE array
-    # as its vis and as every column's mask, not a copy each
+    # each on a v5e, PERF.md §6 PR 30): ONE array is a chunk's vis and the
+    # mask of every column that brought none (all of them without nulls;
+    # the _row_id always), not a copy each
     return [StreamChunk(ops, live, tuple(
-                Column(d, m) for d, m in zip(datas, masks or (live,) * len(datas))))
+                Column(d, m)
+                for d, m in zip(datas, masks + (live,) * (len(datas) - len(masks)))))
             for ops, live, datas, masks
-            in _unpack((tuple(stack_of), flags, cap, k), ns, *bufs)]
+            in _unpack((tuple(stack_of), flags, cap, k, firsts is not None),
+                       ns, firsts, *bufs)]
 
 
 def stage_chunks(host: Sequence[HostChunk],
-                 counts: Optional[StagedCounts] = None) -> list:
+                 counts: Optional[StagedCounts] = None,
+                 row_ids: Optional[RowIdSequence] = None) -> list:
     """The one way host columns become device chunks.
 
     Every run of chunks with one dtype layout and capacity (a feed's chunks
@@ -237,11 +299,17 @@ def stage_chunks(host: Sequence[HostChunk],
     ``ops`` is zeros, made on the device; only a run that has either stages
     one more int8 stack with all masks and the ops. The buffers are
     allocated per call and never written again, so the asynchronous
-    transfer reads what was staged. ``counts``, where given, is added to."""
+    transfer reads what was staged. ``counts``, where given, is added to.
+
+    ``row_ids``, where given, is the sequence of a source whose chunks
+    these are: every chunk gets one more column, the hidden ``_row_id``
+    (``row_id_data``: the chunk's first id, one small runtime vector
+    more, plus the row's position), made by the same dispatch, and the
+    sequence advances by each chunk's rows as the chunk is staged."""
     out = []
     for _, run in itertools.groupby(
             host, key=lambda h: (h.dtypes(), h.capacity)):
-        out.extend(_stage_run(list(run), counts))
+        out.extend(_stage_run(list(run), counts, row_ids))
     return out
 
 

@@ -19,20 +19,24 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
-from ..common.chunk import HostChunk, StagedCounts, StreamChunk, stage_chunks
+from ..common.chunk import (
+    HostChunk, RowIdSequence, StagedCounts, StreamChunk, stage_chunks,
+)
 
 
 def feed_chunks(draw: Callable[[], Optional[HostChunk]], k: int,
                 push: Callable[[StreamChunk], None],
-                counts: Optional[StagedCounts] = None) -> List[StreamChunk]:
+                counts: Optional[StagedCounts] = None,
+                row_ids: Optional[RowIdSequence] = None) -> List[StreamChunk]:
     """One feed's share of a barrier: draw up to ``k`` host chunks, stage
-    them together (one transfer per dtype, one dispatch) and push each.
+    them together (one transfer per dtype, one dispatch; with the feed's
+    ``row_ids`` the hidden ``_row_id`` column too) and push each.
 
     A draw advances its reader's offsets, and the next checkpoint persists
     them. So what was drawn is pushed even where a later draw raises (a
-    broker fetch out of retries, a file read error): offsets and queue
-    agree on every way out, and the tick that is retried goes on from
-    there."""
+    broker fetch out of retries, a file read error): offsets, row ids and
+    queue agree on every way out, and the tick that is retried goes on
+    from there."""
     host: List[HostChunk] = []
     try:
         for _ in range(k):
@@ -40,7 +44,7 @@ def feed_chunks(draw: Callable[[], Optional[HostChunk]], k: int,
             if chunk is not None:
                 host.append(chunk)
     finally:
-        chunks = stage_chunks(host, counts)
+        chunks = stage_chunks(host, counts, row_ids)
         for chunk in chunks:
             push(chunk)
     return chunks
@@ -81,5 +85,6 @@ class SplitReader:
     def rows_emitted(self) -> int:
         """Rows emitted through the current offsets — an upper bound is
         acceptable. Used to restart serial row-id assignment above any id
-        handed out before a crash (RowIdGen continuation on recovery)."""
+        handed out before a crash (where a feed's ``RowIdSequence`` goes on
+        after recovery)."""
         return sum(self.offsets.values())

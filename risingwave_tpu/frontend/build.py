@@ -25,7 +25,6 @@ from ..stream.hash_join import HashJoinExecutor
 from ..stream.hop_window import HopWindowExecutor
 from ..stream.materialize import MaterializeExecutor
 from ..stream.project import FilterExecutor, ProjectExecutor
-from ..stream.row_id_gen import RowIdGenExecutor
 from ..stream.simple_agg import SimpleAggExecutor
 from ..stream.top_n import TopNExecutor
 from ..stream.union import UnionExecutor

@@ -64,8 +64,7 @@ class PlanNode:
 
 @dataclasses.dataclass
 class PSource(PlanNode):
-    source: SourceDef
-    row_id_index: int = -1           # hidden _row_id column index
+    source: SourceDef                # schema = the source's + hidden _row_id
 
 
 @dataclasses.dataclass
@@ -475,12 +474,12 @@ class Planner:
         alias = ref.alias or name
         if kind == "source":
             # hidden _row_id appended: the stream key of a keyless source
-            # (reference: row_id_gen.rs + logical source planning)
+            # (reference: logical source planning); the column is made
+            # where the source's chunks are staged (common/chunk.py)
             from ..common.types import SERIAL
             schema = Schema(tuple(d.schema) + (Field("_row_id", SERIAL),))
             n = len(schema)
-            node = PSource(schema=schema, pk=(n - 1,), source=d,
-                           row_id_index=n - 1)
+            node = PSource(schema=schema, pk=(n - 1,), source=d)
             scope = Scope([
                 ScopeColumn(f.name, alias, i, f.type)
                 for i, f in enumerate(d.schema)

@@ -24,7 +24,7 @@ from typing import Any, Callable, Optional, Sequence
 
 from ..common.chunk import (
     OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, HostChunk,
-    StagedCounts, StreamChunk, chunk_to_rows, make_chunk,
+    RowIdSequence, StagedCounts, StreamChunk, chunk_to_rows, make_chunk,
 )
 from ..common.config import MeshUnavailableError
 from ..common.types import Field, Schema
@@ -39,7 +39,7 @@ from ..stream.executor import Executor
 from ..stream.fused_jobs import MARKER_PREFIXES, FusedJobs
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Mutation, MutationKind
-from ..stream.row_id_gen import RowIdAppendExecutor, RowIdGenExecutor
+from ..stream.row_id_gen import RowIdGenExecutor
 from ..stream.source import MockSource
 from . import sqlast as A
 from .binder import BindError, ExprBinder, Scope
@@ -164,6 +164,9 @@ class _SourceFeed:
     state_table: Optional[StateTable] = None
     offsets_at_epoch: dict = dataclasses.field(default_factory=dict)
     job: str = ""          # owning stream job; feed dies with it on DROP
+    #: where the leaf's hidden _row_id stands; the chunks get the column
+    #: as they are staged (None: a fused job's feed, which stages nothing)
+    row_ids: Optional[RowIdSequence] = None
 
 
 def _split_sql(sql: str) -> list[str]:
@@ -1325,16 +1328,14 @@ class Session:
         if not stmt.pk:
             start_seq = 0
             if self._recovering:
-                # continue above the recovered max row id (ids are
-                # shard<<48 | seq; mask off the shard prefix)
+                # continue above the recovered max row id (its serial
+                # number, under whatever shard prefix it had)
                 recovered = StateTable(self.store, t.table_id, schema, list(pk))
-                seqs = [r[len(fields)] & ((1 << 48) - 1)
+                seqs = [RowIdSequence.seq_of(r[len(fields)])
                         for r in recovered.scan_all()]
                 start_seq = max(seqs) + 1 if seqs else 0
-            src = RowIdAppendExecutor(q, schema)
-            src = RowIdGenExecutor(src, row_id_index=len(fields),
-                                   shard_id=self._alloc_shard(),
-                                   start_seq=start_seq)
+            src = RowIdGenExecutor(
+                q, schema, RowIdSequence(self._alloc_shard(), start_seq))
         mat = MaterializeExecutor(
             src, StateTable(self.store, t.table_id, schema, list(pk)))
         job = StreamJob(stmt.name, mat, [q])
@@ -2704,11 +2705,15 @@ class Session:
         """-> (executor, session_driven_queue_or_None, init_messages)"""
         if isinstance(leaf, PSource):
             src_def = leaf.source
-            q = QueueSource(src_def.schema)
             reader = self._connector_reader(src_def)
-            start_seq = 0
+            row_ids = RowIdSequence(self._alloc_shard())
+            ex: Executor
             if reader is None:
+                # fed by pushes of chunks that are on the device already:
+                # the one executor that gives those their _row_id
+                q = QueueSource(src_def.schema)
                 self.feeds.append(_SourceFeed(q, lambda: None))
+                ex = RowIdGenExecutor(q, leaf.schema, row_ids)
             else:
                 # split-state table: (split_id, next_offset), persisted on
                 # checkpoint epochs, sought on recovery
@@ -2726,13 +2731,13 @@ class Session:
                         # row ids must continue above any id assigned
                         # before the crash (pk collisions in downstream
                         # materialized state otherwise)
-                        start_seq = reader.rows_emitted()
+                        row_ids.next = reader.rows_emitted()
+                # the feed's chunks leave the staging dispatch with their
+                # _row_id (common/chunk.stage_chunks): the queue IS the leaf
+                ex = q = QueueSource(leaf.schema)
                 self.feeds.append(_SourceFeed(
-                    q, reader.next_host_chunk, reader=reader, state_table=st))
-            ex: Executor = RowIdAppendExecutor(q, leaf.schema)
-            ex = RowIdGenExecutor(ex, row_id_index=leaf.row_id_index,
-                                  shard_id=self._alloc_shard(),
-                                  start_seq=start_seq)
+                    q, reader.next_host_chunk, reader=reader, state_table=st,
+                    row_ids=row_ids))
             if src_def.watermark is not None:
                 col, delay = src_def.watermark
                 ex = WatermarkFilterExecutor(ex, time_col=col, delay=delay)
@@ -3119,7 +3124,7 @@ class Session:
                         continue
                     for chunk in feed_chunks(
                             feed.generator, self.chunks_per_tick,
-                            feed.queue.push, staged):
+                            feed.queue.push, staged, feed.row_ids):
                         fed += 1
                         fed_rows += chunk.capacity
                 feed_span.set(chunks=fed, capacity_rows=fed_rows,

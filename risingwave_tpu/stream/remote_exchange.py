@@ -296,7 +296,7 @@ def _build_fragments_into(host, req: dict, store, job: FragmentJob,
                 inner = ex
                 from ..frontend.runtime import QueueSource
                 while not isinstance(inner, QueueSource):
-                    inner = getattr(inner, "inner", None) or inner.input
+                    inner = inner.input
                 job.sources.append(inner)
                 return ex
             if isinstance(leaf, PExchange):

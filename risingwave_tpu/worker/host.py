@@ -32,7 +32,7 @@ import base64
 import sys
 from typing import AsyncIterator, Optional
 
-from ..common.chunk import StreamChunk
+from ..common.chunk import RowIdSequence, StreamChunk
 from ..common.row import encode_value_row
 from ..common.types import Field, INT64, Schema, VARCHAR
 from ..connector.base import feed_chunks
@@ -48,7 +48,7 @@ from ..stream.eowc import WatermarkFilterExecutor
 from ..stream.executor import Executor
 from ..stream.materialize import MaterializeExecutor
 from ..stream.message import Barrier, Message, Mutation, MutationKind
-from ..stream.row_id_gen import RowIdAppendExecutor, RowIdGenExecutor
+from ..stream.row_id_gen import RowIdGenExecutor
 
 
 class _Feed:
@@ -57,12 +57,13 @@ class _Feed:
     and recovery seeks them)."""
 
     def __init__(self, queue: QueueSource, reader, state_table: StateTable,
-                 job: str):
+                 job: str, row_ids: RowIdSequence):
         self.queue = queue
         self.reader = reader
         self.state_table = state_table
         self.offsets_at_epoch: dict[int, dict] = {}
         self.job = job
+        self.row_ids = row_ids      # where the leaf's hidden _row_id stands
 
 
 class _ChannelSource(Executor):
@@ -190,13 +191,23 @@ class WorkerHost:
     def _source_leaf(self, leaf: PSource, job_name: str, store,
                      next_table_id, shard_id: Optional[int] = None) -> Executor:
         src = leaf.source
-        q = QueueSource(src.schema)
         from ..connector.factory import make_reader
         reader = make_reader(src.connector, src.options, src.schema,
                              self.chunk_capacity, self.seed,
                              fault=self.fault)
-        start_seq = 0
-        if reader is not None:
+        # span fragments pin their shard id from the session (stable
+        # across drop-and-rebuild recovery, so replayed rows reproduce
+        # their pre-crash row ids — the exactly-once upsert condition for
+        # row-id-keyed MVs); whole-job placement keeps the process-local
+        # counter
+        row_ids = RowIdSequence(self._alloc_shard()
+                                if shard_id is None else shard_id)
+        ex: Executor
+        if reader is None:
+            # nothing feeds it here; what is pushed is on the device already
+            ex = RowIdGenExecutor(QueueSource(src.schema), leaf.schema,
+                                  row_ids)
+        else:
             st = StateTable(store, next_table_id(),
                             Schema((Field("split_id", VARCHAR),
                                     Field("next_offset", INT64))), [0])
@@ -204,18 +215,11 @@ class WorkerHost:
                        for r in st.scan_all()}
             if offsets:           # recovered split state: seek
                 reader.seek(offsets)
-                start_seq = reader.rows_emitted()
-            self.feeds.append(_Feed(q, reader, st, job_name))
-        ex: Executor = RowIdAppendExecutor(q, leaf.schema)
-        # span fragments pin their shard id from the session (stable
-        # across drop-and-rebuild recovery, so replayed rows reproduce
-        # their pre-crash row ids — the exactly-once upsert condition for
-        # row-id-keyed MVs); whole-job placement keeps the process-local
-        # counter
-        ex = RowIdGenExecutor(ex, row_id_index=leaf.row_id_index,
-                              shard_id=(self._alloc_shard()
-                                        if shard_id is None else shard_id),
-                              start_seq=start_seq)
+                row_ids.next = reader.rows_emitted()
+            # the feed's chunks are staged with their _row_id
+            # (common/chunk.stage_chunks): the queue IS the leaf
+            ex = q = QueueSource(leaf.schema)
+            self.feeds.append(_Feed(q, reader, st, job_name, row_ids))
         if src.watermark is not None:
             col, delay = src.watermark
             ex = WatermarkFilterExecutor(ex, time_col=col, delay=delay)
@@ -284,7 +288,7 @@ class WorkerHost:
                 # find the root queue for barrier injection
                 inner = ex
                 while not isinstance(inner, QueueSource):
-                    inner = getattr(inner, "inner", None) or inner.input
+                    inner = inner.input
                 queues.append(inner)
                 return ex
             if isinstance(leaf, (PTableScan, PMvScan)):
@@ -454,7 +458,8 @@ class WorkerHost:
                 if feed.job not in scope:
                     continue
                 feed_chunks(feed.reader.next_host_chunk,
-                            self.chunks_per_tick, feed.queue.push)
+                            self.chunks_per_tick, feed.queue.push,
+                            row_ids=feed.row_ids)
         for feed in self.feeds:
             if feed.job in scope:
                 feed.offsets_at_epoch[epoch] = feed.reader.offsets
@@ -574,7 +579,7 @@ class WorkerHost:
                 out.append((node.state_table, tuple(range(nk)),
                             tuple(node.in_schema[i].type
                                   for i in node.group_keys)))
-            for attr in ("input", "inner", "left", "right"):
+            for attr in ("input", "left", "right"):
                 child = getattr(node, attr, None)
                 if isinstance(child, Executor):
                     stack.append(child)
@@ -947,7 +952,7 @@ def _channel_roots(job: StreamJob):
         if isinstance(node, _ChannelSource):
             out.append(node)
             continue
-        for attr in ("input", "inner", "left", "right"):
+        for attr in ("input", "left", "right"):
             child = getattr(node, attr, None)
             if isinstance(child, Executor):
                 stack.append(child)

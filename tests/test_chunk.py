@@ -14,7 +14,7 @@ from risingwave_tpu.common import (
     vnode_of, vnode_to_shard, hash_columns, VNODE_COUNT,
 )
 from risingwave_tpu.common.chunk import (
-    Column, HostChunk, StagedCounts, stage_chunks,
+    Column, HostChunk, RowIdSequence, StagedCounts, stage_chunks,
 )
 from risingwave_tpu.connector.datagen import DatagenReader, _field_values
 from risingwave_tpu.connector.nexmark import (
@@ -294,6 +294,130 @@ def test_stage_chunks_stages_a_run_together():
         assert_same_bits(chunk, by_columns(schema, h.arrays, h.n, h.capacity,
                                            h.masks, h.ops))
     assert stage_chunks([]) == []
+
+
+def parent_row_id_step(base, seq, chunk):
+    """``RowIdGenExecutor._step`` as it was before the ids moved into the
+    staging dispatch (PR 36), kept as the reference the staged column must
+    equal: ``shard_id << 48 | (seq + rank among the visible rows)``."""
+    vis = chunk.vis
+    offset = jnp.cumsum(vis) - vis.astype(jnp.int64)
+    return seq + jnp.sum(vis), base | (seq + offset)
+
+
+ROW_ID_SCHEMA = Schema.of(("a", INT64), ("b", VARCHAR), ("c", INT64))
+
+
+def row_id_barrier(rng, k, cap, flags):
+    """K host chunks of one barrier: full, partly full and empty ones; with
+    ``flags`` one has a null and one a non-Insert op."""
+    run = []
+    for c in range(k):
+        n = (cap, int(rng.integers(0, cap)), 0, cap - 1)[c % 4]
+        arrays = [rng.integers(0, 1 << 40, n).astype(f.type.np_dtype)
+                  for f in ROW_ID_SCHEMA]
+        masks = ops = None
+        if flags and c == 0:
+            masks = [np.ones(n, bool), np.arange(n) % 3 > 0, np.ones(n, bool)]
+        if flags and c == k - 1:
+            ops = np.full(n, OP_DELETE, np.int8)
+        run.append(HostChunk(ROW_ID_SCHEMA, arrays, n, cap, masks, ops))
+    return run
+
+
+@pytest.mark.parametrize("flags", [False, True], ids=["plain", "int8_stack"])
+@pytest.mark.parametrize("k", [1, 4, 16])
+def test_staged_row_ids_equal_the_parents_step(k, flags):
+    """ISSUE 36 (a): over three consecutive barriers the ``_row_id`` of
+    every visible row is what the parent's RowIdGen step gave the same
+    stream, the column's mask is ``vis`` (the same array), every other leaf
+    is what staging without a sequence gives, and the sequence and the
+    counts advance by the rows staged — in one dispatch a barrier."""
+    cap, shard, start = 48, 3, 1_000_003
+    rng = np.random.default_rng(k)
+    row_ids = RowIdSequence(shard, start)
+    seq, base = jnp.asarray(start, jnp.int64), jnp.int64(shard) << 48
+    fed = 0
+    for _barrier in range(3):
+        run = row_id_barrier(rng, k, cap, flags)
+        counts = StagedCounts()
+        got = stage_chunks(run, counts, row_ids)
+        plain = stage_chunks(run)
+        fed += sum(h.n for h in run)
+        assert counts.dispatches == 1
+        assert counts.row_ids == sum(h.n for h in run)
+        assert counts.transfers == 2 + flags
+        for h, chunk, bare in zip(run, got, plain):
+            assert len(chunk.columns) == len(ROW_ID_SCHEMA) + 1
+            assert_same_bits(chunk.replace(columns=chunk.columns[:-1]), bare)
+            ids = chunk.columns[-1]
+            assert ids.data.dtype == jnp.int64
+            assert ids.mask is chunk.vis
+            seq, want = parent_row_id_step(base, seq, bare)
+            vis = np.asarray(bare.vis)
+            assert np.array_equal(np.asarray(ids.data)[vis],
+                                  np.asarray(want)[vis])
+            assert vis.sum() == h.n
+    assert row_ids == RowIdSequence(shard, start + fed)
+    assert int(seq) == start + fed
+
+
+@pytest.mark.parametrize("flags", [False, True], ids=["plain", "int8_stack"])
+def test_staging_without_a_sequence_is_what_it_was(flags):
+    """ISSUE 36 (b): ``stage_chunks(host)`` with no row-id sequence gives
+    the column-by-column chunk leaf for leaf — no further column, and a
+    null-free chunk's masks are still its ``vis`` array."""
+    rng = np.random.default_rng(36)
+    run = row_id_barrier(rng, 4, 56, flags)
+    counts = StagedCounts()
+    for h, chunk in zip(run, stage_chunks(run, counts)):
+        assert_same_bits(chunk, by_columns(ROW_ID_SCHEMA, h.arrays, h.n, 56,
+                                           h.masks, h.ops))
+        if not flags:
+            assert all(c.mask is chunk.vis for c in chunk.columns)
+    assert counts.row_ids == 0 and counts.dispatches == 1
+
+
+def test_a_run_of_other_capacity_continues_the_sequence():
+    """A capacity change splits a feed's barrier into two dispatches; the
+    ids run on across the split in the order the chunks were drawn."""
+    schema = Schema.of(("a", INT64),)
+    host = [HostChunk(schema, [np.arange(n, dtype=np.int64)], n, cap)
+            for n, cap in ((5, 8), (8, 8), (3, 16), (16, 16))]
+    counts = StagedCounts()
+    got = stage_chunks(host, counts, RowIdSequence(1, 10))
+    assert counts.dispatches == 2 and counts.row_ids == 32
+    ids = np.concatenate([np.asarray(c.columns[-1].data)[np.asarray(c.vis)]
+                          for c in got])
+    assert ids.tolist() == [(1 << 48) | (10 + i) for i in range(32)]
+
+
+def test_row_ids_of_a_retried_feed_are_contiguous():
+    """ISSUE 36 (d): a draw that raises mid-barrier — the chunks drawn
+    before it are pushed with their ids, and the retried call goes on from
+    the id after the last one staged."""
+    from risingwave_tpu.connector.base import feed_chunks
+    schema = Schema.of(("a", INT64),)
+    cap, drawn = 16, []
+
+    def draw():
+        if len(drawn) == 2:
+            drawn.append(None)
+            raise OSError("fetch failed, out of retries")
+        drawn.append(None)
+        n = cap - len(drawn) % 3
+        return HostChunk(schema, [np.zeros(n, np.int64)], n, cap)
+
+    row_ids, counts, pushed = RowIdSequence(2, 7), StagedCounts(), []
+    with pytest.raises(OSError):
+        feed_chunks(draw, 4, pushed.append, counts, row_ids)
+    assert len(pushed) == 2 and counts.dispatches == 1
+    feed_chunks(draw, 4, pushed.append, counts, row_ids)
+    assert len(pushed) == 6 and counts.dispatches == 2
+    ids = np.concatenate([np.asarray(c.columns[-1].data)[np.asarray(c.vis)]
+                          for c in pushed])
+    assert counts.row_ids == len(ids) == row_ids.next - 7
+    assert ids.tolist() == [(2 << 48) | (7 + i) for i in range(len(ids))]
 
 
 def test_feed_chunks_pushes_what_was_drawn_when_a_later_draw_raises():

@@ -4,6 +4,8 @@ src/stream/src/executor/)."""
 
 import asyncio
 
+import jax.numpy as jnp
+
 from risingwave_tpu.common import (
     INT64, TIMESTAMP, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT,
     Schema, chunk_to_rows, make_chunk,
@@ -147,17 +149,35 @@ def test_dedup_checkpoint_recovery():
 
 
 def test_row_id_gen():
+    """The one step that gives chunks ALREADY on the device their ids: the
+    column appended and filled in one dispatch, the ids serial over the
+    visible rows of consecutive chunks, from where the sequence stood."""
+    from risingwave_tpu.common.chunk import RowIdSequence, append_row_ids
+    from risingwave_tpu.common.types import SERIAL, Field
+    gaps = make_chunk(TS, [(7, 10), (8, 20), (9, 25)], capacity=4)
+    gaps = gaps.with_vis(gaps.vis.at[1].set(False))
     src = MockSource(TS, [
         Barrier.new(1),
-        make_chunk(TS, [(None, 10), (None, 20)], capacity=4),
+        gaps,
         make_chunk(TS, [(None, 30)], capacity=4),
         Barrier.new(2),
     ])
-    ex = RowIdGenExecutor(src, row_id_index=0, shard_id=3)
+    out_schema = Schema(tuple(TS) + (Field("_row_id", SERIAL),))
+    ex = RowIdGenExecutor(src, out_schema, RowIdSequence(3, 5))
     chunks, _, _ = run(drain(ex))
     rows = rows_of(chunks, ex.schema)
-    base = 3 << 48
-    assert rows == [(base, 10), (base + 1, 20), (base + 2, 30)]
+    base = (3 << 48) + 5
+    assert rows == [(7, 10, base), (9, 25, base + 1), (None, 30, base + 2)]
+    assert int(ex.next_id) == base + 3
+    assert all(c.columns[-1].data.dtype == jnp.int64 for c in chunks)
+    # one program for the layout, whoever runs it: not one an executor
+    programs = append_row_ids._cache_size()
+    ex2 = RowIdGenExecutor(MockSource(TS, [
+        Barrier.new(1), make_chunk(TS, [(1, 1)], capacity=4), Barrier.new(2),
+    ]), out_schema, RowIdSequence(4))
+    chunks, _, _ = run(drain(ex2))
+    assert rows_of(chunks, ex2.schema) == [(1, 1, 4 << 48)]
+    assert append_row_ids._cache_size() == programs
 
 
 def test_watermark_filter_drops_late_rows():

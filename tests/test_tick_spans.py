@@ -67,8 +67,7 @@ EVERY_BARRIER = {
     "shardfused": FUSED_DRIVER,
     "exec": {**CONDUCTOR,
              **{f"{ident}.{kind}": "barrier.collect"
-                for ident in ("RowIdAppend", "RowIdGen", "Project",
-                              "HashAgg")
+                for ident in ("Project", "HashAgg")
                 for kind in ("chunks", "barrier")},
              "agg.flush_wait": "HashAgg.barrier"},
 }
@@ -394,9 +393,12 @@ def test_chunk_rollup_counts_sixteen_chunks_per_operator(tmp_path):
     s, by_epoch = run("exec", tmp_path, ticks=4)
     try:
         for spans in by_epoch.values():
-            rollups = {d["name"]: d for d in spans
+            # by name, the executor nearest the source last (two Projects)
+            rollups = {d["name"]: d
+                       for d in sorted(spans, key=lambda d: d["args"].get(
+                           "node", -1))
                        if d["name"].endswith(".chunks")}
-            for ident in ("RowIdGen", "HashAgg"):
+            for ident in ("Project", "HashAgg"):
                 args = rollups[f"{ident}.chunks"]["args"]
                 assert args["chunks"] == CHUNKS
                 assert args["capacity_rows"] == CHUNKS * CAP
@@ -806,7 +808,9 @@ def test_source_feed_counts_what_it_staged(path, tmp_path, q8_run):
     """``source.feed`` (ISSUE 30): a feed's chunks of a barrier are staged
     together — one transfer per dtype of its schema and one unpack
     dispatch — and the args add up over the feeds: 16 bid chunks are 2 + 1
-    where they were 272 copies."""
+    where they were 272 copies. Since ISSUE 36 the same dispatch makes the
+    hidden ``_row_id``: ``row_ids`` = the rows fed, ``dispatches`` what it
+    was (one a feed), and no executor of the plan spends a span on ids."""
     if path == "exec":
         s, by_epoch = run("exec", tmp_path, ticks=3)
         feeds, chunks, cap_rows = 1, CHUNKS, CHUNKS * CAP
@@ -823,7 +827,9 @@ def test_source_feed_counts_what_it_staged(path, tmp_path, q8_run):
             assert feed["args"] == {
                 "chunks": chunks, "capacity_rows": cap_rows,
                 "transfers": 2 * feeds, "dispatches": feeds,
-                "bytes_staged": nbytes}
+                "bytes_staged": nbytes, "row_ids": cap_rows}
+            assert not [d["name"] for d in spans
+                        if d["name"].startswith("RowId")]
     finally:
         if path == "exec":
             s.close()
@@ -966,9 +972,18 @@ def test_every_executor_of_a_plan_has_a_clock_and_a_node(cell_run):
                   if hasattr(ex, "stats"))
     if cell in DEFAULT_PATH:
         idents = {ident for ident, _node in want}
-        assert {"RowIdAppend", "RowIdGen", "Project", "Materialize"} <= idents
+        assert {"Project", "Materialize"} <= idents
         # two executors of one identity in every default-path plan
         assert len(idents) < len(want)
+        # ISSUE 36: a connector's chunks are staged with their _row_id, so
+        # no executor stands between a source's queue and its Project
+        assert not [ident for ident in idents if ident.startswith("RowId")]
+        fed = {id(f.queue) for f in s.feeds}
+        over_queue = [ex for ex in plan if id(getattr(ex, "input", None)) in fed]
+        assert len(over_queue) == len(fed) == len(job.sources)
+        assert {ex.identity for ex in over_queue} == {"Project"}
+        assert all(f.reader is not None and f.row_ids is not None
+                   for f in s.feeds)
     for epoch, spans in by_epoch.items():
         for kind in (".chunks", ".barrier"):
             got = sorted((d["name"][:-len(kind)], d["args"]["node"])
@@ -1019,18 +1034,19 @@ def test_executor_steps_are_in_the_profilers_trace(cell_run):
                 rollups[d["name"]] = (rollups.get(d["name"], 0)
                                       + d["args"]["chunks"])
     owed = {"fused": ("Materialize.chunks",),
-            "mesh": ("RowIdAppend.chunks", "RowIdGen.chunks",
-                     "ShardedHashAgg.chunks", "Materialize.chunks",
-                     "shard.split")}.get(
-        cell, ("RowIdAppend.chunks", "RowIdGen.chunks", "HashAgg.chunks",
-               "Materialize.chunks"))
+            "mesh": ("Project.chunks", "ShardedHashAgg.chunks",
+                     "Materialize.chunks", "shard.split")}.get(
+        cell, ("Project.chunks", "HashAgg.chunks", "Materialize.chunks"))
     assert set(owed) <= set(rollups)
+    # ISSUE 36: no barrier of any cell's plan has a row-id span or step
+    assert not [name for name in set(names) | set(events)
+                if name.startswith(("RowIdAppend", "RowIdGen"))]
     for name, chunks in rollups.items():
         steps = events.get(name, [])
         if name == "HashJoin.chunks":
             # a step a chunk and one a flush of pending output
             assert len(steps) >= chunks > 0
-        elif name in ("RowIdAppend.chunks", "shard.split"):
+        elif name == "shard.split":
             assert len(steps) == chunks > 0, name
         else:
             # each chunk: a step an output and the closing StopIteration
