@@ -234,19 +234,24 @@ class JoinCore:
         return state, StreamChunk(ops, vis, cols)
 
     def emit_counts(self, big: StreamChunk) -> tuple:
-        """``(rows_out, null_padded_out, transitions)`` of one step's
-        emission grid, as int64 scalars: the visible rows; those on the
-        ``pself`` lane (lane 2W: the input row NULL-padded, or a semi /
-        anti join's own row); and the degree transitions 0 -> 1 / 1 -> 0
-        of the opposite side that the step emitted — the second rows of
-        an outer join's update pairs (lanes 2w+1), a semi / anti join's
-        opposite rows (lanes 2w; its own-side passes leave them empty)."""
+        """``(rows_out, null_padded_out, transitions, matched, unmatched)``
+        of one step's emission grid, as int64 scalars: the visible rows;
+        those on the ``pself`` lane (lane 2W: the input row NULL-padded,
+        or a semi / anti join's own row); and the degree transitions of
+        the opposite side that the step emitted — the second rows of an
+        outer join's update pairs (lanes 2w+1), a semi / anti join's
+        opposite rows (lanes 2w; its own-side passes leave them empty) —
+        in all and by direction: ``matched`` 0 -> 1 (the insert pass, the
+        grid's second half), ``unmatched`` 1 -> 0 (the delete pass, its
+        first)."""
         W = self.W
-        vis = big.vis.reshape(-1, 2 * W + 1)
+        vis = big.vis.reshape(2, -1, 2 * W + 1)       # [delete | insert] pass
         first = 1 if self.join_type.semi_anti_side is None else 0
+        unmatched, matched = jnp.sum(vis[:, :, first:2 * W:2], axis=(1, 2),
+                                     dtype=jnp.int64)
         return (jnp.sum(vis, dtype=jnp.int64),
-                jnp.sum(vis[:, 2 * W], dtype=jnp.int64),
-                jnp.sum(vis[:, first:2 * W:2], dtype=jnp.int64))
+                jnp.sum(vis[:, :, 2 * W], dtype=jnp.int64),
+                matched + unmatched, matched, unmatched)
 
     # -- internals -------------------------------------------------------------
 

@@ -96,9 +96,12 @@ class HashJoinExecutor(Executor):
         # probe row — incremental null-aware anti join is a global flip
         # this executor does not implement, so it rejects loudly instead
         # of silently diverging from PG (NULL probe keys are already
-        # filtered below the join at plan time).
-        self.null_aware_anti = bool(null_aware_anti) and \
-            join_type == JoinType.LEFT_ANTI
+        # filtered below the join at plan time). The build side's NULL
+        # keys are counted on the device into the packed stats
+        # (``_null_keys``: per side the key columns to look at; no entry
+        # on any other plan, whose stats vector is one slot shorter).
+        self._null_keys = {"left": (), "right": tuple(right_keys)} \
+            if null_aware_anti and join_type == JoinType.LEFT_ANTI else {}
         from .metrics import ExecutorStats
         self.stats = ExecutorStats()
         # an insert may refill a lane tombstoned since the last checkpoint;
@@ -144,8 +147,8 @@ class HashJoinExecutor(Executor):
         self.state = self.core.init_state()
         # what HashJoin.chunks reports for the epoch, reset at its barrier
         self._epoch_counts = {"rows_in_left": 0, "rows_in_right": 0,
-                              "rows_out": 0, "null_padded_out": 0,
-                              "transitions": 0, "rewinds": 0, "grows": 0}
+                              **dict.fromkeys(EMIT_COUNTS, 0),
+                              "rewinds": 0, "grows": 0}
         self._make_jits()
         if any(self.state_tables.values()):
             self._load_from_state_tables()
@@ -176,7 +179,8 @@ class HashJoinExecutor(Executor):
             def body(st, x):
                 ch, step = x if steps is not None else (x, None)
                 st, big = core.apply_chunk(st, ch, side=side, step=step)
-                return st, (pack_stats(core, _flags(st), big, ch), big)
+                return st, (pack_stats(core, _flags(st), big, ch,
+                                       self._null_keys.get(side)), big)
 
             xs = (batched_chunk, steps) if steps is not None \
                 else batched_chunk
@@ -205,11 +209,13 @@ class HashJoinExecutor(Executor):
 
         self._gather = jax.jit(join_gather)
 
-        def join_pack_stats(flags, big, ch):
-            return pack_stats(core, flags, big, ch)
+        def join_pack_stats(flags, big, ch, side):
+            return pack_stats(core, flags, big, ch, self._null_keys.get(side))
 
-        self._pack_stats = jax.jit(join_pack_stats)
-        self._no_stats = jnp.zeros(9, jnp.int64)      # pads a stats fetch
+        self._pack_stats = jax.jit(join_pack_stats, static_argnums=(3,))
+        # pads a stats fetch
+        self._no_stats = jnp.zeros(
+            N_STATS + bool(self._null_keys), jnp.int64)
         self._clear_ckpt = jax.jit(_clear_ckpt_marks)
         # reads a side and leaves it in place (not donated); a device
         # trace shows it as jit_join_ckpt_delta_window
@@ -283,18 +289,31 @@ class HashJoinExecutor(Executor):
 
     def _fetch_stats(self, packed) -> np.ndarray:
         """The packed stats of the chunks applied since the last sync: the
-        host blocks here until the device has run every one of them."""
+        host blocks here until the device has run every one of them. A
+        NOT IN plan's vectors end in the build-side rows whose key is
+        NULL: PG would return zero rows for the WHOLE view, which
+        incrementally means retracting everything already emitted —
+        unsupported; fail with an actionable message instead of
+        diverging, before the epoch's barrier is passed on (nothing of
+        the chunk is committed)."""
         with span("join.emit_wait", epoch=conductor_epoch(), wait="device",
                   parent="barrier.collect", tid=self.identity):
-            return np.asarray(packed)
+            rows = np.asarray(packed)
+        if self._null_keys and rows[..., N_STATS].any():
+            raise RuntimeError(
+                "NULL value in NOT IN (SELECT ...) subquery: PostgreSQL "
+                "semantics would drop every row of the view, which a "
+                "streaming anti join cannot express incrementally — "
+                "filter NULLs in the subquery (WHERE col IS NOT NULL) "
+                "or use NOT EXISTS")
+        return rows
 
     def _count(self, side: str, rows: np.ndarray) -> None:
-        """Add the packed stats of applied chunks (``[k, 9]``) to what
-        ``HashJoin.chunks`` reports for the epoch."""
+        """Add the packed stats of applied chunks (``[k, N_STATS]``) to
+        what ``HashJoin.chunks`` reports for the epoch."""
         counts = self._epoch_counts
         counts[f"rows_in_{side}"] += int(rows[:, 5].sum())
-        for i, name in enumerate(
-                ("rows_out", "null_padded_out", "transitions"), 6):
+        for i, name in enumerate(EMIT_COUNTS, 6):
             counts[name] += int(rows[:, i].sum())
 
     def _gather_units(self, big, n_units: int):
@@ -305,7 +324,8 @@ class HashJoinExecutor(Executor):
     def _replay_growing(self, side: str, chunk: StreamChunk):
         """One chunk of a rewound batch again, through the growing path."""
         big = self._apply_growing(side, chunk)
-        row = np.asarray(self._pack_stats(_flags(self.state), big, chunk))
+        row = self._fetch_stats(
+            self._pack_stats(_flags(self.state), big, chunk, side))
         self._count(side, row[None])
         yield from self._gather_units(big, int(row[4]))
 
@@ -339,8 +359,6 @@ class HashJoinExecutor(Executor):
     # and-loop default paid K dispatches + K syncs per batch.
 
     def _consume_batch(self, side: str, batch):
-        if self.null_aware_anti and side == "right":
-            self._reject_null_build_keys(flatten_shards(batch.chunk))
         if self._evicted:
             hits = self._evicted_hits(side, flatten_shards(batch.chunk))
             if hits:
@@ -403,8 +421,6 @@ class HashJoinExecutor(Executor):
                 stats.chunks_in += 1
                 stats.capacity_rows_in += chunk.capacity
                 clock.begin()
-                if self.null_aware_anti and side == "right":
-                    self._reject_null_build_keys(chunk)
                 if self._evicted:
                     hits = self._evicted_hits(side, chunk)
                     if hits:
@@ -423,7 +439,8 @@ class HashJoinExecutor(Executor):
                 self.state = new_state
                 self._pending.append(
                     (side, chunk,
-                     self._pack_stats(_flags(new_state), big, chunk), big))
+                     self._pack_stats(_flags(new_state), big, chunk, side),
+                     big))
                 clock.end()
                 if len(self._pending) >= self.emit_batch:
                     for out in clock.timed(self._flush_pending()):
@@ -469,23 +486,6 @@ class HashJoinExecutor(Executor):
                     for out in self._flush_pending():
                         yield out
                     yield wm.__class__(out_idx, wm.value)
-
-    def _reject_null_build_keys(self, chunk: StreamChunk) -> None:
-        """NULL-aware anti join (NOT IN): a NULL subquery value makes PG
-        return zero rows for the WHOLE view, which incrementally means
-        retracting everything already emitted — unsupported; fail with an
-        actionable message instead of diverging. One host sync per
-        build-side chunk, only on NOT IN plans."""
-        keyed = chunk.vis
-        for i in self.core.right_keys:
-            keyed = keyed & chunk.columns[i].mask
-        if bool(jnp.any(chunk.vis & ~keyed)):
-            raise RuntimeError(
-                "NULL value in NOT IN (SELECT ...) subquery: PostgreSQL "
-                "semantics would drop every row of the view, which a "
-                "streaming anti join cannot express incrementally — "
-                "filter NULLs in the subquery (WHERE col IS NOT NULL) "
-                "or use NOT EXISTS")
 
     # -- eviction / fault-in ---------------------------------------------------
 
@@ -736,17 +736,32 @@ def _flags(state: JoinState) -> tuple:
             state.right.lane_overflow, state.right.ht_overflow)
 
 
-def pack_stats(core: JoinCore, flags: tuple, big, chunk) -> jax.Array:
+#: what ``JoinCore.emit_counts`` gives, in the packed stats from slot 6 on
+EMIT_COUNTS = ("rows_out", "null_padded_out", "transitions", "matched",
+               "unmatched")
+#: slots of the packed stats every plan has
+N_STATS = 6 + len(EMIT_COUNTS)
+
+
+def pack_stats(core: JoinCore, flags: tuple, big, chunk,
+               null_keys=None) -> jax.Array:
     """Every host-read scalar of one applied chunk in ONE vector:
     [l.lane_ovf, l.ht_ovf, r.lane_ovf, r.ht_ovf, n_units, rows_in,
-    rows_out, null_padded_out, transitions] (the last three:
-    ``JoinCore.emit_counts``)."""
-    return jnp.stack([
+    *EMIT_COUNTS] and, where ``null_keys`` is given (a NOT IN plan: the
+    chunk's key columns to look at, none on the probe side), the visible
+    rows of the chunk with a NULL among them."""
+    stats = [
         *(f.astype(jnp.int64) for f in flags),
         count_units(big),
         jnp.sum(chunk.vis, dtype=jnp.int64),
         *core.emit_counts(big),
-    ])
+    ]
+    if null_keys is not None:
+        keyed = chunk.vis
+        for i in null_keys:
+            keyed = keyed & chunk.columns[i].mask
+        stats.append(jnp.sum(chunk.vis & ~keyed, dtype=jnp.int64))
+    return jnp.stack(stats)
 
 
 def _clear_ckpt_marks(state: JoinState) -> JoinState:

@@ -105,7 +105,9 @@ class FilterExecutor(SingleInputExecutor):
         self.schema = input.schema
         self.predicate = predicate
 
-        def _step(chunk: StreamChunk) -> StreamChunk:
+        # a name of its own: a profiler's trace and the idle-gap labels
+        # tell jit_filter_step from Project's jit__step
+        def filter_step(chunk: StreamChunk) -> StreamChunk:
             cond = predicate.eval(chunk)
             keep = cond.data & cond.mask  # NULL -> filtered out (SQL WHERE)
             # Degrade broken update pairs to Insert/Delete: a U- whose U+ was
@@ -124,11 +126,11 @@ class FilterExecutor(SingleInputExecutor):
 
         from ..expr.expr import uses_host_callback
         if uses_host_callback(predicate):
-            self._step = _step          # eager: see ProjectExecutor note
+            self._step = filter_step    # eager: see ProjectExecutor note
             self._step_batch = None
         else:
-            self._step = jax.jit(_step)
-            self._step_batch = jax.jit(jax.vmap(_step))
+            self._step = jax.jit(filter_step)
+            self._step_batch = jax.jit(jax.vmap(filter_step))
 
     async def map_chunk(self, chunk: StreamChunk):
         yield self._step(chunk)

@@ -431,7 +431,8 @@ def test_refill_of_another_state_key_buries_the_old_row():
 
 def test_emit_counts_read_the_outer_join_mechanism_off_the_grid():
     """What ``HashJoin.chunks`` reports as rows_out / null_padded_out /
-    transitions (``JoinCore.emit_counts``, summed on the device)."""
+    transitions / matched / unmatched (``JoinCore.emit_counts``, summed
+    on the device)."""
     from risingwave_tpu.ops.join_state import JoinCore
 
     def counts(core, st, chunk, side):
@@ -443,24 +444,38 @@ def test_emit_counts_read_the_outer_join_mechanism_off_the_grid():
                     key_capacity=16, bucket_width=1)
     st = core.init_state()
     st, got = counts(core, st, lchunk([(1, 100), (2, 200)]), "left")
-    assert got == (2, 2, 0)                  # two NULL-padded rows
+    assert got == (2, 2, 0, 0, 0)            # two NULL-padded rows
     st, got = counts(core, st, rchunk([(1, 10), (3, 30)]), "right")
-    assert got == (2, 0, 1)                  # one pair replaces a padded row
+    assert got == (2, 0, 1, 1, 0)            # one pair replaces a padded row
     st, got = counts(core, st, rchunk([(1, 10), (1, 11)], ops=[U_, UP]),
                      "right")
-    assert got == (4, 0, 2)                  # 1 -> 0 and 0 -> 1: two pairs
+    assert got == (4, 0, 2, 1, 1)            # 1 -> 0 and 0 -> 1: two pairs
     st, got = counts(core, st, lchunk([(3, 300)]), "left")
-    assert got == (1, 0, 0)                  # a plain matched insert
+    assert got == (1, 0, 0, 0, 0)            # a plain matched insert
+    st, got = counts(core, st, rchunk([(3, 30)], ops=[OP_DELETE]), "right")
+    assert got == (2, 0, 1, 0, 1)            # the padded row comes back
 
     semi = JoinCore(L_SCHEMA, R_SCHEMA, [0], [0], JoinType.LEFT_SEMI,
                     key_capacity=16, bucket_width=2)
     st = semi.init_state()
     st, got = counts(semi, st, lchunk([(1, 100), (2, 200)]), "left")
-    assert got == (0, 0, 0)
+    assert got == (0, 0, 0, 0, 0)
     st, got = counts(semi, st, rchunk([(1, 10)]), "right")
-    assert got == (1, 0, 1)                  # the left row appears
+    assert got == (1, 0, 1, 1, 0)            # the left row appears
     st, got = counts(semi, st, lchunk([(1, 101)]), "left")
-    assert got == (1, 1, 0)                  # its own row, on the self lane
+    assert got == (1, 1, 0, 0, 0)            # its own row, on the self lane
+
+    anti = JoinCore(L_SCHEMA, R_SCHEMA, [0], [0], JoinType.LEFT_ANTI,
+                    key_capacity=16, bucket_width=1)
+    st = anti.init_state()
+    st, got = counts(anti, st, lchunk([(1, 100), (2, 200)]), "left")
+    assert got == (2, 2, 0, 0, 0)            # both own rows: no match yet
+    st, got = counts(anti, st, rchunk([(1, 10), (3, 30)]), "right")
+    assert got == (1, 0, 1, 1, 0)            # row 1 is retracted
+    st, got = counts(anti, st, rchunk([(1, 10)], ops=[OP_DELETE]), "right")
+    assert got == (1, 0, 1, 0, 1)            # and comes back
+    st, got = counts(anti, st, lchunk([(3, 300)]), "left")
+    assert got == (0, 0, 0, 0, 0)            # matched on arrival: never shown
 
 
 def test_a_new_count_of_pending_chunks_compiles_nothing():

@@ -904,8 +904,9 @@ CELL_CONFIGS = {"fused": "nexmark-q5core-fused",
                 "exec": "nexmark-q5core-exec",
                 "q8": "nexmark-q8",
                 "q101": "nexmark-q101",
+                "q104": "nexmark-q104",
                 "mesh": "nexmark-q5core-exec-mesh4"}
-DEFAULT_PATH = ("exec", "q8", "q101", "mesh")
+DEFAULT_PATH = ("exec", "q8", "q101", "q104", "mesh")
 #: the store writer's children (``commit.pending`` and ``store.apply`` sit
 #: beside it, under ``checkpoint.commit``)
 COMMIT_PARTS = ("commit.pending", "DurableStateStore.commit", "store.apply")
@@ -1062,7 +1063,7 @@ def test_checkpoint_by_part_under_every_delta_and_the_commit(cell_run):
     cell, _s, by_epoch, history, _log = cell_run
     parts = set(DELTA_PARTS + SEGMENT_PARTS + COMMIT_PARTS)
     deltas_a_checkpoint = {"fused": 1, "exec": 1, "mesh": 1, "q8": 4,
-                           "q101": 3}[cell]
+                           "q101": 3, "q104": 3}[cell]
     checkpoints = 0
     for epoch, spans in by_epoch.items():
         if not history[epoch]["checkpoint"]:
