@@ -21,6 +21,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..common.packed import PackedBatch, PackedColumn
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "rowcodec.cpp")
 
@@ -71,9 +73,9 @@ def _build() -> Optional[ctypes.CDLL]:
     lib.rw_encode_segment_table.restype = ctypes.c_longlong
     lib.rw_encode_segment_table.argtypes = [
         ctypes.c_char_p, _LL_P, ctypes.c_char_p, _LL_P,
-        _UB_P, ctypes.c_longlong, _UB_P, ctypes.c_longlong,
+        _UB_P, ctypes.c_longlong, _UB_P, ctypes.c_longlong, _LL_P,
     ]
-    if lib.rw_abi_version() != 2:
+    if lib.rw_abi_version() != 3:
         return None
     return lib
 
@@ -156,12 +158,12 @@ class RowCodec:
             blob_bytes
 
     def _encode(self, key_mode: int, datas, masks, types,
-                indices: np.ndarray) -> list:
+                indices: np.ndarray) -> PackedColumn:
         n = len(types)
         sel = np.ascontiguousarray(indices, np.int64)
         n_sel = len(sel)
         if n_sel == 0:
-            return []
+            return PackedColumn.empty()
         # gather the dirty delta FIRST: all per-column prep (string
         # uniquing, dtype coercion) must scale with the delta, not the
         # full state capacity
@@ -175,68 +177,93 @@ class RowCodec:
         # ≤ 2x its longest string (escape doubling) + framing per row
         cap = n_sel * (9 * n + 8 + 2 * blob_bytes + 6) + 64
         for _ in range(3):
-            out = np.zeros(cap, np.uint8)
+            out = np.empty(cap, np.uint8)
             written = self.lib.rw_encode(
                 key_mode, n, codes, data_ptrs, mask_ptrs, blob_ptrs,
-                off_ptrs, idx.ctypes.data_as(
-                    ctypes.POINTER(ctypes.c_longlong)),
-                n_sel,
-                out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
-                cap,
-                out_offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_longlong)))
+                off_ptrs, idx.ctypes.data_as(_LL_P), n_sel,
+                out.ctypes.data_as(_UB_P), cap,
+                out_offsets.ctypes.data_as(_LL_P))
             if written >= 0:
-                buf = out.tobytes()
-                return [buf[out_offsets[r]:out_offsets[r + 1]]
-                        for r in range(n_sel)]
+                return PackedColumn(out[:written].tobytes(), out_offsets)
             cap *= 4
         raise RuntimeError("native row encode: buffer growth failed")
 
-    def encode_value_rows(self, datas, masks, types, indices) -> list:
-        """Columnar buffers -> value-encoded bytes per selected row
-        (byte-identical to common/row.py encode_value_row)."""
+    def pack_value_rows(self, datas, masks, types, indices) -> PackedColumn:
+        """Columnar buffers -> the selected rows value-encoded
+        (byte-identical to common/row.py encode_value_row), packed."""
         return self._encode(0, datas, masks, types, indices)
 
-    def encode_keys(self, datas, masks, types, indices) -> list:
-        """Columnar buffers -> memcomparable key bytes per selected row
-        (byte-identical to common/row.py encode_key)."""
+    def pack_keys(self, datas, masks, types, indices) -> PackedColumn:
+        """Columnar buffers -> the selected rows' memcomparable keys
+        (byte-identical to common/row.py encode_key), packed."""
         return self._encode(1, datas, masks, types, indices)
 
-    def encode_segment_table(self, buf: dict) -> Optional[np.ndarray]:
-        """One table's delta ``{key: value | None}`` -> its rows as a
-        checkpoint segment lays them out (byte-identical to the row loop of
-        storage/checkpoint.py ``_encode_segment_py``: ordered by key,
-        ``<H klen> key 0x00`` for a tombstone, ``<H klen> key 0x01 <I vlen>
-        value`` for a put), or None where a length does not fit the layout.
-        The dict is taken apart with C-speed calls only: nothing here runs
-        once a row in Python."""
+    def encode_value_rows(self, datas, masks, types, indices) -> list:
+        """``pack_value_rows`` cut into one ``bytes`` a row."""
+        return self.pack_value_rows(datas, masks, types, indices).cut()
+
+    def encode_keys(self, datas, masks, types, indices) -> list:
+        """``pack_keys`` cut into one ``bytes`` a row."""
+        return self.pack_keys(datas, masks, types, indices).cut()
+
+    @staticmethod
+    def _unpack_dict(buf: dict) -> tuple:
+        """A dict layer ``{key: value | None}`` -> ``(key_blob, key_lens,
+        val_blob, val_lens, live)``, the values those of the live rows
+        alone, with C-speed calls only."""
         n = len(buf)
-        if n == 0:
-            return np.empty(0, np.uint8)
         keys = list(buf)
         vals = list(buf.values())
         klens = np.fromiter(map(len, keys), np.int64, count=n)
         n_live = n - vals.count(None)
         if n_live == n:
             live = np.ones(n, np.uint8)
-            vlens = np.fromiter(map(len, vals), np.int64, count=n)
         else:
             live = np.fromiter(map(operator.is_not, vals, repeat(None)),
                                np.uint8, count=n)
             vals = list(compress(vals, live.tolist()))
-            vlens = np.zeros(n, np.int64)
-            vlens[live.view(np.bool_)] = np.fromiter(
-                map(len, vals), np.int64, count=n_live)
-        if klens.max() > 0xFFFF or vlens.max() > 0xFFFFFFFF:
+        vlens = np.fromiter(map(len, vals), np.int64, count=n_live)
+        return b"".join(keys), klens, b"".join(vals), vlens, live
+
+    def encode_segment_table(self, layers: list) -> Optional[tuple]:
+        """One table's delta layers (common/packed.py: packed batches and
+        dicts ``{key: value | None}``, in application order) -> ``(block,
+        rows)``: its rows as a checkpoint segment lays them out and how
+        many there are (byte-identical to the row loop of
+        storage/checkpoint.py ``_encode_segment_py`` over the layers' dict
+        view: ordered by key, the last row of a key alone, ``<H klen> key
+        0x00`` for a tombstone, ``<H klen> key 0x01 <I vlen> value`` for a
+        put), or None where a length does not fit the layout. A packed
+        batch is handed on as it is and a dict is taken apart with C-speed
+        calls only: nothing here runs once a row in Python."""
+        parts = [
+            (layer.keys.blob, layer.keys.lens(), layer.values.blob,
+             layer.values.lens(), layer.live)
+            if isinstance(layer, PackedBatch) else self._unpack_dict(layer)
+            for layer in layers if len(layer)]
+        if not parts:
+            return np.empty(0, np.uint8), 0
+        if len(parts) == 1:
+            key_blob, klens, val_blob, vlens, live = parts[0]
+        else:
+            key_blobs, klens, val_blobs, vlens, live = zip(*parts)
+            key_blob, val_blob = b"".join(key_blobs), b"".join(val_blobs)
+            klens, vlens, live = (np.concatenate(klens),
+                                  np.concatenate(vlens),
+                                  np.concatenate(live))
+        if klens.max() > 0xFFFF or vlens.max(initial=0) > 0xFFFFFFFF:
             return None
-        size = 3 * n + int(klens.sum()) + 4 * n_live + int(vlens.sum())
+        n = len(live)
+        # room for every row; the rows a later one shadows are not written
+        size = 3 * n + len(key_blob) + 4 * len(vlens) + len(val_blob)
         out = np.empty(size, np.uint8)
-        key_blob, val_blob = b"".join(keys), b"".join(vals)
+        rows = ctypes.c_longlong(0)
         written = self.lib.rw_encode_segment_table(
             key_blob, klens.ctypes.data_as(_LL_P),
             val_blob, vlens.ctypes.data_as(_LL_P),
             live.ctypes.data_as(_UB_P), n,
-            out.ctypes.data_as(_UB_P), size)
-        if written != size:
+            out.ctypes.data_as(_UB_P), size, ctypes.byref(rows))
+        if written < 0 or written > size:
             raise RuntimeError(
                 f"native segment encode: wrote {written} of {size} bytes")
-        return out
+        return out[:written], rows.value

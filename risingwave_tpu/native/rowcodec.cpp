@@ -24,7 +24,10 @@
 // Segment table block (storage/checkpoint.py _encode_segment): a table's
 // rows ordered by key as Python's bytes compare (memcmp, the shorter first
 // on a tie), each  u16 LE klen + key + 0x00  (tombstone)  or
-// u16 LE klen + key + 0x01 + u32 LE vlen + value  (put).
+// u16 LE klen + key + 0x01 + u32 LE vlen + value  (put). The caller's rows
+// are a table's delta layers back to back in application order, so a key
+// may come more than once: the sort is stable (ties by row number) and
+// only the LAST row of a key is laid out.
 
 #include <algorithm>
 #include <cstdint>
@@ -210,26 +213,34 @@ struct SegKey {
     uint32_t row;
 };
 
+inline bool seg_key_equal(const SegKey& a, const SegKey& b) {
+    return a.prefix == b.prefix && a.len == b.len
+        && (a.len == 0 || std::memcmp(a.p, b.p, a.len) == 0);
+}
+
+// by key, then by the caller's row number: what a stable sort would give
 inline bool seg_key_less(const SegKey& a, const SegKey& b) {
     if (a.prefix != b.prefix) return a.prefix < b.prefix;
     uint32_t m = a.len < b.len ? a.len : b.len;
     int c = m ? std::memcmp(a.p, b.p, m) : 0;
     if (c != 0) return c < 0;
-    return a.len < b.len;
+    if (a.len != b.len) return a.len < b.len;
+    return a.row < b.row;
 }
 
 inline long long encode_segment_table(
         const unsigned char* keys, const long long* key_lens,
         const unsigned char* vals, const long long* val_lens,
         const unsigned char* live, long long n,
-        unsigned char* out, long long out_cap) {
+        unsigned char* out, long long out_cap, long long* rows_kept) {
     if (n > 0xFFFFFFFFLL) return -2;
     std::vector<SegKey> order((size_t)n);
     std::vector<long long> val_off((size_t)n);
-    long long kpos = 0, vpos = 0, need = 0;
+    std::vector<uint32_t> val_len((size_t)n);
+    long long kpos = 0, vpos = 0, need = 0, n_live = 0;
     for (long long r = 0; r < n; ++r) {
         long long kl = key_lens[r];
-        long long vl = live[r] ? val_lens[r] : 0;
+        long long vl = live[r] ? val_lens[n_live++] : 0;
         if (kl < 0 || kl > 0xFFFF || vl < 0 || vl > 0xFFFFFFFFLL) return -2;
         SegKey& k = order[(size_t)r];
         k.p = keys + kpos;
@@ -241,14 +252,20 @@ inline long long encode_segment_table(
         }
         k.prefix = prefix;
         val_off[(size_t)r] = vpos;
+        val_len[(size_t)r] = (uint32_t)vl;
         kpos += kl;
         vpos += vl;
         need += 2 + kl + 1 + (live[r] ? 4 + vl : 0);
     }
     if (need > out_cap) return -1;
     std::sort(order.begin(), order.end(), seg_key_less);
-    long long pos = 0;
-    for (const SegKey& k : order) {
+    long long pos = 0, kept = 0;
+    for (size_t i = 0; i < order.size(); ++i) {
+        const SegKey& k = order[i];
+        if (i + 1 < order.size() && seg_key_equal(k, order[i + 1])) {
+            continue;                 // a later row of this key wins
+        }
+        ++kept;
         out[pos++] = (unsigned char)(k.len & 0xff);
         out[pos++] = (unsigned char)(k.len >> 8);
         std::memcpy(out + pos, k.p, k.len);
@@ -258,7 +275,7 @@ inline long long encode_segment_table(
             continue;
         }
         out[pos++] = 0x01;
-        uint32_t vl = (uint32_t)val_lens[k.row];
+        uint32_t vl = val_len[k.row];
         out[pos++] = (unsigned char)(vl & 0xff);
         out[pos++] = (unsigned char)((vl >> 8) & 0xff);
         out[pos++] = (unsigned char)((vl >> 16) & 0xff);
@@ -266,6 +283,7 @@ inline long long encode_segment_table(
         std::memcpy(out + pos, vals + val_off[k.row], vl);
         pos += vl;
     }
+    *rows_kept = kept;
     return pos;
 }
 
@@ -297,24 +315,27 @@ long long rw_encode(int key_mode, int ncols, const int* typecodes,
 }
 
 // One table's rows of a checkpoint segment. keys / vals are the rows' keys
-// and values back to back in the caller's order, key_lens / val_lens their
-// lengths (a tombstone's value length is not read), live[r] == 0 marks a
-// tombstone. Returns the bytes written; -1 if out_cap is too small; -2 if
-// a length does not fit the layout (key > 65,535 bytes, value > 4 GiB - 1);
+// and values back to back in application order (a table's delta layers, one
+// after the other), live[r] == 0 marks a tombstone; key_lens holds a length
+// a row, val_lens and vals a length and a value a LIVE row (a tombstone has
+// none). Of the rows of one key only the last is written.
+// Returns the bytes written and, in *rows_kept, the rows written; -1 if
+// out_cap (sized for ALL n rows by the caller) is too small; -2 if a
+// length does not fit the layout (key > 65,535 bytes, value > 4 GiB - 1);
 // -3 if memory ran out.
 long long rw_encode_segment_table(
         const unsigned char* keys, const long long* key_lens,
         const unsigned char* vals, const long long* val_lens,
         const unsigned char* live, long long n,
-        unsigned char* out, long long out_cap) {
+        unsigned char* out, long long out_cap, long long* rows_kept) {
     try {
         return encode_segment_table(keys, key_lens, vals, val_lens, live, n,
-                                    out, out_cap);
+                                    out, out_cap, rows_kept);
     } catch (...) {
         return -3;
     }
 }
 
-int rw_abi_version() { return 2; }
+int rw_abi_version() { return 3; }
 
 }  // extern "C"

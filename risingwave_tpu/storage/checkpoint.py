@@ -35,6 +35,7 @@ import struct
 import threading
 from typing import Optional
 
+from ..common.packed import dict_view, packed_rows
 from ..common.tracing import CAT_STORAGE, span
 from .object_store import ObjectStore, open_object_store, wrap_object_store
 from .state_store import MemoryStateStore
@@ -67,6 +68,13 @@ def _part(name: str, **args):
     ``epoch_spans()`` skips them. No ledger stage: the writer's span
     holds their time."""
     return span(name, epoch=None, cat=CAT_STORAGE, tid="storage", **args)
+
+
+def _layers_of(delta) -> list:
+    """A table's delta as the segment writer takes it: its layers
+    (common/packed.py) in application order, or one dict ``{key: value |
+    None}``."""
+    return delta if isinstance(delta, list) else [delta]
 
 
 class CheckpointLog:
@@ -157,47 +165,64 @@ class CheckpointLog:
         return b"".join(parts)
 
     @staticmethod
-    def _encode_segment_native(
-            deltas: dict[int, dict[bytes, Optional[bytes]]]
-    ) -> Optional[bytes]:
-        """The same bytes with each table's rows sorted and laid out by the
-        native codec (native/rowcodec.cpp ``rw_encode_segment_table``);
-        None where the codec is absent or refuses a table."""
+    def _segment_native(deltas: dict) -> Optional[tuple]:
+        """``(segment bytes, rows in it)`` with each table's layers sorted
+        and laid out by the native codec (native/rowcodec.cpp
+        ``rw_encode_segment_table``: a packed layer goes in as it is, the
+        last row of a key wins); None where the codec is absent or refuses
+        a table."""
         from ..native import codec
         native = codec()
         if native is None:
             return None
         parts: list = [struct.pack("<I", len(deltas))]
-        for table_id, buf in sorted(deltas.items()):
-            block = native.encode_segment_table(buf)
-            if block is None:
+        rows = 0
+        for table_id, delta in sorted(deltas.items()):
+            encoded = native.encode_segment_table(_layers_of(delta))
+            if encoded is None:
                 return None
-            parts.append(struct.pack("<II", table_id, len(buf)))
+            block, n = encoded
+            parts.append(struct.pack("<II", table_id, n))
             parts.append(block)
-        return b"".join(parts)
+            rows += n
+        return b"".join(parts), rows
 
     @staticmethod
-    def _encode_segment(
-            deltas: dict[int, dict[bytes, Optional[bytes]]]) -> bytes:
+    def _encode_segment_native(deltas: dict) -> Optional[bytes]:
+        encoded = CheckpointLog._segment_native(deltas)
+        return None if encoded is None else encoded[0]
+
+    @staticmethod
+    def _dict_deltas(deltas: dict) -> dict[int, dict[bytes, Optional[bytes]]]:
+        """What ``_encode_segment_py`` takes: every table's layers folded
+        into one dict."""
+        return {t: dict_view(_layers_of(d)) for t, d in deltas.items()}
+
+    @staticmethod
+    def _encode_segment(deltas: dict) -> bytes:
         # a segment is never empty (its table count), so None alone is falsy
         return (CheckpointLog._encode_segment_native(deltas)
-                or CheckpointLog._encode_segment_py(deltas))
+                or CheckpointLog._encode_segment_py(
+                    CheckpointLog._dict_deltas(deltas)))
 
-    def _write_segment(self, name: str,
-                       deltas: dict[int, dict[bytes, Optional[bytes]]]
-                       ) -> dict:
-        """Encode and durably put one segment; returns what the caller's
-        span reports of it (``_segment_counts``)."""
+    def _write_segment(self, name: str, deltas: dict) -> dict:
+        """Encode and durably put one segment of ``{table_id: layers |
+        dict}``; returns what the caller's span reports of it
+        (``_segment_counts``)."""
         from ..common.failpoint import fail_point
         fail_point("checkpoint.segment.write")
         with _part("segment.encode") as encode:
-            payload = self._encode_segment_native(deltas)
-            native = payload is not None
-            if not native:
-                payload = self._encode_segment_py(deltas)
-            counts = _segment_counts(sum(map(len, deltas.values())),
-                                     len(payload), native)
-            encode.set(**counts)
+            encoded = self._segment_native(deltas)
+            native = encoded is not None
+            if native:
+                payload, rows = encoded
+            else:
+                by_dict = self._dict_deltas(deltas)
+                payload = self._encode_segment_py(by_dict)
+                rows = sum(map(len, by_dict.values()))
+            counts = _segment_counts(rows, len(payload), native)
+            encode.set(**counts, packed=sum(
+                packed_rows(_layers_of(d)) for d in deltas.values()))
         try:
             # simulates a torn segment (crash mid-write): a truncated
             # object lands on disk. Safe because the manifest that would
@@ -250,8 +275,7 @@ class CheckpointLog:
     # manifest rewrite per commit
     COMPACT_AFTER = 64
 
-    def append_epoch(self, epoch: int,
-                     deltas: dict[int, dict[bytes, Optional[bytes]]]) -> dict:
+    def append_epoch(self, epoch: int, deltas: dict) -> dict:
         from ..common.failpoint import fail_point
         fail_point("checkpoint.commit")
         counts = _segment_counts()
@@ -286,8 +310,7 @@ class CheckpointLog:
     # the META node one atomic version for the whole cluster;
     # src/meta/src/hummock/manager/ commit_epoch).
 
-    def prepare_epoch(self, epoch: int,
-                      deltas: dict[int, dict[bytes, Optional[bytes]]]) -> dict:
+    def prepare_epoch(self, epoch: int, deltas: dict) -> dict:
         """Phase 1: durably stage an epoch's deltas without committing."""
         from ..common.failpoint import fail_point
         fail_point("checkpoint.prepare")
@@ -541,14 +564,20 @@ class DurableStateStore(MemoryStateStore):
             self._committed = tables
             self.committed_epoch = epoch
 
-    def _pending_deltas(self, epoch: int) -> dict:
+    def _pending_deltas(self, epoch: int) -> dict[int, list]:
+        """``pending_tables(epoch)`` under its span: the layers are handed
+        on unmerged — the segment writer folds them (the last row of a key
+        wins)."""
         with span("commit.pending", epoch=epoch, cat=CAT_STORAGE,
                   tid="storage") as pending:
-            deltas: dict[int, dict[bytes, Optional[bytes]]] = {}
-            for e in sorted(k for k in self._pending if k <= epoch):
-                for table_id, buf in self._pending[e].items():
-                    deltas.setdefault(table_id, {}).update(buf)
-            pending.set(rows=sum(map(len, deltas.values())))
+            deltas = self.pending_tables(epoch)
+            pending.set(
+                rows=sum(len(layer) for layers in deltas.values()
+                         for layer in layers),
+                packed=sum(map(packed_rows, deltas.values())),
+                dict_tables=sorted(
+                    t for t, layers in deltas.items()
+                    if any(isinstance(layer, dict) for layer in layers)))
         return deltas
 
     def prepare(self, epoch: int) -> None:

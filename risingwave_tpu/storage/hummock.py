@@ -33,6 +33,7 @@ import threading
 import uuid
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from ..common.packed import dict_view
 from .checkpoint import PLAN_FORMAT_VERSION
 from .object_store import ObjectStore, open_object_store, wrap_object_store
 from .sstable import Sstable, SstBuilder, load_sst, merge_iter
@@ -365,10 +366,8 @@ class HummockStateStore(MemoryStateStore):
         if epoch <= self.committed_epoch:
             return
         from ..common.tracing import CAT_STORAGE, span
-        deltas: Dict[int, Dict[bytes, Optional[bytes]]] = {}
-        for e in sorted(k for k in self._pending if k <= epoch):
-            for table_id, buf in self._pending[e].items():
-                deltas.setdefault(table_id, {}).update(buf)
+        deltas = {t: dict_view(layers)
+                  for t, layers in self.pending_tables(epoch).items()}
         with span("HummockStateStore.commit", epoch=epoch,
                   cat=CAT_STORAGE, tid="storage", tables=len(deltas)):
             name = self._write_l0(epoch, deltas) if deltas else None

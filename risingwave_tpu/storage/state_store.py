@@ -20,6 +20,9 @@ from __future__ import annotations
 import copy
 from typing import Any, Iterator, Optional
 
+from ..common.packed import (
+    apply_layer, apply_view, layer_view, packed_rows,
+)
 from ..common.tracing import CAT_STORAGE, span
 
 
@@ -35,7 +38,10 @@ class MemoryStateStore:
 
     def __init__(self) -> None:
         self._committed: dict[int, dict[bytes, tuple]] = {}
-        self._pending: dict[int, dict[int, dict[bytes, Optional[tuple]]]] = {}
+        # epoch -> table id -> the table's delta LAYERS in application
+        # order (common/packed.py: packed batches and dicts {key: value |
+        # None}); readers go through pending_layers() / the dict views
+        self._pending: dict[int, dict[int, list]] = {}
         self.committed_epoch: int = 0
         # per-table sorted committed-key cache (range scans / backfill):
         # rebuilt lazily after a commit touches the table
@@ -44,12 +50,34 @@ class MemoryStateStore:
 
     # -- write path -----------------------------------------------------------
 
+    def ingest_layers(self, table_id: int, epoch: int, layers: list) -> None:
+        """Stage a table's delta layers for ``epoch``, on top of what the
+        epoch already holds of the table. The store keeps the layers as
+        they are; the caller must not write to them again."""
+        self._pending.setdefault(epoch, {}).setdefault(
+            table_id, []).extend(layers)
+
     def ingest(self, table_id: int, epoch: int,
                puts: dict[bytes, tuple], deletes: set[bytes]) -> None:
-        buf = self._pending.setdefault(epoch, {}).setdefault(table_id, {})
-        for k in deletes:
-            buf[k] = None
-        buf.update(puts)
+        """One dict layer: ``deletes``, then ``puts``."""
+        self.ingest_layers(table_id, epoch,
+                           [{**dict.fromkeys(deletes), **puts}])
+
+    def pending_layers(self, table_id: int) -> list:
+        """The staged layers of a table in application order: every
+        pending epoch's, oldest first."""
+        return [layer for e in sorted(self._pending)
+                for layer in self._pending[e].get(table_id, ())]
+
+    def pending_tables(self, epoch: int) -> dict[int, list]:
+        """``{table_id: layers}`` of the pending epochs ≤ ``epoch``: each
+        table's layers in epoch order, as they are — what a durable tier's
+        commit writes (``dict_view`` folds a table's into one dict)."""
+        tables: dict[int, list] = {}
+        for e in sorted(k for k in self._pending if k <= epoch):
+            for table_id, layers in self._pending[e].items():
+                tables.setdefault(table_id, []).extend(layers)
+        return tables
 
     def commit(self, epoch: int) -> None:
         """Atomically apply all writes buffered for epochs ≤ ``epoch``.
@@ -59,6 +87,10 @@ class MemoryStateStore:
         non-checkpoint barriers stage state that the next checkpoint's
         ``commit_epoch`` makes durable (docs/checkpoint.md:26-44).
 
+        EAGER: when this returns the committed dict holds every row. A
+        packed layer is cut into Python ``bytes`` here, once
+        (``common/packed.apply_layer``).
+
         Idempotent per epoch: every executor of an epoch may trigger the
         commit; the first wins (the reference's HummockManager.commit_epoch
         is likewise a single logical commit per epoch)."""
@@ -66,18 +98,16 @@ class MemoryStateStore:
             return
         with span("store.apply", epoch=epoch, cat=CAT_STORAGE,
                   tid="storage") as apply:
-            rows = 0
+            rows = packed = 0
             for e in sorted(k for k in self._pending if k <= epoch):
-                for table_id, buf in self._pending.pop(e).items():
+                for table_id, layers in self._pending.pop(e).items():
                     tbl = self._committed.setdefault(table_id, {})
                     self._keys_dirty.add(table_id)
-                    rows += len(buf)
-                    for k, v in buf.items():
-                        if v is None:
-                            tbl.pop(k, None)
-                        else:
-                            tbl[k] = v
-            apply.set(rows=rows)
+                    rows += sum(map(len, layers))
+                    packed += packed_rows(layers)
+                    for layer in layers:
+                        apply_layer(tbl, layer)
+            apply.set(rows=rows, packed=packed)
         self.committed_epoch = epoch
 
     # -- async commit surface (pipelined tick, docs/performance.md) -----------
@@ -102,19 +132,15 @@ class MemoryStateStore:
         buffer makes sealed epochs readable before the checkpoint commits
         them (docs/checkpoint.md:36-44, state visibility vs durability)."""
         view = dict(self._committed.get(table_id, {}))
-        for e in sorted(self._pending):
-            for k, v in self._pending[e].get(table_id, {}).items():
-                if v is None:
-                    view.pop(k, None)
-                else:
-                    view[k] = v
+        for layer in self.pending_layers(table_id):
+            apply_view(view, layer_view(layer))
         return view
 
     def get(self, table_id: int, key: bytes) -> Optional[tuple]:
-        for e in sorted(self._pending, reverse=True):
-            buf = self._pending[e].get(table_id, {})
-            if key in buf:
-                return buf[key]
+        for layer in reversed(self.pending_layers(table_id)):
+            view = layer_view(layer)
+            if key in view:
+                return view[key]
         return self._committed.get(table_id, {}).get(key)
 
     def iter_table(self, table_id: int) -> Iterator[tuple[bytes, tuple]]:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Any, Iterator, Optional, Sequence
 
+from ..common.packed import PackedBatch, dict_view, layer_view
 from ..common.row import decode_value_row, encode_key, encode_value_row
 from ..common.types import Schema
 from .state_store import MemoryStateStore
@@ -33,9 +34,14 @@ class StateTable:
         self.schema = schema
         self.pk_indices = tuple(pk_indices)
         self._pk_types = tuple(schema[i].type for i in self.pk_indices)
+        # The epoch buffer, oldest write first: LAYERS in durable form
+        # (common/packed.py — a packed batch, or a dict {key: value bytes |
+        # None} that the row-at-a-time writers fill), under the raw rows
+        # of insert(), which are the newest writes of their keys and are
+        # value-encoded into the top dict layer when a packed batch lands
+        # on them or at commit().
+        self._layers: list = []
         self._puts: dict[bytes, tuple] = {}
-        self._puts_enc: dict[bytes, bytes] = {}   # pre-encoded (native path)
-        self._dels: set[bytes] = set()
 
     # -- key helpers ----------------------------------------------------------
 
@@ -44,48 +50,50 @@ class StateTable:
 
     # -- buffered writes (MemTable semantics) ---------------------------------
 
+    def _top(self) -> dict:
+        """The dict layer the row-at-a-time writers write: the last layer,
+        or a new one on top of a packed batch."""
+        if not self._layers or not isinstance(self._layers[-1], dict):
+            self._layers.append({})
+        return self._layers[-1]
+
+    def _seal_puts(self) -> None:
+        """insert()'s raw rows, value-encoded, into the top dict layer."""
+        if self._puts:
+            types = self.schema.types
+            self._top().update({k: encode_value_row(v, types)
+                                for k, v in self._puts.items()})
+            self._puts = {}
+
     def insert(self, row: Sequence[Any]) -> None:
-        k = self.key_of(row)
-        self._dels.discard(k)
-        self._puts_enc.pop(k, None)
-        self._puts[k] = tuple(row)
+        self._puts[self.key_of(row)] = tuple(row)
 
     def delete(self, row: Sequence[Any]) -> None:
         k = self.key_of(row)
         self._puts.pop(k, None)
-        self._puts_enc.pop(k, None)
-        self._dels.add(k)
+        self._top()[k] = None
 
     def stage_encoded(self, puts: dict, dels: Sequence[bytes]) -> None:
-        """Batch-staged rows already in durable form — the native
-        checkpoint fast path (native/rowcodec.cpp): keys are memcomparable
-        bytes, values are value-encoded bytes. Semantically identical to
-        insert()/delete() row by row."""
-        for k in dels:
-            self._puts.pop(k, None)
-            self._puts_enc.pop(k, None)
-            self._dels.add(k)
-        for k, v in puts.items():
-            self._dels.discard(k)
-            self._puts.pop(k, None)
-            self._puts_enc[k] = v
+        """Batch-staged rows already in durable form: keys are
+        memcomparable bytes, values are value-encoded bytes. Semantically
+        identical to delete() of every ``dels`` and then insert() of every
+        ``puts``, row by row."""
+        if self._puts:
+            for k in (*dels, *puts):
+                self._puts.pop(k, None)
+        top = self._top()
+        top.update(dict.fromkeys(dels))
+        top.update(puts)
 
-    def stage_ops(self, keys: Sequence[bytes], values: Sequence[bytes],
-                  is_put: Sequence[bool]) -> None:
-        """An ORDERED batch already in durable form — the MV egress path
-        (stream/materialize.py): ``keys[i]`` is put or deleted as
-        ``is_put[i]`` says, in that order; ``values`` holds one encoded row
-        per put, in the same order. Semantically identical to insert()/
-        delete() row by row: the last operation on a pk wins."""
-        value = iter(values)
-        for k, put in zip(keys, is_put):
-            self._puts.pop(k, None)
-            if put:
-                self._dels.discard(k)
-                self._puts_enc[k] = next(value)
-            else:
-                self._puts_enc.pop(k, None)
-                self._dels.add(k)
+    def stage_packed(self, batch: PackedBatch) -> None:
+        """An ordered batch as the native codec packed it (the checkpoint
+        delta of ``stream/state_delta.py``, a barrier's rows of
+        ``stream/materialize.py``): a layer of its own, whole — no Python
+        statement runs once a row. Semantically identical to insert() /
+        delete() of its rows one by one: the last write of a pk wins."""
+        if len(batch):
+            self._seal_puts()
+            self._layers.append(batch)
 
     def update(self, old_row: Sequence[Any], new_row: Sequence[Any]) -> None:
         ko, kn = self.key_of(old_row), self.key_of(new_row)
@@ -99,47 +107,48 @@ class StateTable:
         boundary as value-encoded bytes — the store is an opaque KV tier,
         and the durable backend persists process-independent bytes
         (reference: value encoding at the table layer, state_table.rs:62)."""
-        if self._puts or self._puts_enc or self._dels:
-            encoded = {
-                k: encode_value_row(v, self.schema.types)
-                for k, v in self._puts.items()
-            }
-            encoded.update(self._puts_enc)
-            self.store.ingest(self.table_id, epoch, encoded, self._dels)
-            self._puts, self._puts_enc, self._dels = {}, {}, set()
+        self._seal_puts()
+        layers = [layer for layer in self._layers if len(layer)]
+        self._layers = []
+        if layers:
+            self.store.ingest_layers(self.table_id, epoch, layers)
 
     def is_dirty(self) -> bool:
-        return bool(self._puts or self._puts_enc or self._dels)
+        return bool(self._puts) or any(map(len, self._layers))
 
     # -- reads (committed + own uncommitted buffer) ---------------------------
 
+    def _staged(self) -> dict:
+        """``{key: value bytes | None}`` of every write the store has not
+        committed, the newest winning: the store's pending epochs of this
+        table under this buffer's layers. insert()'s raw rows are not in
+        it; the readers lay them on top."""
+        return dict_view(self.store.pending_layers(self.table_id)
+                         + self._layers)
+
     def get_row(self, pk_values: Sequence[Any]) -> Optional[tuple]:
         k = encode_key(list(pk_values), self._pk_types)
-        if k in self._dels:
-            return None
         if k in self._puts:
             return self._puts[k]
-        if k in self._puts_enc:
-            return decode_value_row(self._puts_enc[k], self.schema.types)
-        v = self.store.get(self.table_id, k)
+        for layer in reversed(self._layers):
+            view = layer_view(layer)
+            if k in view:
+                v = view[k]
+                break
+        else:
+            v = self.store.get(self.table_id, k)
         return None if v is None else decode_value_row(v, self.schema.types)
 
     def scan_all(self) -> Iterator[tuple]:
         """Committed rows merged with the uncommitted buffer, pk order."""
-        merged: dict[bytes, Optional[Any]] = {
-            k: decode_value_row(v, self.schema.types)
-            for k, v in self.store.iter_table(self.table_id)
-        }
-        for k in self._dels:
-            merged.pop(k, None)
-        merged.update({
-            k: decode_value_row(v, self.schema.types)
-            for k, v in self._puts_enc.items()})
+        types = self.schema.types
+        merged = dict(self.store.iter_table(self.table_id))
+        merged.update(dict_view(self._layers))
         merged.update(self._puts)
         for k in sorted(merged):
             v = merged[k]
             if v is not None:
-                yield v
+                yield v if k in self._puts else decode_value_row(v, types)
 
     def scan_after(self, after_key: Optional[bytes],
                    limit: int) -> tuple[list[tuple], Optional[bytes]]:
@@ -158,14 +167,9 @@ class StateTable:
         skeys = self.store.sorted_committed_keys(self.table_id)
         # staged overlay (pending epochs + this instance's buffer): small
         # between checkpoints; None = delete
-        overlay: dict[bytes, Optional[Any]] = {}
-        for e in sorted(self.store._pending):
-            overlay.update(self.store._pending[e].get(self.table_id, {}))
-        overlay.update(self._puts_enc)
+        overlay: dict[bytes, Optional[Any]] = dict(self._staged())
         overlay.update(self._puts)
-        raw = set(self._puts)
-        for k in self._dels:
-            overlay[k] = None
+        raw = self._puts
         okeys = sorted(k for k in overlay
                        if after_key is None or k > after_key)
         i = (bisect.bisect_right(skeys, after_key)
@@ -199,38 +203,30 @@ class StateTable:
         sorted committed keys + the (small) staged overlay — the join
         cold-tier fault-in path calls this per faulted key."""
         import bisect
+        types = self.schema.types
         prefix = encode_key(list(prefix_values), self._pk_types[:n_cols])
         committed = self.store.committed_view(self.table_id)
         skeys = self.store.sorted_committed_keys(self.table_id)
         merged: dict[bytes, Optional[Any]] = {}
         i = bisect.bisect_left(skeys, prefix)
         while i < len(skeys) and skeys[i].startswith(prefix):
-            merged[skeys[i]] = decode_value_row(
-                committed[skeys[i]], self.schema.types)
+            merged[skeys[i]] = committed[skeys[i]]
             i += 1
-        for e in sorted(self.store._pending):
-            for k, v in self.store._pending[e].get(self.table_id, {}).items():
+        for overlay in (self._staged(), self._puts):
+            for k, v in overlay.items():
                 if k.startswith(prefix):
-                    merged[k] = (None if v is None
-                                 else decode_value_row(v, self.schema.types))
-        for k, v in self._puts_enc.items():
-            if k.startswith(prefix):
-                merged[k] = decode_value_row(v, self.schema.types)
-        for k, v in self._puts.items():
-            if k.startswith(prefix):
-                merged[k] = v
-        for k in self._dels:
-            if k.startswith(prefix):
-                merged[k] = None
+                    merged[k] = v
         for k in sorted(merged):
             v = merged[k]
             if v is not None:
-                yield v
+                yield v if k in self._puts else decode_value_row(v, types)
 
     def __len__(self) -> int:
         n = self.store.table_len(self.table_id)
-        new_puts = sum(
-            1 for k in (*self._puts, *self._puts_enc)
-            if self.store.get(self.table_id, k) is None)
-        dead = sum(1 for k in self._dels if self.store.get(self.table_id, k) is not None)
-        return n + new_puts - dead
+        staged = dict_view(self._layers) if self._layers else {}
+        if self._puts:
+            staged = {**staged, **self._puts}
+        for k, v in staged.items():
+            before = self.store.get(self.table_id, k) is not None
+            n += (v is not None) - before
+        return n

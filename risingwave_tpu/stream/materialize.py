@@ -7,10 +7,11 @@ device→host copy (one ``common.fetch.async_fetch`` over the whole pytree,
 nothing blocks between two chunks of an epoch) and the barrier resolves
 the epoch's copies, takes the visible rows of all of them as one columnar
 batch, encodes keys and value rows with the native codec (one call each a
-barrier) and stages them in arrival order. Where the codec is absent, or
-the schema holds a type it has no code for, the same fetched host chunks
-go through Python rows. Conflict handling is overwrite-on-pk, matching
-the reference's default HandleConflictBehavior for MVs.
+barrier) and stages them whole, in arrival order, as one packed batch
+(common/packed.py). Where the codec is absent, or the schema holds a type
+it has no code for, the same fetched host chunks go through Python rows.
+Conflict handling is overwrite-on-pk, matching the reference's default
+HandleConflictBehavior for MVs.
 
 Visibility: a chunk's rows reach the table's uncommitted buffer at the
 barrier that closes their epoch (or when ``rows()`` / ``drain()`` asks),
@@ -30,6 +31,7 @@ from ..common.chunk import (
     OP_INSERT, OP_UPDATE_INSERT, StreamChunk, chunk_to_rows,
 )
 from ..common.fetch import async_fetch
+from ..common.packed import PackedBatch
 from ..common.tracing import CAT_STORAGE, conductor_epoch, span
 from ..native import codec as native_codec
 from ..storage.state_table import StateTable
@@ -105,12 +107,20 @@ class MaterializeExecutor(SingleInputExecutor):
         types = self.table.schema.types
         pk = self.table.pk_indices
         is_put = (rows.ops == OP_INSERT) | (rows.ops == OP_UPDATE_INSERT)
-        keys = codec.encode_keys(
-            [datas[i] for i in pk], [masks[i] for i in pk],
-            [types[i] for i in pk], np.arange(len(is_put)))
-        values = codec.encode_value_rows(datas, masks, types,
-                                         np.nonzero(is_put)[0])
-        self.table.stage_ops(keys, values, is_put.tolist())
+        # the ordered batch of the barrier, packed: staged whole
+        batch = PackedBatch(
+            codec.pack_keys(
+                [datas[i] for i in pk], [masks[i] for i in pk],
+                [types[i] for i in pk], np.arange(len(is_put))),
+            codec.pack_value_rows(datas, masks, types,
+                                  np.nonzero(is_put)[0]),
+            is_put)
+        self.table.stage_packed(batch)
+        # the batch's one cut into Python bytes, taken HERE, on the barrier
+        # its rows arrive at, and kept for the store's commit: a checkpoint
+        # barrier then applies ten barriers' views instead of cutting ten
+        # barriers' rows (the segment writer still takes the blobs)
+        batch.view()
         self._counts["rows_staged"] += len(is_put)
 
     def epoch_counts(self) -> dict:

@@ -7,7 +7,7 @@ gathered, and which dirty rows are deletes — stays with the caller.
 Whatever ``*.state_delta`` span the caller has open gets three children
 here, one a kind of work: ``delta.fetch_wait`` (the host blocked on the
 device's windows), ``delta.encode`` (dirty rows to key / value bytes) and
-``delta.stage`` (the bytes into the state table and its commit). What is
+``delta.stage`` (the packed batch into the state table and its commit). What is
 left as the parent's self time is window 0's dispatch, the numpy cut, the
 caller's masks and its ``ckpt_dirty`` reset. None carries a ledger stage:
 the parent's ``state_delta`` stage already holds their time.
@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from ..common.fetch import async_fetch, fetch
+from ..common.packed import PackedBatch
 from ..common.tracing import CAT_STORAGE, current_span, span
 from ..storage.state_table import StateTable
 
@@ -77,8 +78,10 @@ def stage_delta(table: StateTable, epoch: int, datas: Sequence[np.ndarray],
     where ``puts[i]``, deleted where ``dels[i]`` — and commit them to
     ``epoch``. Deletes strictly before puts: a join's same-pk update lands
     in two rows of one delta, and the delete must not clobber the freshly
-    upserted row. Returns the encoded bytes staged (0 where the native
-    codec does not serve: the table then encodes at its commit)."""
+    upserted row. With the native codec that is ONE packed batch
+    (common/packed.py), the delete keys first, staged whole. Returns the
+    encoded bytes staged (0 where the native codec does not serve: the
+    table then encodes at its commit)."""
     from ..native import codec as _native_codec
     put_idx, del_idx = np.flatnonzero(puts), np.flatnonzero(dels)
     types = table.schema.types
@@ -89,14 +92,15 @@ def stage_delta(table: StateTable, epoch: int, datas: Sequence[np.ndarray],
                 native=int(native)) as encode:
         if native:
             pk = table.pk_indices
-            pk_d = [datas[i] for i in pk]
-            pk_m = [masks[i] for i in pk]
-            pk_t = [types[i] for i in pk]
-            put_keys = codec.encode_keys(pk_d, pk_m, pk_t, put_idx)
-            put_rows = codec.encode_value_rows(datas, masks, types, put_idx)
-            del_keys = codec.encode_keys(pk_d, pk_m, pk_t, del_idx)
-            staged = (sum(map(len, put_keys)) + sum(map(len, put_rows))
-                      + sum(map(len, del_keys)))
+            live = np.zeros(len(del_idx) + len(put_idx), np.uint8)
+            live[len(del_idx):] = 1
+            batch = PackedBatch(
+                codec.pack_keys([datas[i] for i in pk],
+                                [masks[i] for i in pk],
+                                [types[i] for i in pk],
+                                np.concatenate([del_idx, put_idx])),
+                codec.pack_value_rows(datas, masks, types, put_idx), live)
+            staged = batch.nbytes
         else:
             def row_at(r):
                 return tuple(d[r].item() if m[r] else None
@@ -107,7 +111,7 @@ def stage_delta(table: StateTable, epoch: int, datas: Sequence[np.ndarray],
         encode.set(bytes=staged)
     with _child("delta.stage", puts=len(put_idx), deletes=len(del_idx)):
         if native:
-            table.stage_encoded(dict(zip(put_keys, put_rows)), del_keys)
+            table.stage_packed(batch)
         else:
             for row in del_rows:
                 table.delete(row)

@@ -231,10 +231,12 @@ def _native_or_skip():
         pytest.skip("native toolchain unavailable")
 
 
-def _drive_epochs(st, model, epochs, seed):
+def _drive_epochs(st, model, epochs, seed, form="dict"):
     """Ingest and commit ``epochs`` of random puts and deletes over three
-    tables; ``model`` follows as plain dicts."""
+    tables — as a dict layer, or as a packed batch (the deletes first) the
+    way ``stage_delta`` stages one; ``model`` follows as plain dicts."""
     import random
+    from test_packed_delta import packed
     rng = random.Random(seed)
     for e in epochs:
         for tid in (4, 2, 9):
@@ -243,7 +245,11 @@ def _drive_epochs(st, model, epochs, seed):
                     rng.randbytes(rng.randrange(0, 24)) for _ in range(60)}
             live = sorted(set(tbl) - set(puts))
             dels = set(rng.sample(live, min(len(live), 15)))
-            st.ingest(tid, e, puts, dels)
+            if form == "packed":
+                st.ingest_layers(tid, e, [packed(
+                    [(k, None) for k in sorted(dels)] + list(puts.items()))])
+            else:
+                st.ingest(tid, e, puts, dels)
             tbl.update(puts)
             for k in dels:
                 del tbl[k]
@@ -254,15 +260,17 @@ def _tables(st):
     return {tid: dict(st.iter_table(tid)) for tid in (4, 2, 9)}
 
 
+@pytest.mark.parametrize("form", ["dict", "packed"])
 @pytest.mark.parametrize("how", ["straight", "folded", "torn"])
-def test_native_segments_recover(tmp_path, how):
-    """Written through the native encoder, read back by a fresh store:
-    straight, after a fold, and with a torn segment left unreferenced."""
+def test_native_segments_recover(tmp_path, how, form):
+    """Written through the native encoder — from dict layers, and from
+    packed batches (ISSUE 38) — read back by a fresh store: straight, after
+    a fold, and with a torn segment left unreferenced."""
     from risingwave_tpu.common.failpoint import failpoints
     _native_or_skip()
     d = str(tmp_path)
     st, model = DurableStateStore(d, compact_after=1000), {}
-    _drive_epochs(st, model, range(1, 7), seed=34)
+    _drive_epochs(st, model, range(1, 7), seed=34, form=form)
     if how == "folded":
         st.log.compact()
         assert len(st.log._read_manifest()["segments"]) == 1
@@ -291,11 +299,11 @@ def test_segments_of_either_encoder_reopen_under_the_other(
     that writes natively, keeps growing, and the other way round."""
     _native_or_skip()
     d = str(tmp_path)
-    native = CheckpointLog._encode_segment_native
+    native = CheckpointLog._segment_native
 
     def use(encoder):
         monkeypatch.setattr(
-            CheckpointLog, "_encode_segment_native",
+            CheckpointLog, "_segment_native",
             staticmethod(native if encoder == "native"
                          else (lambda deltas: None)))
 

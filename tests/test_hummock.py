@@ -52,6 +52,26 @@ class TestHummockStore:
         assert dict(st3.iter_table(7)) == {b"b": b"row-b", b"c": b"row-c"}
         assert st3.committed_epoch == 3
 
+    def test_commit_of_a_table_staged_packed(self, tmp_path):
+        """ISSUE 38: the tier takes the pending layers' dict view — a
+        packed batch (an update pair and a retraction in it), a dict layer
+        of another epoch under it — and recovers the same rows."""
+        from test_packed_delta import packed
+        d = str(tmp_path / "hm")
+        st = HummockStateStore(data_dir=d, inline_compaction=False)
+        st.ingest(7, 2, {b"a": b"old", b"gone": b"x"}, set())
+        st.ingest_layers(7, 3, [packed([
+            (b"a", None), (b"a", b"new"), (b"b", b"row-b"), (b"gone", None),
+            (b"flash", b"1"), (b"flash", None)])])
+        assert st.get(7, b"a") == b"new" and st.get(7, b"gone") is None
+        st.commit(3)
+        want = {b"a": b"new", b"b": b"row-b"}
+        assert dict(st.iter_table(7)) == want
+        st2 = HummockStateStore(data_dir=d)
+        assert st2.committed_epoch == 3
+        assert dict(st2.iter_table(7)) == want
+        assert st2.get(7, b"flash") is None
+
     def test_idle_commit_adds_no_runs(self):
         st = _store()
         _fill(st, epochs=range(1, 3))

@@ -13,6 +13,7 @@ import pytest
 import risingwave_tpu.native as native_mod
 from risingwave_tpu.common import fetch as fetch_mod
 from risingwave_tpu.common import tracing
+from risingwave_tpu.common.packed import dict_view
 from risingwave_tpu.common.chunk import (
     OP_DELETE, OP_INSERT, OP_UPDATE_DELETE, OP_UPDATE_INSERT, chunk_to_rows,
     make_chunk,
@@ -117,7 +118,7 @@ def run_row_by_row(schema, pk, epochs):
 
 
 def sealed(store, n_epochs):
-    pending = {e: dict(tables.get(1, {}))
+    pending = {e: dict(dict_view(tables.get(1, [])))
                for e, tables in store._pending.items()}
     store.commit(n_epochs + 1)
     return pending, list(store.iter_table(1))

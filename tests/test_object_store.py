@@ -59,7 +59,7 @@ class TestCheckpointLogOverObjectStore:
         s = DurableStateStore(object_store=store)
         s.ingest(7, 3, {b"k": ("row",)}, set())
         # value must be bytes for durability; emulate the table layer
-        s._pending[3][7][b"k"] = b"row-bytes"
+        s._pending[3][7][0][b"k"] = b"row-bytes"
         s.commit(3)
         s2 = DurableStateStore(object_store=store)
         assert s2.committed_epoch == 3

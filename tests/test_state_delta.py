@@ -26,6 +26,7 @@ from risingwave_tpu.common.chunk import (
     Column, OP_UPDATE_DELETE, OP_UPDATE_INSERT,
 )
 from risingwave_tpu.common.hashing import vnode_of, vnode_to_shard
+from risingwave_tpu.common.packed import dict_view
 from risingwave_tpu.expr.agg import agg, count_star
 from risingwave_tpu.ops import JoinType
 from risingwave_tpu.ops.join_state import join_ckpt_delta_window
@@ -163,22 +164,24 @@ def host_mesh_agg(state, n, table, epoch) -> None:
 # -- what is compared --------------------------------------------------------
 
 def spy_on_store(store) -> list:
-    """Every ingest of ``store``, as the bytes and the order it was
-    handed (the deletes are a set)."""
+    """Every ingest of ``store``: the dict view of the layers it was
+    handed (``{key: value | None}``, the executor's packed batch or the
+    reference's dict alike), as bytes and in order."""
     got = []
-    real = store.ingest
+    real = store.ingest_layers
 
-    def ingest(table_id, epoch, puts, deletes):
-        got.append((table_id, epoch, list(puts.items()), sorted(deletes)))
-        return real(table_id, epoch, puts, deletes)
-    store.ingest = ingest
+    def ingest_layers(table_id, epoch, layers):
+        got.append((table_id, epoch, list(dict_view(layers).items())))
+        return real(table_id, epoch, layers)
+    store.ingest_layers = ingest_layers
     return got
 
 
 def spy_on_table(table) -> list:
     """The name of every call that stages or commits, in order."""
     calls = []
-    for name in ("stage_encoded", "insert", "delete", "commit"):
+    for name in ("stage_packed", "stage_encoded", "insert", "delete",
+                 "commit"):
         def spy(*a, _real=getattr(table, name), _name=name):
             calls.append(_name)
             return _real(*a)
@@ -196,7 +199,7 @@ def check_calls(calls: list, n_dirty: int, with_codec: bool) -> None:
     if not n_dirty:
         assert calls == []
     elif with_codec:
-        assert calls == ["stage_encoded", "commit"]
+        assert calls == ["stage_packed", "commit"]
     else:
         assert calls[-1] == "commit" and len(calls) > 1
         assert calls[:-1] == sorted(calls[:-1])     # "delete" < "insert"
