@@ -7,10 +7,10 @@ this is the conductor and the event loop's entry and exit. The spans'
 staged for the barrier) are printed on a line of their own.
 
 ``find`` and ``counts`` serve the other readers of the span tree's leaves
-too (``executor_unowned_ms``, ``rowid_ms``, ``delta_*_ms``,
-``commit_*_ms``): nothing where NO barrier of the window has a span of
-the names (a program without them); a program that has them owes every
-name on every barrier the metric is over."""
+too (``executor_unowned_ms``, ``delta_*_ms``, ``commit_*_ms``): nothing
+where NO barrier of the window has a span of the names (a program without
+them); a program that has them owes every name on every barrier the
+metric is over."""
 
 import json
 

@@ -141,25 +141,45 @@ def install_compile_listeners() -> dict:
 # -- the traced slice ---------------------------------------------------------
 
 class Tracer:
-    """Profiles barriers ``[skip, skip + count)`` of the window, each
-    inside an annotation of the harness's own."""
+    """Profiles ``count`` barriers of the window, each inside an
+    annotation of the harness's own, from window barrier ``skip`` on, or
+    from the first barrier at which the run's pace says that the seconds
+    left cannot hold the barriers up to ``skip`` and the slice. The pace
+    is the median time of the warm-up's last barriers (``warmup_s``) and of
+    the window's barriers so far: a deployment slower than ``seconds /
+    (skip + count)`` a barrier has its slice start earlier, every faster
+    one at ``skip``."""
 
     def __init__(self, log_dir: str, skip: int, count: int,
-                 keep_dir: str = ""):
+                 warmup_s: tuple = (), keep_dir: str = ""):
         self.log_dir, self.skip, self.count = log_dir, skip, count
+        self.warmup_s = list(warmup_s)
         self.keep_dir = keep_dir
         self.active = False
         self.traced: list = []
 
-    def before(self, i: int) -> None:
-        import jax
-        if i == self.skip and not self.traced:
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0     # no per-call Python events
-            jax.profiler.start_trace(self.log_dir, profiler_options=options)
-            self.active = True
-        elif self.active and i >= self.skip + self.count:
+    def starts_at(self, i: int, seconds_left: float, barrier_s: list) -> bool:
+        if self.active or self.traced or i > self.skip:
+            return False
+        if i == self.skip:
+            return True
+        pace = window.median(self.warmup_s + list(barrier_s))
+        return pace is not None and \
+            seconds_left < (self.skip - i + self.count) * pace
+
+    def before(self, i: int, seconds_left: float, barrier_s: list) -> None:
+        """The hook ``window.drive`` calls ahead of a barrier that runs."""
+        if self.active and len(self.traced) >= self.count:
             self.stop()
+        elif self.starts_at(i, seconds_left, barrier_s):
+            self.start()
+
+    def start(self) -> None:
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # no per-call Python events
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
+        self.active = True
 
     def around(self, i: int):
         import jax
@@ -169,16 +189,27 @@ class Tracer:
         return jax.profiler.TraceAnnotation(ANNOTATION)
 
     def stop(self) -> None:
-        import jax
         if self.active:
+            import jax
             jax.profiler.stop_trace()
             self.active = False
 
+    def slice(self) -> dict:
+        return {"first_traced": self.traced[0] if self.traced else None,
+                "traced_barriers": len(self.traced)}
+
+    def empty(self, why: str) -> None:
+        """Says why a traced run has no device keys, and gives nothing."""
+        print(f"benchmark/run.py: the trace is empty: {why}",
+              file=sys.stderr, flush=True)
+        say({"trace": {"empty": why, **self.slice()}})
+
     def read(self, checkpoint_of: list):
         """The reduced trace, its annotations renamed for the checkpoint
-        barriers (the ledger says which those were)."""
+        barriers (the ledger says which those were); None, said, where no
+        barrier was traced or no operation ran on the device."""
         if not self.traced:
-            return None
+            return self.empty("no barrier was traced")
         xplane = trace.find_xplane(self.log_dir)
         raw = trace.extract(xplane, (ANNOTATION,))
         for note, i in zip(raw["annotations"], self.traced):
@@ -191,7 +222,11 @@ class Tracer:
             with gzip.open(os.path.join(self.keep_dir, "extract.json.gz"),
                            "wt") as f:
                 json.dump(raw, f)
-        return trace.reduce(raw)
+        reduced = trace.reduce(raw)
+        if reduced is None:
+            return self.empty(f"no device operation in {len(self.traced)} "
+                              "traced barriers")
+        return reduced
 
 
 # -- one run ------------------------------------------------------------------
@@ -236,11 +271,14 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict,
     try:
         sut = system.System(config, os.path.join(work_dir, "data"), seed)
         sut.create()
+        warm_s = []                 # the last three set the traced run's pace
         for _ in range(warm):
+            t_warm = time.perf_counter()
             sut.barrier()
+            warm_s.append(time.perf_counter() - t_warm)
         tracer = Tracer(os.path.join(work_dir, "trace"),
                         traffic["trace_skip_barriers"],
-                        traffic["trace_barriers"], keep_trace) \
+                        traffic["trace_barriers"], warm_s[-3:], keep_trace) \
             if traced else None
         before_window = dict(compiles)
         setup_s = time.perf_counter() - t0
@@ -338,9 +376,10 @@ def run_cell(spec: dict, cell: dict, config: dict, traffic: dict,
          "compiles_in_window": in_window, "compiles_total": dict(compiles),
          "control": control or None})
     if traced and reduced:
-        say({"trace": {k: reduced[k] for k in
-                       ("window_s", "busy_s", "devices", "program_s",
-                        "program_runs")}})
+        say({"trace": {**tracer.slice(),
+                       **{k: reduced[k] for k in
+                          ("window_s", "busy_s", "devices", "program_s",
+                           "program_runs")}}})
         if "work" in config:
             per = work.of(config, per_barrier,
                           expected["groups_touched"][warm])

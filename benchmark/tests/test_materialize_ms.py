@@ -83,4 +83,3 @@ def test_the_entry_lists_the_three_cells_and_edits_nothing_else():
         "workloads": ["q5core_fused_catchup", "q5core_exec_catchup",
                       "q8_catchup"]}
     assert set(entry["workloads"]) == {cell for cell, _ in WANT.values()}
-    assert spec["per_layer"][-1] is entry

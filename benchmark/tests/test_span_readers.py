@@ -23,7 +23,7 @@ WANT = {
               "state_delta_ms": 99.138715, "operator_busy_ms": 0.679373,
               "tick_unaccounted_ms": 0.276544},
     "exec": {"source_feed_span_ms": 5.220058, "device_wait_ms": 0.287885,
-             "state_delta_ms": 2.258033, "operator_busy_ms": 3.062934,
+             "state_delta_ms": 2.258033, "operator_busy_ms": 2.678265,
              "tick_unaccounted_ms": 0.288869},
 }
 CELL = {"fused": "q5core_fused_catchup", "exec": "q5core_exec_catchup"}
@@ -153,10 +153,9 @@ def test_one_summary_line_per_run(monkeypatch, capsys):
     (line,) = lines
     summary = line["program_spans"]
     assert summary["window_barriers"] == summary["covered_barriers"] == 5
-    assert summary["spans_per_barrier"] == 17
+    assert summary["spans_per_barrier"] == 15
     assert set(summary["chunks_median_ms"]) == {
-        "RowIdGen.chunks", "Project.chunks", "HashAgg.chunks",
-        "Materialize.chunks"}
+        "Project.chunks", "HashAgg.chunks", "Materialize.chunks"}
     assert "source.feed" in summary["median_ms_where_present"]
 
 

@@ -1,6 +1,5 @@
-"""The eight readers of the span tree's leaves (ISSUE 35) — the job task's
-clock (``actor_run_ms``, ``executor_unowned_ms``, ``rowid_ms``) and the
-checkpoint by part (``delta_wait_ms``, ``delta_encode_ms``,
+"""The seven readers of the span tree's leaves — the job task's clock
+(``actor_run_ms``, ``executor_unowned_ms``) and the checkpoint by part (``delta_wait_ms``, ``delta_encode_ms``,
 ``delta_stage_ms``, ``commit_apply_ms``, ``commit_io_ms``) — on a window
 of four barriers small enough to add up in the head: barrier ``i`` is the
 base barrier below times ``i``, the second and the fourth are checkpoints.
@@ -23,33 +22,30 @@ EXECUTORS = "executors and epoch collection"
 ENTRIES = {
     "actor_run_ms": (EXECUTORS, "events_per_s", CELLS),
     "executor_unowned_ms": (EXECUTORS, "events_per_s", CELLS),
-    "rowid_ms": (EXECUTORS, "events_per_s", CELLS[1:]),
     "delta_wait_ms": ("checkpoint", "barrier_p95_ms", CELLS),
     "delta_encode_ms": ("checkpoint", "barrier_p95_ms", CELLS),
     "delta_stage_ms": ("checkpoint", "barrier_p95_ms", CELLS),
     "commit_apply_ms": ("checkpoint", "barrier_p95_ms", CELLS),
     "commit_io_ms": ("checkpoint", "barrier_p95_ms", CELLS),
 }
-#: base barrier: actor.run 58 (93 on a checkpoint), the operators' own
-#: 6 + 0 + 4 + 1 + 10 + 20 + 2 + 3 = 46 (81), so 12 unowned; the row ids
-#: 6 + 4 + 1 = 11. Over barriers x1, x2, x3, x4 (x2 and x4 checkpoints):
-#: 58, 186, 174, 372 -> 180; 12, 24, 36, 48 -> 30; 11, 22, 33, 44 -> 27.5.
+#: base barrier: actor.run 47 (82 on a checkpoint), the operators' own
+#: 10 + 20 + 2 + 3 = 35 (70), so 12 unowned. Over barriers x1, x2, x3, x4
+#: (x2 and x4 checkpoints): 47, 164, 141, 328 -> 152.5; 12, 24, 36, 48 -> 30.
 #: A checkpoint's parts at x1: wait 5, encode 9, stage 12, pending 3 +
 #: apply 8, put 6 + manifest 4; the median of x2 and x4 is x3.
-WANT = {"actor_run_ms": 180, "executor_unowned_ms": 30, "rowid_ms": 27.5,
+WANT = {"actor_run_ms": 152.5, "executor_unowned_ms": 30,
         "delta_wait_ms": 15, "delta_encode_ms": 27, "delta_stage_ms": 36,
         "commit_apply_ms": 33, "commit_io_ms": 30}
 #: the spans each metric reads: without them (the parent commit) nothing
 READS = {"actor_run_ms": ("actor.run",),
          "executor_unowned_ms": ("actor.run",),
-         "rowid_ms": ("RowIdAppend.chunks",),
          "delta_wait_ms": ("delta.fetch_wait",),
          "delta_encode_ms": ("delta.encode",),
          "delta_stage_ms": ("delta.stage",),
          "commit_apply_ms": ("commit.pending", "store.apply"),
          "commit_io_ms": ("segment.put", "manifest.write")}
 NEW_SPANS = {name for names in READS.values() for name in names} | {
-    "segment.encode", "RowIdAppend.barrier"}
+    "segment.encode"}
 
 
 def barrier(epoch: int, checkpoint: bool, scale: int):
@@ -64,11 +60,7 @@ def barrier(epoch: int, checkpoint: bool, scale: int):
              span(2, "source.feed", 7, 1, chunks=16, capacity_rows=65536,
                   transfers=2, bytes_staged=1000, dispatches=1),
              span(3, "barrier.collect", 60 + more, 1),
-             span(4, "actor.run", 58 + more, 3, task=0, messages=17),
-             span(5, "RowIdAppend.chunks", 6, 3, node=5),
-             span(6, "RowIdAppend.barrier", 0, 3, node=5),
-             span(7, "RowIdGen.chunks", 4, 3, node=4),
-             span(8, "RowIdGen.barrier", 1, 3, node=4),
+             span(4, "actor.run", 47 + more, 3, task=0, messages=17),
              span(9, "HashAgg.chunks", 10, 3, node=2, chunks=16),
              span(10, "HashAgg.barrier", 20 + more, 3, node=2),
              span(11, "agg.flush_wait", 15, 10, "device"),
@@ -218,9 +210,7 @@ def test_nothing_for_a_window_without_a_checkpoint(metric, monkeypatch,
     (metric, name, epoch)
     for metric, names in sorted(READS.items()) for name in names
     for epoch in ((4,) if ENTRIES[metric][0] == "checkpoint" else (3, 4))]
-    + [("rowid_ms", "RowIdGen.chunks", 1),
-       ("rowid_ms", "RowIdGen.barrier", 2),
-       ("executor_unowned_ms", "barrier.collect", 3)])
+    + [("executor_unowned_ms", "barrier.collect", 3)])
 def test_a_barrier_that_lacks_its_span_while_another_has_it_is_an_error(
         metric, gone, epoch, monkeypatch, capsys):
     ctx, by_epoch = window(drop=(gone,), only_in=epoch)
@@ -236,8 +226,6 @@ def test_the_entries_name_files_layers_and_cells_that_exist():
     older = [m for m in spec["per_layer"] if m["name"] not in ENTRIES]
     layers = {m["layer"] for m in older}
     reports = {m["name"] for m in spec["end_to_end"]}
-    assert [m["name"] for m in spec["per_layer"]][-len(ENTRIES):] \
-        == list(ENTRIES)
     for name, (layer, moves, listed) in ENTRIES.items():
         assert by_name[name] == {
             "name": name, "unit": "ms", "better": "lower",

@@ -63,14 +63,18 @@ def test_hooks_run_outside_the_barrier_s_time():
     def barrier():
         clock.now += 0.1
 
-    def before(i):
-        seen.append(i)
+    def before(i, seconds_left, barrier_s):
+        assert len(barrier_s) == i
+        seen.append((i, seconds_left))
         clock.now += 1.0          # a profiler stopping: not barrier time
 
     out = window.drive(barrier, seconds=3.5, max_barriers=100, clock=clock,
                        before=before)
-    assert out["barrier_s"] == pytest.approx([0.1, 0.1, 0.1])
-    assert seen == [0, 1, 2, 3]
+    # the hook runs only ahead of a barrier that runs: the fourth starts
+    # 3.3 s in, inside the window, and runs after its hook
+    assert out["barrier_s"] == pytest.approx([0.1, 0.1, 0.1, 0.1])
+    assert [i for i, _ in seen] == [0, 1, 2, 3]
+    assert [left for _, left in seen] == pytest.approx([3.5, 2.4, 1.3, 0.2])
 
 
 def test_rates_are_over_the_elapsed_seconds():

@@ -40,9 +40,11 @@ def drive(barrier: Callable[[], None], seconds: float, max_barriers: int,
     ``max_barriers`` have run, whichever is first. A barrier that starts
     inside the window is finished and counted, with its time.
 
-    The traced run's hooks: ``before(i)`` runs ahead of barrier ``i``,
-    outside its time (the profiler's start and stop); ``around(i)`` gives
-    a context manager to run it inside (a trace annotation). Returns
+    The traced run's hooks: ``before(i, seconds_left, barrier_s)`` runs
+    ahead of barrier ``i`` once it is settled that ``i`` runs, outside its
+    time (the profiler's start and stop), with the seconds the window has
+    left and the barrier times so far; ``around(i)`` gives a context
+    manager to run it inside (a trace annotation). Returns
     ``{"barrier_s": [...], "elapsed_s": first start -> last return,
     "stopped_by": "seconds" | "max_barriers"}``."""
     per: list = []
@@ -54,11 +56,12 @@ def drive(barrier: Callable[[], None], seconds: float, max_barriers: int,
         if len(per) >= max_barriers:
             stopped_by = "max_barriers"
             break
-        if before is not None:
-            before(len(per))
         t0 = clock()
         if t0 >= deadline:
             break
+        if before is not None:
+            before(len(per), deadline - t0, per)
+            t0 = clock()
         with around(len(per)) if around else contextlib.nullcontext():
             barrier()
         end = clock()
