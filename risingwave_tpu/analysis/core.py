@@ -97,6 +97,9 @@ class Module:
         self.source = abspath.read_text(encoding="utf-8")
         self.lines = self.source.splitlines()
         self.tree = ast.parse(self.source, filename=str(abspath))
+        #: every node of the tree in ``ast.walk`` order, walked once: a
+        #: dozen rules each walk every module, and the walk dominated
+        self._nodes: Optional[List[ast.AST]] = None
         # dotted module qualname: pkgname.sub.mod (pkgname/__init__.py
         # -> pkgname), with the root segment pinned to CANONICAL_PKG —
         # rule targets are written against it, not the root dir name
@@ -171,14 +174,16 @@ class Module:
 
     # -- AST helpers ------------------------------------------------------
 
-    def walk(self) -> Iterator[ast.AST]:
-        return ast.walk(self.tree)
+    def walk(self) -> List[ast.AST]:
+        if self._nodes is None:
+            self._nodes = list(ast.walk(self.tree))
+        return self._nodes
 
     def docstring_linenos(self) -> "set[int]":
         """Line numbers covered by docstrings (module/class/function) —
         the classic grep false-positive surface."""
         covered: "set[int]" = set()
-        for node in ast.walk(self.tree):
+        for node in self.walk():
             if isinstance(node, (ast.Module, ast.ClassDef,
                                  ast.FunctionDef, ast.AsyncFunctionDef)):
                 body = node.body

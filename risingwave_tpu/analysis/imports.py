@@ -46,10 +46,10 @@ class ImportMap:
             self._pkg = qn
         else:
             self._pkg = qn.rpartition(".")[0]
-        self._collect(module.tree)
+        self._collect(module.walk())
 
-    def _collect(self, tree: ast.AST) -> None:
-        for node in ast.walk(tree):
+    def _collect(self, nodes) -> None:
+        for node in nodes:
             if isinstance(node, ast.Import):
                 for a in node.names:
                     local = a.asname or a.name.split(".")[0]

@@ -165,7 +165,7 @@ module outside frontend/session.py writes either attribute."""
     @staticmethod
     def _method_of(mod: Module, node: ast.AST) -> Optional[str]:
         best: Optional[ast.AST] = None
-        for fn in ast.walk(mod.tree):
+        for fn in mod.walk():
             if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)) \
                     and fn.lineno <= node.lineno <= \
                     (fn.end_lineno or fn.lineno):

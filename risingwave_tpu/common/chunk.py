@@ -375,6 +375,15 @@ def chunk_to_rows(
     vis = np.asarray(chunk.vis)
     datas = [np.asarray(c.data) for c in chunk.columns]
     masks = [np.asarray(c.mask) for c in chunk.columns]
+    if physical:
+        # column at a time: one ``tolist`` a column, not one ``item`` a cell
+        at = np.flatnonzero(vis)
+        cols = [d[at].tolist() if m[at].all() else
+                [v if ok else None for v, ok in zip(d[at].tolist(),
+                                                    m[at].tolist())]
+                for d, m in zip(datas[:len(schema)], masks)]
+        rows = list(zip(*cols)) if cols else [()] * len(at)
+        return list(zip(ops[at].tolist(), rows)) if with_ops else rows
     out = []
     for i in range(chunk.capacity):
         if not vis[i]:

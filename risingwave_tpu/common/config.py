@@ -37,6 +37,10 @@ class StreamingConfig:
     agg_table_capacity: int = 1 << 16
     join_key_capacity: int = 1 << 13
     join_bucket_width: int = 16
+    # rows of a join side's fan-out arena: the side a join key may hold
+    # many rows of (its stream key is not inside the join key) moves
+    # there when a key outgrows the bucket width (ops/join_state.py)
+    join_row_capacity: int = 1 << 16
     topn_table_capacity: int = 1 << 16
     # actor parallelism per fragmentable operator (grouped aggs, joins):
     # >1 builds multi-fragment jobs with hash-dispatch exchanges

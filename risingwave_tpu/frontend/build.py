@@ -44,6 +44,7 @@ class BuildConfig:
     agg_table_capacity: int = 1 << 16
     join_key_capacity: int = 1 << 13
     join_bucket_width: int = 16
+    join_row_capacity: int = 1 << 16
     topn_table_capacity: int = 1 << 16
     # Data parallelism: a jax.sharding.Mesh routes grouped aggs and joins
     # through the mesh-sharded executors (parallel/executors.py); None keeps
@@ -93,6 +94,17 @@ def join_state_pk(join_keys, stream_pk) -> list:
     a pk prefix scan (the reference's JoinHashMap tables are likewise
     keyed join-key-first, managed_state/join/mod.rs)."""
     return list(join_keys) + [i for i in stream_pk if i not in join_keys]
+
+
+def fanout_row_key(join_keys, stream_pk):
+    """A join side's row key where one join key may hold many rows — its
+    stream key is known and not inside the join key (a source's rows, a
+    GROUP BY wider than the join key) — else None: a side whose stream
+    key lies inside its join key holds at most one live row a key and
+    keeps the bucket arena (ops/join_state.py "Side layouts")."""
+    if not stream_pk or set(stream_pk) <= set(join_keys):
+        return None
+    return tuple(join_state_pk(join_keys, stream_pk))
 
 
 class BuildContext:
@@ -266,7 +278,10 @@ def _build_plan(plan: P.PlanNode, ctx: BuildContext) -> Executor:
             bucket_width=cfg.join_bucket_width,
             out_capacity=cfg.chunk_capacity,
             hbm_key_budget=cfg.join_hbm_budget,
-            null_aware_anti=getattr(plan, "null_aware", False))
+            null_aware_anti=getattr(plan, "null_aware", False),
+            row_keys=(fanout_row_key(plan.left_keys, plan.left.pk),
+                      fanout_row_key(plan.right_keys, plan.right.pk)),
+            row_capacity=cfg.join_row_capacity)
 
     if isinstance(plan, P.PTopN):
         inp = build_plan(plan.input, ctx)

@@ -298,6 +298,7 @@ class Session:
                 agg_table_capacity=st.agg_table_capacity,
                 join_key_capacity=st.join_key_capacity,
                 join_bucket_width=st.join_bucket_width,
+                join_row_capacity=st.join_row_capacity,
                 topn_table_capacity=st.topn_table_capacity,
                 fragment_parallelism=st.fragment_parallelism,
                 coschedule=st.coschedule,
@@ -1626,6 +1627,7 @@ class Session:
                 "agg_table_capacity": cfg.agg_table_capacity,
                 "join_key_capacity": cfg.join_key_capacity,
                 "join_bucket_width": cfg.join_bucket_width,
+                "join_row_capacity": cfg.join_row_capacity,
                 "topn_table_capacity": cfg.topn_table_capacity,
                 "agg_hbm_budget": cfg.agg_hbm_budget,
             },
@@ -1959,6 +1961,7 @@ class Session:
                     "agg_table_capacity": cfg.agg_table_capacity,
                     "join_key_capacity": cfg.join_key_capacity,
                     "join_bucket_width": cfg.join_bucket_width,
+                    "join_row_capacity": cfg.join_row_capacity,
                     "topn_table_capacity": cfg.topn_table_capacity,
                     "agg_hbm_budget": cfg.agg_hbm_budget,
                 },

@@ -32,10 +32,25 @@ chunk would be mis-ordered; that pattern trips the ``inconsistent`` flag
 
 Join-key NULLs never match (SQL semantics), unlike GROUP BY: rows with a null
 key are stored (for deletes / outer emission) but masked out of probing.
+
+**Side layouts.** Each side has its own geometry (``SideLayout``). The
+bucket arena above suits a side with few rows a key. A side whose key
+holds thousands of rows (NEXmark q5's window key) takes the **fan-out
+arena** instead (``FanoutSideState``): one flat ``[rows]`` array a
+column, each row found by a hash table on the side's row key (the
+state table's key), so an insert or a delete of a row costs one table
+probe whatever its key holds. A probe from the other side scans the
+arena's key column against blocks of ``FANOUT_BLOCK`` live probe rows (one
+dense masked compare, the condition fused in), compacts the passing
+pairs by count -> prefix sum -> gather into a pair buffer of the chunk's
+own static size, and emits from it; degrees and the degree transitions
+come from per-row counts, never from an ``[N, W]`` grid. The bucket
+arena probed by any side costs ``[N, W]`` of ITS width.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import enum
 from typing import Optional, Sequence
 
@@ -48,7 +63,7 @@ from ..common.chunk import (
     StreamChunk,
 )
 from ..common.types import Schema
-from .ckpt_delta import delta_window
+from .ckpt_delta import delta_window, dirty_slots
 from .hash_table import DeviceHashTable, ht_lookup, ht_lookup_or_insert, ht_new
 
 
@@ -56,6 +71,8 @@ from .hash_table import DeviceHashTable, ht_lookup, ht_lookup_or_insert, ht_new
 #: tombstoned lane by another state-table key between two checkpoints;
 #: past it the insert reports ``lane_overflow`` and the bucket width grows
 GRAVE_ROWS = 1 << 13
+#: live probe rows a fan-out scan compares against the arena at once
+FANOUT_BLOCK = 8
 
 
 class JoinType(enum.Enum):
@@ -113,9 +130,54 @@ class JoinSideState:
 
 
 @struct.dataclass
+class FanoutSideState:
+    """A side whose key may hold thousands of rows: ``[rows]`` per column.
+    ``ht`` maps the side's row key to its slot, and the slot IS the row,
+    so an insert or a delete finds its row by one table probe. A deleted
+    row keeps its slot (and its values, for the durable delete) until a
+    rehash drops it; the same row key inserted again takes it back."""
+    ht: DeviceHashTable                 # row key -> row
+    row_data: tuple[jax.Array, ...]     # per column: dtype[rows]
+    row_mask: tuple[jax.Array, ...]     # per column: bool[rows]
+    occupied: jax.Array                 # bool[rows]
+    tomb: jax.Array                     # bool[rows] — deleted since last ckpt
+    degree: jax.Array                   # int32[rows] — opposite-side matches
+    ckpt_dirty: jax.Array               # bool[rows] — changed since last ckpt
+    # what the last step's probes of this side did: live probe rows,
+    # candidate rows read (rows of their keys), pairs passed
+    probe_counts: jax.Array             # int64[3]
+    ht_overflow: jax.Array              # bool scalar, sticky: rows full
+    lane_overflow: jax.Array            # bool scalar, sticky: pair buffer full
+    inconsistent: jax.Array             # bool scalar, sticky
+
+
+@struct.dataclass
 class JoinState:
-    left: JoinSideState
-    right: JoinSideState
+    left: JoinSideState | FanoutSideState
+    right: JoinSideState | FanoutSideState
+
+
+@dataclasses.dataclass(frozen=True)
+class SideLayout:
+    """One side's device geometry. Bucket arena (``row_key`` None):
+    ``capacity`` key slots of ``width`` lanes. Fan-out arena: ``capacity``
+    rows found by ``row_key`` (the columns that identify a row), and
+    ``width`` pair slots per row of a chunk that probes the side."""
+    capacity: int
+    width: int = 1
+    row_key: Optional[tuple] = None
+
+    @property
+    def fanout(self) -> bool:
+        return self.row_key is not None
+
+    @property
+    def cells(self) -> int:
+        return self.capacity if self.fanout else self.capacity * self.width
+
+
+def _other(side: str) -> str:
+    return "right" if side == "left" else "left"
 
 
 class JoinCore:
@@ -134,6 +196,7 @@ class JoinCore:
         key_capacity: int = 1 << 13,
         bucket_width: int = 16,
         state_pks: tuple = (None, None),
+        layouts: Optional[tuple] = None,
     ):
         """``state_pks``: per side, the columns of the durable state
         table's key (``()``: the side has no durable tier). An insert may
@@ -141,16 +204,19 @@ class JoinCore:
         row's state-table key differs from the new row's, the old row is
         kept in the side's graveyard for the checkpoint to delete. ``None``
         (the key is not known here) buries every such row: a delete too
-        many is harmless, deletes are staged before puts."""
+        many is harmless, deletes are staged before puts.
+
+        ``layouts``: per side a ``SideLayout``; without it both sides are
+        bucket arenas of ``key_capacity`` x ``bucket_width``."""
         self.left_schema = left_schema
         self.right_schema = right_schema
         self.left_keys = tuple(left_keys)
         self.right_keys = tuple(right_keys)
         self.join_type = join_type
         self.condition = condition
-        self.capacity = key_capacity
-        self.W = bucket_width
-        self.grave_rows = min(key_capacity * bucket_width, GRAVE_ROWS)
+        if layouts is None:
+            layouts = (SideLayout(key_capacity, bucket_width),) * 2
+        self.layout = {"left": layouts[0], "right": layouts[1]}
         self.state_pks = {"left": state_pks[0], "right": state_pks[1]}
         lkt = tuple(left_schema[i].type for i in self.left_keys)
         rkt = tuple(right_schema[i].type for i in self.right_keys)
@@ -165,11 +231,36 @@ class JoinCore:
         else:
             self.out_schema = left_schema.concat(right_schema)
 
+    @property
+    def capacity(self) -> int:
+        """The bucket arenas' key capacity (the larger, where they differ)."""
+        return max((lay.capacity for lay in self.layout.values()
+                    if not lay.fanout), default=0)
+
+    @property
+    def W(self) -> int:
+        """The bucket arenas' width (the larger, where they differ)."""
+        return max((lay.width for lay in self.layout.values()
+                    if not lay.fanout), default=0)
+
+    def grave_rows(self, side: str) -> int:
+        return min(self.layout[side].cells, GRAVE_ROWS)
+
+    def schema_of(self, side: str) -> Schema:
+        return self.left_schema if side == "left" else self.right_schema
+
+    def keys_of(self, side: str) -> tuple:
+        return self.left_keys if side == "left" else self.right_keys
+
     # -- state ----------------------------------------------------------------
 
-    def _new_side(self, schema: Schema, key_idx: Sequence[int]) -> JoinSideState:
-        cap, W = self.capacity, self.W
-        key_types = tuple(schema[i].type for i in key_idx)
+    def _new_side(self, side: str):
+        lay, schema = self.layout[side], self.schema_of(side)
+        if lay.fanout:
+            return _new_fanout(schema, lay)
+        cap, W = lay.capacity, lay.width
+        key_types = tuple(schema[i].type for i in self.keys_of(side))
+        grave_rows = self.grave_rows(side)
         return JoinSideState(
             ht=ht_new(key_types, cap),
             row_data=tuple(jnp.zeros((cap, W), f.type.dtype) for f in schema),
@@ -178,9 +269,9 @@ class JoinCore:
             tomb=jnp.zeros((cap, W), jnp.bool_),
             degree=jnp.zeros((cap, W), jnp.int32),
             ckpt_dirty=jnp.zeros((cap, W), jnp.bool_),
-            grave_data=tuple(jnp.zeros(self.grave_rows, f.type.dtype)
+            grave_data=tuple(jnp.zeros(grave_rows, f.type.dtype)
                              for f in schema),
-            grave_mask=tuple(jnp.zeros(self.grave_rows, jnp.bool_)
+            grave_mask=tuple(jnp.zeros(grave_rows, jnp.bool_)
                              for _ in schema),
             grave_n=jnp.zeros((), jnp.int32),
             lru=jnp.zeros(cap, jnp.int32),
@@ -190,10 +281,8 @@ class JoinCore:
         )
 
     def init_state(self) -> JoinState:
-        return JoinState(
-            left=self._new_side(self.left_schema, self.left_keys),
-            right=self._new_side(self.right_schema, self.right_keys),
-        )
+        return JoinState(left=self._new_side("left"),
+                         right=self._new_side("right"))
 
     # -- the step --------------------------------------------------------------
 
@@ -201,17 +290,24 @@ class JoinCore:
                     step=None):
         """Join one chunk arriving on ``side``; returns (state, big_chunk).
 
-        ``big_chunk`` has capacity 2*N*(2W+1) and is mostly invisible; compact
-        it with gather_units_window before sending downstream.
+        ``big_chunk`` is mostly invisible: the delete pass's emission then
+        the insert pass's (``_empty_out``'s shape); compact it with
+        gather_units_window before sending downstream.
 
         ``step``: optional int32 LRU stamp — when set, both the own-side
         key slot and every probed opposite-side slot are touched, so the
         two sides' stamps for one key value stay in sync (the invariant
-        cold-tier eviction relies on to evict a key from BOTH arenas)."""
+        cold-tier eviction relies on to evict a key from BOTH arenas).
+        A fan-out side keeps no stamps."""
         is_del = chunk.vis & (
             (chunk.ops == OP_DELETE) | (chunk.ops == OP_UPDATE_DELETE))
         is_ins = chunk.vis & (
             (chunk.ops == OP_INSERT) | (chunk.ops == OP_UPDATE_INSERT))
+        for s in ("left", "right"):
+            if self.layout[s].fanout:        # the counts are this step's
+                st = getattr(state, s)
+                state = state.replace(**{s: st.replace(
+                    probe_counts=jnp.zeros(3, jnp.int64))})
 
         def run_del(st):
             return self._pass(st, chunk, is_del, False, side, step)
@@ -220,7 +316,7 @@ class JoinCore:
             return self._pass(st, chunk, is_ins, True, side, step)
 
         def skip(st):
-            return st, self._empty_out(chunk.capacity)
+            return st, self._empty_out(chunk.capacity, side)
 
         state, out_d = jax.lax.cond(jnp.any(is_del), run_del, skip, state)
         state, out_i = jax.lax.cond(jnp.any(is_ins), run_ins, skip, state)
@@ -233,56 +329,72 @@ class JoinCore:
         )
         return state, StreamChunk(ops, vis, cols)
 
-    def emit_counts(self, big: StreamChunk) -> tuple:
+    def emit_counts(self, big: StreamChunk, side: str) -> tuple:
         """``(rows_out, null_padded_out, transitions, matched, unmatched)``
-        of one step's emission grid, as int64 scalars: the visible rows;
-        those on the ``pself`` lane (lane 2W: the input row NULL-padded,
-        or a semi / anti join's own row); and the degree transitions of
-        the opposite side that the step emitted — the second rows of an
-        outer join's update pairs (lanes 2w+1), a semi / anti join's
-        opposite rows (lanes 2w; its own-side passes leave them empty) —
-        in all and by direction: ``matched`` 0 -> 1 (the insert pass, the
-        grid's second half), ``unmatched`` 1 -> 0 (the delete pass, its
-        first)."""
-        W = self.W
-        vis = big.vis.reshape(2, -1, 2 * W + 1)       # [delete | insert] pass
+        of one step's emission (a chunk that arrived on ``side``), as int64
+        scalars: the visible rows; those on the ``pself`` lane (the input
+        row NULL-padded, or a semi / anti join's own row); and the degree
+        transitions of the opposite side that the step emitted — the
+        second rows of an outer join's update pairs (lane 1 of a match), a
+        semi / anti join's opposite rows (lane 0; its own-side passes leave
+        them empty) — in all and by direction: ``matched`` 0 -> 1 (the
+        insert pass, the emission's second half), ``unmatched`` 1 -> 0 (the
+        delete pass, its first). A match is lanes 2w / 2w+1 of the bucket
+        grid, or one slot of a fan-out probe's pair buffer."""
+        vis = big.vis.reshape(2, -1)                 # [delete | insert] pass
         first = 1 if self.join_type.semi_anti_side is None else 0
-        unmatched, matched = jnp.sum(vis[:, :, first:2 * W:2], axis=(1, 2),
+        lay = self.layout[_other(side)]
+        if lay.fanout:
+            n_self = vis.shape[1] // (2 * lay.width + 1)
+            lanes = vis[:, :-n_self].reshape(2, -1, 2)[:, :, first]
+            own = vis[:, -n_self:]
+        else:
+            W = lay.width
+            grid = vis.reshape(2, -1, 2 * W + 1)
+            lanes = grid[:, :, first:2 * W:2]
+            own = grid[:, :, 2 * W]
+        unmatched, matched = jnp.sum(lanes.reshape(2, -1), axis=1,
                                      dtype=jnp.int64)
-        return (jnp.sum(vis, dtype=jnp.int64),
-                jnp.sum(vis[:, :, 2 * W], dtype=jnp.int64),
+        return (jnp.sum(vis, dtype=jnp.int64), jnp.sum(own, dtype=jnp.int64),
                 matched + unmatched, matched, unmatched)
 
     # -- internals -------------------------------------------------------------
 
-    def _empty_out(self, N: int):
-        L = 2 * self.W + 1
+    def _empty_out(self, N: int, side: str):
+        lay = self.layout[_other(side)]
+        shape = ((2 * N * lay.width + N,) if lay.fanout
+                 else (N, 2 * lay.width + 1))
         return (
-            jnp.zeros((N, L), jnp.int8),
-            jnp.zeros((N, L), jnp.bool_),
+            jnp.zeros(shape, jnp.int8),
+            jnp.zeros(shape, jnp.bool_),
             tuple(
-                (jnp.zeros((N, L), f.type.dtype), jnp.zeros((N, L), jnp.bool_))
+                (jnp.zeros(shape, f.type.dtype), jnp.zeros(shape, jnp.bool_))
                 for f in self.out_schema
             ),
         )
 
+    def _pair_condition(self, a_cols, b_datas, b_masks, side: str):
+        """The non-equi condition on aligned flat pairs: ``a_cols`` the
+        input side's columns and ``b_*`` the opposite side's, one entry a
+        pair -> bool[pairs]."""
+        n = b_datas[0].shape[0]
+        b_cols = [Column(d, m) for d, m in zip(b_datas, b_masks)]
+        pair = list(a_cols) + b_cols if side == "left" else b_cols + list(a_cols)
+        pseudo = StreamChunk(
+            jnp.zeros(n, jnp.int8), jnp.ones(n, jnp.bool_), tuple(pair))
+        res = self.condition.eval(pseudo)
+        return res.data & res.mask
+
     def _eval_condition(self, chunk, b_datas, b_masks, side: str):
         """Evaluate the non-equi condition on all candidate pairs -> bool[N, W]."""
-        N, W = chunk.capacity, self.W
+        N, W = chunk.capacity, b_datas[0].shape[1]
         a_cols = [
             Column(jnp.repeat(c.data, W), jnp.repeat(c.mask, W))
             for c in chunk.columns
         ]
-        b_cols = [
-            Column(d.reshape(-1), m.reshape(-1))
-            for d, m in zip(b_datas, b_masks)
-        ]
-        pair = a_cols + b_cols if side == "left" else b_cols + a_cols
-        pseudo = StreamChunk(
-            jnp.zeros(N * W, jnp.int8), jnp.ones(N * W, jnp.bool_), tuple(pair)
-        )
-        res = self.condition.eval(pseudo)
-        return (res.data & res.mask).reshape(N, W)
+        return self._pair_condition(
+            a_cols, [d.reshape(-1) for d in b_datas],
+            [m.reshape(-1) for m in b_masks], side).reshape(N, W)
 
     def _pass(self, state: JoinState, chunk: StreamChunk, sel: jax.Array,
               is_insert: bool, side: str, step=None):
@@ -297,23 +409,37 @@ class JoinCore:
             has_null_key = has_null_key | ~c.mask
         match_ok = sel & ~has_null_key
 
-        with jax.named_scope("join_probe"):
-            B, probed = self._probe(B, chunk, a_key_cols, match_ok,
-                                    is_insert, side, step)
+        fan_b = self.layout[_other(side)].fanout
+        with jax.named_scope("join_fanout_scan" if fan_b else "join_probe"):
+            if fan_b:
+                B, probed = self._probe_fanout(B, chunk, a_key_cols, match_ok,
+                                               is_insert, side)
+            else:
+                B, probed = self._probe(B, chunk, a_key_cols, match_ok,
+                                        is_insert, side, step)
         with jax.named_scope("join_insert" if is_insert else "join_delete"):
-            A = self._update_own(A, chunk, a_key_cols, sel, is_insert,
-                                 probed[1], step, self.state_pks[side])
+            if self.layout[side].fanout:
+                A = self._update_own_fanout(A, chunk, sel, is_insert,
+                                            probed[1], side)
+            else:
+                A = self._update_own(A, chunk, a_key_cols, sel, is_insert,
+                                     probed[1], step, self.state_pks[side],
+                                     side)
         state = (state.replace(left=A, right=B) if side == "left"
                  else state.replace(left=B, right=A))
         with jax.named_scope("join_emit"):
-            out = self._emit(chunk, sel, is_insert, side, *probed)
+            if fan_b:
+                out = self._emit_pairs(chunk, sel, is_insert, side, *probed)
+            else:
+                out = self._emit(chunk, sel, is_insert, side, *probed)
         return state, out
 
     def _probe(self, B: JoinSideState, chunk: StreamChunk, a_key_cols,
                match_ok, is_insert: bool, side: str, step):
         """Probe the opposite side for all rows at once and maintain its
         degrees; returns it and what ``_emit`` takes of the probe."""
-        cap, W = self.capacity, self.W
+        lay = self.layout[_other(side)]
+        cap, W = lay.capacity, lay.width
         b_slot, b_found = ht_lookup(B.ht, a_key_cols, match_ok)
         bs = jnp.where(b_found, b_slot, 0)
         occ_b = B.occupied[bs] & b_found[:, None]                      # [N, W]
@@ -348,9 +474,109 @@ class JoinCore:
                           .max(step, mode="drop"))
         return B, (matches, c_cnt, r, t, d0, b_datas, b_masks)
 
+    def _probe_fanout(self, B: FanoutSideState, chunk: StreamChunk,
+                      a_key_cols, match_ok, is_insert: bool, side: str):
+        """Probe a fan-out side: the live probe rows, ``FANOUT_BLOCK`` at a
+        time, against the arena's key column — one dense masked compare
+        with the condition fused in (``[block, rows]``, reduced at once to
+        per-row and per-probe counts). The arena rows with a passing pair
+        are compacted (prefix count + binary search), the pairs among them
+        laid out probe row by probe row into the pair buffer (``width``
+        slots a chunk row), each with its degree transition: insert — the
+        row's degree before the pass plus the earlier probe rows' matches
+        of it is 0; delete — this match takes it to 0. The degrees move by
+        the per-row counts. Returns the side and ``(pairs, c_cnt)``; a
+        pass whose pairs outrun the buffer sets the side's
+        ``lane_overflow`` (the executor widens it and runs the chunk again)."""
+        lay = self.layout[_other(side)]
+        N, R, P = chunk.capacity, lay.capacity, FANOUT_BLOCK
+        PB = N * lay.width
+        b_keys = self.keys_of(_other(side))
+        n_live = jnp.sum(match_ok, dtype=jnp.int32)
+        live = jnp.nonzero(match_ok, size=N + P,
+                           fill_value=N)[0].astype(jnp.int32)
+        d0 = B.degree
+        b_key_d = [B.row_data[i] for i in b_keys]
+        b_key_m = [B.row_mask[i] & B.occupied for i in b_keys]
+
+        def block_matches(rows, b_rows):
+            """bool[P, X]: probe rows ``rows`` [P] (N = none) against the
+            arena rows ``b_rows`` (None: all of them)."""
+            ok = rows < N
+            at = jnp.minimum(rows, N - 1)
+            take = (lambda x: x) if b_rows is None else (lambda x: x[b_rows])
+            m = ok[:, None]
+            for kd, km, c in zip(b_key_d, b_key_m, a_key_cols):
+                m = m & take(km)[None, :] & (take(kd)[None, :]
+                                             == c.data[at][:, None])
+            keq = m
+            if self.condition is not None:
+                X = m.shape[1]
+                a_cols = [Column(jnp.broadcast_to(c.data[at][:, None],
+                                                  (P, X)).reshape(-1),
+                                 jnp.broadcast_to(c.mask[at][:, None],
+                                                  (P, X)).reshape(-1))
+                          for c in chunk.columns]
+                b_d = [jnp.broadcast_to(take(d)[None, :], (P, X)).reshape(-1)
+                       for d in B.row_data]
+                b_m = [jnp.broadcast_to(take(mk)[None, :], (P, X)).reshape(-1)
+                       for mk in B.row_mask]
+                m = m & self._pair_condition(a_cols, b_d, b_m,
+                                             side).reshape(P, X)
+            return keq, m
+
+        def body(carry):
+            b, seen, c_cnt, p_row, p_b, p_trans, total, cand = carry
+            rows = jax.lax.dynamic_slice(live, (b * P,), (P,))
+            keq, m = block_matches(rows, None)
+            per_row = jnp.sum(m, axis=0, dtype=jnp.int32)             # [R]
+            c_cnt = c_cnt.at[rows].set(
+                jnp.sum(m, axis=1, dtype=jnp.int32), mode="drop")
+            cand = cand + jnp.sum(keq, dtype=jnp.int64)
+            # the arena rows with a passing pair, in row order
+            hit, hit_ok = dirty_slots(
+                jnp.cumsum(per_row > 0, dtype=jnp.int32), jnp.int32(0), PB)
+            _, ms = block_matches(rows, hit)
+            ms = ms & hit_ok[None, :]                                   # [P, PB]
+            r = seen[hit][None, :] + jnp.cumsum(ms, axis=0, dtype=jnp.int32) \
+                - ms
+            trans = ms & ((d0[hit][None, :] + r == 0) if is_insert
+                          else (d0[hit][None, :] - r - 1 == 0))
+            # this block's pairs, probe row by probe row, after the
+            # earlier blocks' in the buffer
+            flat = ms.reshape(-1)
+            rank = jnp.cumsum(flat, dtype=jnp.int32)
+            q = jnp.arange(PB, dtype=jnp.int32) - total
+            mine = (q >= 0) & (q < rank[-1])
+            at = jnp.searchsorted(rank, q + 1, side="left").astype(jnp.int32)
+            at = jnp.where(mine, at, 0)
+            p_row = jnp.where(mine, rows[at // PB], p_row)
+            p_b = jnp.where(mine, hit[at % PB], p_b)
+            p_trans = jnp.where(mine, trans.reshape(-1)[at], p_trans)
+            # the true count: an overflow shows even where more arena
+            # rows than the buffer holds had a pair
+            return (b + 1, seen + per_row, c_cnt, p_row, p_b, p_trans,
+                    total + jnp.sum(per_row, dtype=jnp.int32), cand)
+
+        init = (jnp.int32(0), jnp.zeros(R, jnp.int32), jnp.zeros(N, jnp.int32),
+                jnp.zeros(PB, jnp.int32), jnp.zeros(PB, jnp.int32),
+                jnp.zeros(PB, jnp.bool_), jnp.int32(0), jnp.int64(0))
+        _, seen, c_cnt, p_row, p_b, p_trans, total, cand = jax.lax.while_loop(
+            lambda c: c[0] * P < n_live, body, init)
+        # degrees are rebuilt on recovery, not persisted — no ckpt_dirty here
+        B = B.replace(
+            degree=d0 + (seen if is_insert else -seen),
+            probe_counts=B.probe_counts + jnp.stack(
+                [n_live.astype(jnp.int64), cand, total.astype(jnp.int64)]),
+            lane_overflow=B.lane_overflow | (total > PB))
+        n_pairs = jnp.minimum(total, PB)
+        return B, ((p_row, p_b, p_trans, n_pairs,
+                    tuple(d[p_b] for d in B.row_data),
+                    tuple(m[p_b] for m in B.row_mask)), c_cnt)
+
     def _update_own(self, A: JoinSideState, chunk: StreamChunk, a_key_cols,
                     sel, is_insert: bool, c_cnt, step,
-                    state_pk=None) -> JoinSideState:
+                    state_pk=None, side: str = "left") -> JoinSideState:
         """The input side's arena: place the inserted rows (each with its
         degree ``c_cnt``, the matches the probe found), or tombstone the
         deleted ones. An insert takes a free lane of its key's bucket
@@ -358,7 +584,8 @@ class JoinCore:
         checkpoint (the U+ of an update pair lands where its U- was): the
         lane is then BOTH occupied and tombstoned, which the checkpoint
         reads as a put."""
-        cap, W = self.capacity, self.W
+        lay = self.layout[side]
+        cap, W = lay.capacity, lay.width
         N = chunk.capacity
         idx = jnp.arange(N)
         if is_insert:
@@ -442,6 +669,43 @@ class JoinCore:
                               .max(step, mode="drop"))
         return A
 
+    def _update_own_fanout(self, A: FanoutSideState, chunk: StreamChunk, sel,
+                           is_insert: bool, c_cnt, side: str):
+        """The input side's fan-out arena: a row is found by its row key
+        (one table probe), whatever its join key holds. An insert takes
+        the row's slot (a row key inserted again, such as the U+ of an
+        update pair, takes back the slot of its U-: occupied and
+        tombstoned, which the checkpoint reads as a put) and its degree
+        ``c_cnt``; a delete tombstones it. An insert of a live row or a
+        delete of an absent one is ``inconsistent``."""
+        R = self.layout[side].capacity
+        key_cols = [chunk.columns[i] for i in self.layout[side].row_key]
+        if is_insert:
+            ht, slot, _, ht_ovf = ht_lookup_or_insert(A.ht, key_cols, sel)
+            ok = sel & (slot < R)
+            f = jnp.where(ok, slot, R)
+            was_live = A.occupied[jnp.minimum(slot, R - 1)] & ok
+            return A.replace(
+                ht=ht,
+                occupied=A.occupied.at[f].set(True, mode="drop"),
+                row_data=tuple(rd.at[f].set(c.data, mode="drop")
+                               for rd, c in zip(A.row_data, chunk.columns)),
+                row_mask=tuple(rm.at[f].set(c.mask, mode="drop")
+                               for rm, c in zip(A.row_mask, chunk.columns)),
+                degree=A.degree.at[f].set(c_cnt, mode="drop"),
+                ckpt_dirty=A.ckpt_dirty.at[f].set(True, mode="drop"),
+                ht_overflow=A.ht_overflow | ht_ovf | jnp.any(sel & ~ok),
+                inconsistent=A.inconsistent | jnp.any(was_live))
+        slot, found = ht_lookup(A.ht, key_cols, sel)
+        ok = sel & found & A.occupied[jnp.minimum(slot, R - 1)]
+        f = jnp.where(ok, slot, R)
+        # values stay in row_data for the durable-tier delete at checkpoint
+        return A.replace(
+            occupied=A.occupied.at[f].set(False, mode="drop"),
+            tomb=A.tomb.at[f].set(True, mode="drop"),
+            ckpt_dirty=A.ckpt_dirty.at[f].set(True, mode="drop"),
+            inconsistent=A.inconsistent | jnp.any(sel & ~ok))
+
     def _bury_refilled(self, A: JoinSideState, chunk: StreamChunk, f,
                        refill, state_pk):
         """Before the rows of ``chunk`` overwrite the tombstoned lanes
@@ -453,7 +717,7 @@ class JoinCore:
         ``any`` and a skipped branch."""
         if state_pk is not None and not len(state_pk):
             return A, jnp.zeros((), jnp.bool_)     # no durable tier
-        G = self.grave_rows
+        G = A.grave_data[0].shape[0]
 
         def bury(grave):
             at = jnp.where(refill, f, 0)
@@ -487,60 +751,71 @@ class JoinCore:
                           grave_n=jnp.minimum(A.grave_n + n_owed, G)),
                 A.grave_n + n_owed > G)
 
-    def _emit(self, chunk, sel, is_insert: bool, side: str, matches, c_cnt,
-              r, t, d0, b_datas, b_masks):
-        """Build the [N, 2W+1] emission grid for one pass."""
-        N, W = chunk.capacity, self.W
+    def _lane_rules(self, matches, trans, is_insert: bool, side: str):
+        """What a match emits, for matches of any shape: ``(p0, p1, op0,
+        a0, a1)`` — its first / second row visible, the first row's op
+        (the second's is always U+), and whether the input side's columns
+        are non-null in the first / second row."""
         jt = self.join_type
         sa = jt.semi_anti_side
         op_plain = OP_INSERT if is_insert else OP_DELETE
-
-        a_outer = (jt.preserves_left if side == "left" else jt.preserves_right)
         b_outer = (jt.preserves_right if side == "left" else jt.preserves_left)
-
-        p0 = jnp.zeros((N, W), jnp.bool_)   # lane 2w visible
-        p1 = jnp.zeros((N, W), jnp.bool_)   # lane 2w+1 visible
-        op0 = jnp.full((N, W), op_plain, jnp.int8)
-        op1 = jnp.full((N, W), OP_UPDATE_INSERT, jnp.int8)
-        pself = jnp.zeros(N, jnp.bool_)     # lane 2W visible
-        # per-lane "A columns are non-null" (B cols are non-null in any pair lane)
-        a0 = jnp.ones((N, W), jnp.bool_)
-        a1 = jnp.ones((N, W), jnp.bool_)
-
-        if is_insert:
-            trans = matches & (d0 + r == 0)
-        else:
-            trans = matches & (d0 - t == 0) & (r == t - 1)
-
+        none = jnp.zeros(matches.shape, jnp.bool_)
+        p0, p1 = none, none
+        op0 = jnp.full(matches.shape, op_plain, jnp.int8)
+        a0 = a1 = jnp.ones(matches.shape, jnp.bool_)
         if sa is None:
+            p0 = matches
             if b_outer:
-                # transition lanes emit an adjacent update pair replacing /
+                # transitions emit an adjacent update pair replacing /
                 # restoring the opposite side's null-padded row
-                p0 = matches
                 p1 = trans
                 op0 = jnp.where(trans, OP_UPDATE_DELETE, op_plain).astype(jnp.int8)
                 if is_insert:
                     a0 = ~trans   # U- row is (B row, A-null)
                 else:
-                    a1 = jnp.zeros((N, W), jnp.bool_)  # U+ row is (B row, A-null)
-            else:
-                p0 = matches
-            if a_outer:
-                pself = sel & (c_cnt == 0)
-        elif sa == side:
-            # input on the preserved side: emit/retract own row only
-            want = (c_cnt == 0) if jt.is_anti else (c_cnt > 0)
-            pself = sel & want
-        else:
+                    a1 = none     # U+ row is (B row, A-null)
+        elif sa != side:
             # input on the non-preserved side: emit/retract opposite rows on
             # degree transitions
             p0 = trans
-            if jt.is_anti:
-                op0 = jnp.full((N, W), OP_DELETE if is_insert else OP_INSERT,
-                               jnp.int8)
-            else:
-                op0 = jnp.full((N, W), OP_INSERT if is_insert else OP_DELETE,
-                               jnp.int8)
+            emit = (OP_DELETE if is_insert else OP_INSERT) if jt.is_anti \
+                else (OP_INSERT if is_insert else OP_DELETE)
+            op0 = jnp.full(matches.shape, emit, jnp.int8)
+        return p0, p1, op0, a0, a1
+
+    def _self_lane(self, sel, c_cnt, side: str):
+        """Whether each input row is emitted on its own (the ``pself``
+        lane): NULL-padded by an outer join that preserves its side, or a
+        semi / anti join's own row."""
+        jt = self.join_type
+        sa = jt.semi_anti_side
+        a_outer = (jt.preserves_left if side == "left" else jt.preserves_right)
+        if sa is None:
+            return sel & (c_cnt == 0) if a_outer else jnp.zeros_like(sel)
+        if sa == side:
+            # input on the preserved side: emit/retract own row only
+            return sel & ((c_cnt == 0) if jt.is_anti else (c_cnt > 0))
+        return jnp.zeros_like(sel)
+
+    def _out_columns(self, side: str, a_cols, b_cols):
+        sa = self.join_type.semi_anti_side
+        if sa is None:
+            return tuple(a_cols + b_cols if side == "left" else b_cols + a_cols)
+        return tuple(a_cols if sa == side else b_cols)
+
+    def _emit(self, chunk, sel, is_insert: bool, side: str, matches, c_cnt,
+              r, t, d0, b_datas, b_masks):
+        """Build the [N, 2W+1] emission grid for one pass."""
+        N, W = matches.shape
+        op_plain = OP_INSERT if is_insert else OP_DELETE
+        if is_insert:
+            trans = matches & (d0 + r == 0)
+        else:
+            trans = matches & (d0 - t == 0) & (r == t - 1)
+        p0, p1, op0, a0, a1 = self._lane_rules(matches, trans, is_insert, side)
+        op1 = jnp.full((N, W), OP_UPDATE_INSERT, jnp.int8)
+        pself = self._self_lane(sel, c_cnt, side)
 
         # ---- assemble ops/vis  [N, 2W+1]
         L = 2 * W + 1
@@ -572,15 +847,37 @@ class JoinCore:
             zeros_self = jnp.zeros(N, d.dtype)
             b_col_list.append(lanes(
                 d, m, d, m, zeros_self, jnp.zeros(N, jnp.bool_), d.dtype))
+        return ops, vis, self._out_columns(side, a_col_list, b_col_list)
 
-        if sa is None:
-            cols = (a_col_list + b_col_list if side == "left"
-                    else b_col_list + a_col_list)
-        elif sa == side:
-            cols = a_col_list
-        else:
-            cols = b_col_list
-        return ops, vis, tuple(cols)
+    def _emit_pairs(self, chunk, sel, is_insert: bool, side: str, pairs,
+                    c_cnt):
+        """One pass's emission from a fan-out probe: the pair buffer's
+        slots, two rows each (a match's first and second row, as lanes
+        2w / 2w+1 of the grid), then the chunk's own rows (the ``pself``
+        lane) — ``2 x slots + N`` rows, flat."""
+        p_row, p_b, trans, n_pairs, b_datas, b_masks = pairs
+        PB = p_row.shape[0]
+        op_plain = OP_INSERT if is_insert else OP_DELETE
+        matches = jnp.arange(PB, dtype=jnp.int32) < n_pairs
+        trans = trans & matches
+        p0, p1, op0, a0, a1 = self._lane_rules(matches, trans, is_insert, side)
+        pself = self._self_lane(sel, c_cnt, side)
+
+        def rows(first, second, own):
+            return jnp.concatenate(
+                [jnp.stack([first, second], axis=1).reshape(-1), own])
+
+        ops = rows(op0, jnp.full(PB, OP_UPDATE_INSERT, jnp.int8),
+                   jnp.full(chunk.capacity, op_plain, jnp.int8))
+        vis = rows(p0, p1, pself)
+        a_cols = []
+        for c in chunk.columns:
+            d, m = c.data[p_row], c.mask[p_row]
+            a_cols.append((rows(d, d, c.data), rows(m & a0, m & a1, c.mask)))
+        none = jnp.zeros(chunk.capacity, jnp.bool_)
+        b_cols = [(rows(d, d, jnp.zeros(chunk.capacity, d.dtype)),
+                   rows(m, m, none)) for d, m in zip(b_datas, b_masks)]
+        return ops, vis, self._out_columns(side, a_cols, b_cols)
 
 
 def clean_side_below(st: JoinSideState, col_idx: int, threshold) -> JoinSideState:
@@ -600,16 +897,20 @@ def clean_side_below(st: JoinSideState, col_idx: int, threshold) -> JoinSideStat
 
 
 @jax.named_scope("ckpt_delta")
-def join_ckpt_delta_window(st: JoinSideState, lo: jax.Array, G: int):
+def join_ckpt_delta_window(st, lo: jax.Array, G: int):
     """One side's checkpoint delta for dirty ranks [lo, lo+G): ``(n_dirty,
     valid[G], occupied, tomb, row_data, row_mask)``, each column gathered
     to ``G`` rows. The dirty lanes of the ``[capacity, W]`` arena come
     first, read row-major (slot, then lane; ``ckpt_delta.delta_window``);
-    the graveyard's rows follow them as tombstones. Degrees, LRU stamps
-    and the key table are not persisted: recovery rebuilds them."""
+    the graveyard's rows follow them as tombstones. A fan-out arena's
+    dirty rows are read in row order; it has no graveyard (a row key
+    always takes back its own slot). Degrees, LRU stamps and the key table
+    are not persisted: recovery rebuilds them."""
     n_arena, valid, (occ, tomb, datas, masks) = delta_window(
         st.ckpt_dirty, (st.occupied, st.tomb, st.row_data, st.row_mask),
         lo, G)
+    if isinstance(st, FanoutSideState):
+        return n_arena, valid, occ, tomb, datas, masks
     k = lo.astype(jnp.int32) + jnp.arange(G, dtype=jnp.int32) - n_arena
     buried = (k >= 0) & (k < st.grave_n)
     at = jnp.clip(k, 0, st.grave_mask[0].shape[0] - 1)
@@ -621,14 +922,86 @@ def join_ckpt_delta_window(st: JoinSideState, lo: jax.Array, G: int):
                   for g, m in zip(st.grave_mask, masks)))
 
 
-def compact_side(core: "JoinCore", old: JoinSideState, schema: Schema,
-                 key_idx: Sequence[int]) -> JoinSideState:
+def _new_fanout(schema: Schema, lay: SideLayout) -> FanoutSideState:
+    R = lay.capacity
+    return FanoutSideState(
+        ht=ht_new(tuple(schema[i].type for i in lay.row_key), R),
+        row_data=tuple(jnp.zeros(R, f.type.dtype) for f in schema),
+        row_mask=tuple(jnp.zeros(R, jnp.bool_) for _ in schema),
+        occupied=jnp.zeros(R, jnp.bool_),
+        tomb=jnp.zeros(R, jnp.bool_),
+        degree=jnp.zeros(R, jnp.int32),
+        ckpt_dirty=jnp.zeros(R, jnp.bool_),
+        probe_counts=jnp.zeros(3, jnp.int64),
+        ht_overflow=jnp.zeros((), jnp.bool_),
+        lane_overflow=jnp.zeros((), jnp.bool_),
+        inconsistent=jnp.zeros((), jnp.bool_),
+    )
+
+
+def _fanout_of(schema: Schema, lay: SideLayout, groups, inconsistent):
+    """A fan-out arena of ``lay`` holding the rows of ``groups``: each a
+    tuple ``(datas, masks, occupied, tomb, degree, ckpt_dirty, keep)`` of
+    flat arrays, the rows where ``keep``; a later group's row wins a row
+    key that an earlier group's holds."""
+    st = _new_fanout(schema, lay)
+    R = lay.capacity
+    for datas, masks, occ, tomb, degree, dirty, keep in groups:
+        ht, slot, _, ovf = ht_lookup_or_insert(
+            st.ht, [Column(datas[i], masks[i]) for i in lay.row_key], keep)
+        f = jnp.where(keep & (slot < R), slot, R)
+        st = st.replace(
+            ht=ht,
+            row_data=tuple(rd.at[f].set(d, mode="drop")
+                           for rd, d in zip(st.row_data, datas)),
+            row_mask=tuple(rm.at[f].set(m, mode="drop")
+                           for rm, m in zip(st.row_mask, masks)),
+            occupied=st.occupied.at[f].set(occ, mode="drop"),
+            tomb=st.tomb.at[f].set(tomb, mode="drop"),
+            degree=st.degree.at[f].set(degree, mode="drop"),
+            ckpt_dirty=st.ckpt_dirty.at[f].set(dirty, mode="drop"),
+            ht_overflow=st.ht_overflow | ovf)
+    return st.replace(inconsistent=inconsistent)
+
+
+def _fanout_rows(st: FanoutSideState):
+    """A fan-out arena's rows as one ``_fanout_of`` group: those live or
+    owed a durable delete (a row deleted before the last checkpoint is
+    dropped)."""
+    return (st.row_data, st.row_mask, st.occupied, st.tomb, st.degree,
+            st.ckpt_dirty, st.occupied | st.tomb)
+
+
+def _bucket_rows(st: JoinSideState) -> list:
+    """A bucket arena's rows as ``_fanout_of`` groups, later winning: the
+    graveyard's (owed deletes), the tombstoned lanes', the live lanes'."""
+    G = st.grave_data[0].shape[0]
+    owed = jnp.arange(G) < st.grave_n
+    flat = (tuple(d.reshape(-1) for d in st.row_data),
+            tuple(m.reshape(-1) for m in st.row_mask))
+    occ, tomb = st.occupied.reshape(-1), st.tomb.reshape(-1)
+    degree, dirty = st.degree.reshape(-1), st.ckpt_dirty.reshape(-1)
+    return [
+        (st.grave_data, st.grave_mask, jnp.zeros(G, jnp.bool_),
+         jnp.ones(G, jnp.bool_), jnp.zeros(G, jnp.int32),
+         jnp.ones(G, jnp.bool_), owed),
+        (*flat, occ, tomb, degree, dirty, tomb & ~occ),
+        (*flat, occ, tomb, degree, dirty, occ),
+    ]
+
+
+def compact_side(core: "JoinCore", old, side: str):
     """Rebuild the side's hash table keeping only keys with live rows,
     remapping the bucket arrays — open-addressing slots cannot be freed in
     place (probe chains), so cleaning reclaims space by rebuild. Run AFTER
-    the checkpoint cleared tombstones (their deletes are persisted)."""
-    cap, W = core.capacity, core.W
-    key_types = tuple(schema[i].type for i in key_idx)
+    the checkpoint cleared tombstones (their deletes are persisted). A
+    fan-out arena is rebuilt from its live rows the same way."""
+    lay = core.layout[side]
+    schema = core.schema_of(side)
+    if lay.fanout:
+        return _fanout_of(schema, lay, [_fanout_rows(old)], old.inconsistent)
+    cap, W = lay.capacity, lay.width
+    key_types = tuple(schema[i].type for i in core.keys_of(side))
     key_live = old.ht.occupied & jnp.any(old.occupied | old.tomb, axis=1)
     key_cols = [
         Column(kd, km) for kd, km in zip(old.ht.key_data, old.ht.key_mask)
@@ -730,9 +1103,8 @@ def apply_evict_side(st: JoinSideState, mask: jax.Array) -> JoinSideState:
     )
 
 
-def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
-                key_idx: Sequence[int]) -> JoinSideState:
-    """Re-layout one side's state into ``core``'s (bigger) geometry.
+def import_side(core: "JoinCore", old, side: str):
+    """Re-layout one side's state into ``core``'s geometry for it.
 
     Functional growth: the streaming executor applies a chunk, checks the
     overflow flags, and on overflow discards the new state, grows, and
@@ -741,9 +1113,20 @@ def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
     reference growing its hash maps on the heap).
 
     Width growth pads lanes; capacity growth rehashes keys into the new
-    table and moves whole buckets by the slot remap. Degrees move with the
-    rows (they depend only on the opposite side's content)."""
-    cap, W = core.capacity, core.W
+    table and moves whole buckets by the slot remap. A bucket arena that
+    becomes a fan-out one hands over every row it holds (and its
+    graveyard's, as owed deletes); a fan-out arena rehashes its rows into
+    the new row count (a wider pair buffer changes no state). Degrees move
+    with the rows (they depend only on the opposite side's content)."""
+    lay = core.layout[side]
+    schema = core.schema_of(side)
+    if lay.fanout:
+        if isinstance(old, JoinSideState):
+            return _fanout_of(schema, lay, _bucket_rows(old), old.inconsistent)
+        if old.occupied.shape[0] == lay.capacity:
+            return old.replace(lane_overflow=jnp.zeros((), jnp.bool_))
+        return _fanout_of(schema, lay, [_fanout_rows(old)], old.inconsistent)
+    cap, W = lay.capacity, lay.width
     old_cap, old_W = old.occupied.shape
     assert cap >= old_cap and W >= old_W
 
@@ -761,13 +1144,13 @@ def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
     # the graveyard is no arena: it keeps its rows, in a buffer of the new
     # geometry's size
     def longer(a):
-        return jnp.zeros(core.grave_rows, a.dtype).at[:a.shape[0]].set(a)
+        return jnp.zeros(core.grave_rows(side), a.dtype).at[:a.shape[0]].set(a)
 
     grave = dict(grave_data=tuple(longer(g) for g in old.grave_data),
                  grave_mask=tuple(longer(g) for g in old.grave_mask),
                  grave_n=old.grave_n)
 
-    key_types = tuple(schema[i].type for i in key_idx)
+    key_types = tuple(schema[i].type for i in core.keys_of(side))
     if cap == old_cap:
         ht = old.ht
         new = JoinSideState(
@@ -810,7 +1193,5 @@ def import_side(core: "JoinCore", old: JoinSideState, schema: Schema,
 
 
 def import_state(core: "JoinCore", old: JoinState) -> JoinState:
-    return JoinState(
-        left=import_side(core, old.left, core.left_schema, core.left_keys),
-        right=import_side(core, old.right, core.right_schema, core.right_keys),
-    )
+    return JoinState(left=import_side(core, old.left, "left"),
+                     right=import_side(core, old.right, "right"))

@@ -17,6 +17,7 @@ managed_state/join/mod.rs:228-258).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Sequence
 
@@ -32,7 +33,7 @@ from ..common.chunk import (
 from ..common.fetch import fetch
 from ..common.tracing import CAT_STORAGE, conductor_epoch, span
 from ..ops.join_state import (
-    JoinCore, JoinSideState, JoinState, JoinType, apply_evict_side,
+    JoinCore, JoinState, JoinType, SideLayout, apply_evict_side,
     clean_side_below, compact_side, import_state, join_ckpt_delta_window,
     join_evict_plan,
 )
@@ -64,6 +65,8 @@ class HashJoinExecutor(Executor):
         load_shard: Optional[tuple] = None,
         hbm_key_budget: Optional[int] = None,
         null_aware_anti: bool = False,
+        row_keys: tuple = (None, None),
+        row_capacity: int = 1 << 16,
     ):
         """``interval_clean``: state-cleaning rules for interval/windowed
         joins — tuples ``(clean_side, clean_col, watch_side, watch_col,
@@ -88,7 +91,16 @@ class HashJoinExecutor(Executor):
         src/stream/src/executor/managed_state/join/mod.rs:228-258).
         Requires both state tables with JOIN-KEY-PREFIXED pks (the
         builder lays pks out as join_keys ++ stream_pk so fault-in is a
-        pk prefix scan)."""
+        pk prefix scan).
+
+        ``row_keys``: per side, the columns that identify a row where the
+        plan says a join key may hold many of them (the side's stream key
+        is not inside its join key), else None. Such a side starts in the
+        bucket arena and moves to a fan-out arena of ``row_capacity`` rows
+        (or twice the rows it holds) the first time a key outgrows the
+        bucket width, instead of widening every bucket
+        (``ops/join_state.py`` "Side layouts"). Not under an
+        ``hbm_key_budget``: eviction is by whole bucket."""
         self.left, self.right = left, right
         self.load_shard = load_shard
         # PG NOT IN semantics (planner.py _plan_in_subquery): a NULL
@@ -112,6 +124,10 @@ class HashJoinExecutor(Executor):
             state_pks=tuple(() if t is None else tuple(t.pk_indices)
                             for t in (left_state_table, right_state_table)))
         self._key_args = (left_keys, right_keys)
+        if hbm_key_budget is not None:
+            row_keys = (None, None)
+        self._row_keys = {"left": row_keys[0], "right": row_keys[1]}
+        self.row_capacity = row_capacity
         self.interval_clean = tuple(interval_clean)
         self._pending_clean: dict[tuple[str, int], int] = {}
         # max threshold ever applied per (side, col) — the fault-in filter
@@ -148,6 +164,7 @@ class HashJoinExecutor(Executor):
         # what HashJoin.chunks reports for the epoch, reset at its barrier
         self._epoch_counts = {"rows_in_left": 0, "rows_in_right": 0,
                               **dict.fromkeys(EMIT_COUNTS, 0),
+                              **dict.fromkeys(FANOUT_COUNTS, 0),
                               "rewinds": 0, "grows": 0}
         self._make_jits()
         if any(self.state_tables.values()):
@@ -179,7 +196,7 @@ class HashJoinExecutor(Executor):
             def body(st, x):
                 ch, step = x if steps is not None else (x, None)
                 st, big = core.apply_chunk(st, ch, side=side, step=step)
-                return st, (pack_stats(core, _flags(st), big, ch,
+                return st, (pack_stats(core, _flags(st), big, ch, side,
                                        self._null_keys.get(side)), big)
 
             xs = (batched_chunk, steps) if steps is not None \
@@ -210,7 +227,8 @@ class HashJoinExecutor(Executor):
         self._gather = jax.jit(join_gather)
 
         def join_pack_stats(flags, big, ch, side):
-            return pack_stats(core, flags, big, ch, self._null_keys.get(side))
+            return pack_stats(core, flags, big, ch, side,
+                              self._null_keys.get(side))
 
         self._pack_stats = jax.jit(join_pack_stats, static_argnums=(3,))
         # pads a stats fetch
@@ -224,15 +242,11 @@ class HashJoinExecutor(Executor):
         self._clean_side = jax.jit(clean_side_below, static_argnums=(1,))
 
         def _compact(state: JoinState) -> JoinState:
-            return JoinState(
-                left=compact_side(self.core, state.left,
-                                  self.core.left_schema, self.core.left_keys),
-                right=compact_side(self.core, state.right,
-                                   self.core.right_schema,
-                                   self.core.right_keys),
-            )
+            return JoinState(left=compact_side(core, state.left, "left"),
+                             right=compact_side(core, state.right, "right"))
 
         self._compact = jax.jit(_compact)
+        self._fanout_gauges = jax.jit(_fanout_gauges)
 
     # -- LRU stamping ----------------------------------------------------------
 
@@ -247,32 +261,47 @@ class HashJoinExecutor(Executor):
 
     def _apply_growing(self, side: str, chunk: StreamChunk):
         """Apply a chunk; on overflow discard the result, grow the state
-        geometry (bucket width for hot-key skew, key capacity for table
-        fill), and retry on the untouched previous state. Functional state
-        makes the retry exact — no partial effects to undo."""
+        geometry of the side that overflowed (key capacity for table fill;
+        for a key that outgrew its bucket, the fan-out arena where the plan
+        allows it, else the bucket width; a fan-out probe's pair buffer),
+        and retry on the untouched previous state. Functional state makes
+        the retry exact — no partial effects to undo."""
         step = self._lru()
         while True:
             new_state, big = self._apply[side](self.state, chunk, step)
             sides = {"left": new_state.left, "right": new_state.right}
-            lane_ovf = any(bool(st.lane_overflow) for st in sides.values())
-            ht_ovf = any(bool(st.ht_overflow) for st in sides.values())
-            if not lane_ovf and not ht_ovf:
+            grown = {s: self._grown_layout(s, st) for s, st in sides.items()}
+            if grown == self.core.layout:
                 self.state = new_state
                 return big
-            new_W = self.core.W * 2 if lane_ovf else self.core.W
-            new_cap = self.core.capacity * 2 if ht_ovf else self.core.capacity
-            if new_W * new_cap > self.max_state_cells:
-                raise RuntimeError(
-                    f"{self.identity}: join state would exceed "
-                    f"{self.max_state_cells} cells (cap={new_cap}, W={new_W})")
-            self._grow(new_cap, new_W)
+            for lay in grown.values():
+                if lay.cells > self.max_state_cells:
+                    raise RuntimeError(
+                        f"{self.identity}: join state would exceed "
+                        f"{self.max_state_cells} cells ({lay})")
+            self._grow((grown["left"], grown["right"]))
             self._epoch_counts["grows"] += 1
 
-    def _grow(self, new_cap: int, new_W: int) -> None:
+    def _grown_layout(self, side: str, st) -> SideLayout:
+        """The geometry ``side`` needs after a step that left ``st``."""
+        lay = self.core.layout[side]
+        lane_ovf, ht_ovf = bool(st.lane_overflow), bool(st.ht_overflow)
+        row_key = self._row_keys[side]
+        if lane_ovf and not lay.fanout and row_key is not None:
+            held = getattr(self.state, side)
+            rows = int(jnp.sum(held.occupied | held.tomb)) + int(held.grave_n)
+            return SideLayout(max(self.row_capacity,
+                                  1 << max(2 * rows - 1, 1).bit_length()),
+                              1, row_key)
+        return dataclasses.replace(
+            lay, width=lay.width * (2 if lane_ovf else 1),
+            capacity=lay.capacity * (2 if ht_ovf else 1))
+
+    def _grow(self, layouts: tuple) -> None:
         left_keys, right_keys = self._key_args
         self.core = JoinCore(
             self.left.schema, self.right.schema, left_keys, right_keys,
-            key_capacity=new_cap, bucket_width=new_W, **self._join_args)
+            layouts=layouts, **self._join_args)
         self.state = import_state(self.core, self.state)
         self._make_jits()
 
@@ -313,7 +342,7 @@ class HashJoinExecutor(Executor):
         what ``HashJoin.chunks`` reports for the epoch."""
         counts = self._epoch_counts
         counts[f"rows_in_{side}"] += int(rows[:, 5].sum())
-        for i, name in enumerate(EMIT_COUNTS, 6):
+        for i, name in enumerate(EMIT_COUNTS + FANOUT_COUNTS, 6):
             counts[name] += int(rows[:, i].sum())
 
     def _gather_units(self, big, n_units: int):
@@ -451,7 +480,8 @@ class HashJoinExecutor(Executor):
                     yield out
                 clock.emit(barrier.epoch.curr, self.node,
                            chunks_out=stats.chunks_out - chunks_out,
-                           bucket_width=self.core.W, **self._epoch_counts)
+                           bucket_width=self.core.W, **self._epoch_counts,
+                           **self._fanout_args())
                 chunks_out = stats.chunks_out
                 self._epoch_counts = dict.fromkeys(self._epoch_counts, 0)
                 with barrier_timer(stats, self.identity, barrier.epoch.curr,
@@ -605,14 +635,25 @@ class HashJoinExecutor(Executor):
             return col_idx if sa == side else None
         return col_idx if side == "left" else col_idx + len(self.core.left_schema)
 
+    def _fanout_args(self) -> dict:
+        """What ``HashJoin.chunks`` says of the fan-out arenas at the
+        barrier: the live rows they hold and how full their row tables
+        are (one small fetch; nothing where no side is fan-out)."""
+        fan = [s for s in ("left", "right") if self.core.layout[s].fanout]
+        if not fan:
+            return {}
+        live, used = (int(x) for x in fetch(self._fanout_gauges(
+            tuple(getattr(self.state, s) for s in fan))))
+        rows = sum(self.core.layout[s].capacity for s in fan)
+        return {"fanout_rows": live, "fanout_fill_pct": 100.0 * used / rows}
+
     def _check_flags(self) -> None:
         for side in ("left", "right"):
-            st: JoinSideState = getattr(self.state, side)
+            st = getattr(self.state, side)
             if bool(st.ht_overflow) or bool(st.lane_overflow):
                 raise RuntimeError(
                     f"{self.identity}: {side} join state overflow escaped "
-                    f"growth (key_capacity={self.core.capacity}, "
-                    f"bucket_width={self.core.W})")
+                    f"growth ({self.core.layout[side]})")
             if self.strict and bool(st.inconsistent):
                 raise RuntimeError(
                     f"{self.identity}: {side} saw delete of an absent row")
@@ -634,7 +675,7 @@ class HashJoinExecutor(Executor):
         what crosses to the host follows the delta, not the arena's
         capacity. A dirty row is a put where it is occupied, a delete
         where it is a tombstone that was not filled again."""
-        st: JoinSideState = getattr(self.state, side)
+        st = getattr(self.state, side)
         n_dirty, (occ, tomb, datas, masks), fetched = fetch_delta(
             lambda lo, G: self._delta_window(st, lo, G), st.ckpt_dirty.size)
         delta.set(dirty_rows=n_dirty, bytes_staged=0, **fetched)
@@ -729,32 +770,50 @@ class HashJoinExecutor(Executor):
 
 
 def _flags(state: JoinState) -> tuple:
-    """The four overflow flags ``pack_stats`` reads: handing the stats
-    program these and not the whole state keeps its dispatch at a few
-    arguments (a state is some sixty device buffers)."""
+    """What ``pack_stats`` reads of the state: the four overflow flags and
+    the fan-out sides' probe counts of the step (an empty tuple where no
+    side is fan-out: nothing is computed outside the stats program).
+    Handing the stats program these and not the whole state keeps its
+    dispatch at a few arguments (a state is some sixty device buffers)."""
     return (state.left.lane_overflow, state.left.ht_overflow,
-            state.right.lane_overflow, state.right.ht_overflow)
+            state.right.lane_overflow, state.right.ht_overflow,
+            tuple(st.probe_counts for st in (state.left, state.right)
+                  if hasattr(st, "probe_counts")))
+
+
+def _fanout_gauges(sides: tuple) -> jax.Array:
+    """``[live rows, row-table slots in use]`` over fan-out sides."""
+    return jnp.stack([sum(jnp.sum(st.occupied, dtype=jnp.int64)
+                          for st in sides),
+                      sum(jnp.sum(st.ht.occupied, dtype=jnp.int64)
+                          for st in sides)])
 
 
 #: what ``JoinCore.emit_counts`` gives, in the packed stats from slot 6 on
 EMIT_COUNTS = ("rows_out", "null_padded_out", "transitions", "matched",
                "unmatched")
+#: what a step's probes of a fan-out side did (``FanoutSideState.
+#: probe_counts``), after ``EMIT_COUNTS``: live probe rows, the arena rows
+#: of their keys that the scan read, the pairs that passed the condition
+FANOUT_COUNTS = ("fanout_probes", "candidate_rows", "fanout_pairs")
 #: slots of the packed stats every plan has
-N_STATS = 6 + len(EMIT_COUNTS)
+N_STATS = 6 + len(EMIT_COUNTS) + len(FANOUT_COUNTS)
 
 
-def pack_stats(core: JoinCore, flags: tuple, big, chunk,
+def pack_stats(core: JoinCore, flags: tuple, big, chunk, side: str,
                null_keys=None) -> jax.Array:
     """Every host-read scalar of one applied chunk in ONE vector:
     [l.lane_ovf, l.ht_ovf, r.lane_ovf, r.ht_ovf, n_units, rows_in,
-    *EMIT_COUNTS] and, where ``null_keys`` is given (a NOT IN plan: the
-    chunk's key columns to look at, none on the probe side), the visible
-    rows of the chunk with a NULL among them."""
+    *EMIT_COUNTS, *FANOUT_COUNTS] and, where ``null_keys`` is given (a NOT
+    IN plan: the chunk's key columns to look at, none on the probe side),
+    the visible rows of the chunk with a NULL among them."""
     stats = [
-        *(f.astype(jnp.int64) for f in flags),
+        *(f.astype(jnp.int64) for f in flags[:4]),
         count_units(big),
         jnp.sum(chunk.vis, dtype=jnp.int64),
-        *core.emit_counts(big),
+        *core.emit_counts(big, side),
+        *(sum(flags[4]) if flags[4]
+          else jnp.zeros(len(FANOUT_COUNTS), jnp.int64)),
     ]
     if null_keys is not None:
         keyed = chunk.vis
@@ -765,12 +824,10 @@ def pack_stats(core: JoinCore, flags: tuple, big, chunk,
 
 
 def _clear_ckpt_marks(state: JoinState) -> JoinState:
-    def clear(st: JoinSideState) -> JoinSideState:
-        return st.replace(
-            ckpt_dirty=jnp.zeros_like(st.ckpt_dirty),
-            tomb=jnp.zeros_like(st.tomb),
-            grave_n=jnp.zeros_like(st.grave_n),
-        )
+    def clear(st):
+        st = st.replace(ckpt_dirty=jnp.zeros_like(st.ckpt_dirty),
+                        tomb=jnp.zeros_like(st.tomb))
+        if hasattr(st, "grave_n"):
+            st = st.replace(grave_n=jnp.zeros_like(st.grave_n))
+        return st
     return state.replace(left=clear(state.left), right=clear(state.right))
-
-

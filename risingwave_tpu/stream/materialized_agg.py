@@ -131,6 +131,9 @@ class MaterializedAggExecutor(SingleInputExecutor):
         self._groups: dict[tuple, _GroupState] = {}
         self._out: dict[tuple, tuple] = {}        # group -> last emitted row
         self._dirty: set = set()
+        #: what ``MaterializedAgg.chunks`` reports for the epoch: the
+        #: changelog rows taken in, the groups recomputed at its barrier
+        self._epoch_counts = {"rows_in": 0, "groups_recomputed": 0}
         #: groups whose multiset changed since the last persisted snapshot
         self._ckpt_dirty: set = set()
         if state_table is not None:
@@ -146,8 +149,10 @@ class MaterializedAggExecutor(SingleInputExecutor):
     # -- input application ----------------------------------------------------
 
     async def map_chunk(self, chunk: StreamChunk):
-        for op, row in chunk_to_rows(chunk, self.in_schema, with_ops=True,
-                                     physical=True):
+        rows = chunk_to_rows(chunk, self.in_schema, with_ops=True,
+                             physical=True)
+        self._epoch_counts["rows_in"] += len(rows)
+        for op, row in rows:
             self._apply_row(op, row)
         if False:
             yield
@@ -263,7 +268,13 @@ class MaterializedAggExecutor(SingleInputExecutor):
         return key + tuple(self._eval_call(i, c, g)
                            for i, c in enumerate(self.agg_calls))
 
+    def epoch_counts(self) -> dict:
+        counts = self._epoch_counts
+        self._epoch_counts = dict.fromkeys(counts, 0)
+        return counts
+
     async def on_barrier(self, barrier: Barrier):
+        self._epoch_counts["groups_recomputed"] += len(self._dirty)
         pairs: list = []
         for key in sorted(self._dirty, key=repr):
             g = self._groups.get(key)

@@ -437,7 +437,7 @@ def test_emit_counts_read_the_outer_join_mechanism_off_the_grid():
 
     def counts(core, st, chunk, side):
         st, big = core.apply_chunk(st, chunk, side=side)
-        return st, tuple(int(x) for x in core.emit_counts(big))
+        return st, tuple(int(x) for x in core.emit_counts(big, side))
 
     U_, UP = OP_UPDATE_DELETE, OP_UPDATE_INSERT
     core = JoinCore(L_SCHEMA, R_SCHEMA, [0], [0], JoinType.LEFT_OUTER,

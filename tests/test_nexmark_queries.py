@@ -130,8 +130,14 @@ def test_q4_avg_final_price():
         assert abs(g[1] - e[1]) < 1e-6
 
 
-def test_q5_hot_items():
-    got = run_mv("""CREATE MATERIALIZED VIEW q5 AS
+@pytest.mark.parametrize("cmp", ["=", ">="], ids=["eq", "ge"])
+def test_q5_hot_items(cmp):
+    """NEXmark q5 with the join's ``num = maxn`` and, as upstream writes
+    it, ``num >= maxn``: the same rows (no count passes its window's
+    maximum). The AuctionBids side holds every (window, auction) count of
+    its window and moves to the fan-out arena."""
+    s = make_session()
+    s.run_sql(f"""CREATE MATERIALIZED VIEW q5 AS
         SELECT AuctionBids.auction, AuctionBids.num FROM (
             SELECT bid.auction, count(*) AS num, window_start AS starttime
             FROM HOP(bid, date_time, INTERVAL '2' SECOND, INTERVAL '10' SECOND)
@@ -148,7 +154,16 @@ def test_q5_hot_items():
             GROUP BY CountBids.starttime_c
         ) AS MaxBids
         ON AuctionBids.starttime = MaxBids.starttime_c
-           AND AuctionBids.num = MaxBids.maxn""", "q5")
+           AND AuctionBids.num {cmp} MaxBids.maxn""")
+    for _ in range(TICKS):
+        s.tick()
+    got = sorted(s.mv_rows("q5"))
+    from risingwave_tpu.stream import HashJoinExecutor
+    from risingwave_tpu.stream.metrics import iter_executors
+    join = next(e for e in iter_executors(s.jobs["q5"].pipeline)
+                if isinstance(e, HashJoinExecutor))
+    assert join.core.layout["left"].fanout
+    assert not join.core.layout["right"].fanout
     bids = replay("bid", TICKS)
     counts: dict = collections.defaultdict(int)
     slide, size = 2 * SEC, 10 * SEC
